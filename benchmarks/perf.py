@@ -862,85 +862,84 @@ def fleet_matrix() -> Dict[str, float]:
     return out
 
 
+def _multi_device_sweep() -> Dict[str, float]:
+    """Warm walls of the 200-job ``plan_batch_jax`` sweep without and with
+    the cell-axis split over every visible device (the sharded arm
+    through a declared :class:`~repro.core.scheduler.grid_jax.MeshConfig`,
+    the production multi-chip path)."""
+    from repro.core.carbon.intensity import PAPER_WINDOW_T0 as T0
+    from repro.core.scheduler.grid_jax import MeshConfig
+    from repro.core.scheduler.overlay import FTN
+    from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
+
+    ftns = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
+            FTN("tacc", "cascade_lake", 10.0)]
+    pl = CarbonPlanner(ftns, batch_backend="jax")
+    jobs = [TransferJob(f"b{i}", (50 + (7 * i) % 400) * 1e9, ("uc", "m1"),
+                        "tacc", SLA(deadline_s=48 * 3600.0),
+                        T0 + (i % 24) * 600.0) for i in range(200)]
+
+    def timed(shard):
+        pl.plan_batch_jax(jobs, shard=shard)          # compile + warm
+        best = float("inf")
+        for _ in range(2):
+            t0 = time.perf_counter()
+            pl.plan_batch_jax(jobs, shard=shard)
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    return {"devices": jax.device_count(), "single_s": timed(False),
+            "sharded_s": timed(MeshConfig())}
+
+
 def planner_multi_device() -> Dict[str, float]:
-    """Multi-device ``shard_map`` path of the batched planner kernel,
-    measured under a forced host-device config: a subprocess (device
-    count is fixed at jax import) sets ``XLA_FLAGS
-    --xla_force_host_platform_device_count=N`` and times the 200-job
-    ``plan_batch_jax`` sweep with and without the cell-axis device
-    sharding — the sharded arm through a declared
-    :class:`~repro.core.scheduler.grid_jax.MeshConfig` (the production
-    multi-chip mesh path). Merges ``multi_device_*`` fields (incl.
-    ``multi_device_speedup_x``) into BENCH_planner.json. Host devices
-    share the same cores, so ~1x is expected on CPU — there the field
-    only tracks kernel overhead and ``multi_device_gate_armed`` stays 0.
-    On a host whose *parent* process already sees >1 genuinely distinct
-    accelerator devices (no forcing involved) the gate arms, mirroring
-    the ``parallel`` bench's drain-floor pattern: an armed run whose
-    sharded sweep is not faster than the single-device sweep raises
-    after the numbers are written."""
+    """Multi-device ``shard_map`` path of the batched planner kernel
+    (:func:`_multi_device_sweep`). Where the default backend is an
+    accelerator with more than one device, the sweep runs in this
+    process, which already holds the chips, and the gate arms: an armed
+    run whose sharded sweep is not faster than the single-device sweep
+    raises after the numbers are written. On the CPU a child process
+    forces ``--xla_force_host_platform_device_count=N`` host devices
+    (the count is fixed at jax import) — they share the same cores, so
+    ~1x is expected and the gate stays unarmed. Merges
+    ``multi_device_*`` fields into BENCH_planner.json."""
     import os as _os
     import subprocess as _sp
     import sys as _sys
 
-    devices = min(_os.cpu_count() or 1, 4)
-    # armed only for real multi-accelerator configs: the subprocess's
-    # forced host devices share cores and MUST NOT arm the gate.
-    armed = int(jax.default_backend() != "cpu" and jax.device_count() > 1)
-    if devices < 2 and not armed:
+    platform = jax.default_backend()
+    devices = jax.device_count() if platform != "cpu" \
+        else min(_os.cpu_count() or 1, 4)
+    if devices < 2:
         out = {"multi_device_count": devices,
                "multi_device_speedup_x": None,
                "multi_device_gate_armed": 0,
-               "multi_device_note": "single-CPU host: sweep skipped"}
+               "multi_device_note": f"one {platform} device: sweep "
+                                    f"skipped"}
         _write_planner_bench(out)
         return out
-    code = """
-import json, time
-import jax
-from repro.core.carbon.intensity import PAPER_WINDOW_T0 as T0
-from repro.core.scheduler.grid_jax import MeshConfig
-from repro.core.scheduler.overlay import FTN
-from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
-
-ftns = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
-        FTN("tacc", "cascade_lake", 10.0)]
-pl = CarbonPlanner(ftns, batch_backend="jax")
-jobs = [TransferJob(f"b{i}", (50 + (7 * i) % 400) * 1e9, ("uc", "m1"),
-                    "tacc", SLA(deadline_s=48 * 3600.0),
-                    T0 + (i % 24) * 600.0) for i in range(200)]
-
-def timed(shard):
-    pl.plan_batch_jax(jobs, shard=shard)          # compile + warm
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        pl.plan_batch_jax(jobs, shard=shard)
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-single_s = timed(False)
-# the sharded arm runs through the declared mesh config (the production
-# multi-chip path), not the bare shard=True every-device default
-sharded_s = timed(MeshConfig())
-print(json.dumps({"devices": jax.device_count(),
-                  "single_s": single_s, "sharded_s": sharded_s}))
-"""
-    env = dict(_os.environ)
-    if not armed:                       # CPU: force host devices
+    if platform != "cpu":
+        res, armed = _multi_device_sweep(), 1
+    else:
+        root = pathlib.Path(__file__).resolve().parent.parent
+        env = dict(_os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count"
                             f"={devices}")
-    src_root = pathlib.Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = str(src_root / "src") + _os.pathsep \
-        + env.get("PYTHONPATH", "")
-    proc = _sp.run([_sys.executable, "-c", code], env=env,
-                   capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"multi-device sweep failed:\n"
-                           f"{proc.stderr[-2000:]}")
-    res = json.loads(proc.stdout.strip().splitlines()[-1])
+        env["PYTHONPATH"] = _os.pathsep.join(
+            [str(root / "src"), str(root), env.get("PYTHONPATH", "")])
+        code = ("import json; from benchmarks.perf import "
+                "_multi_device_sweep; print(json.dumps(_multi_device_sweep()))")
+        proc = _sp.run([_sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=1200)
+        if proc.returncode != 0:
+            raise RuntimeError(f"multi-device sweep failed:\n"
+                               f"{proc.stderr[-2000:]}")
+        res = json.loads(proc.stdout.strip().splitlines()[-1])
+        armed = 0
     speedup = round(res["single_s"] / res["sharded_s"], 2)
     out = {"multi_device_count": res["devices"],
+           "multi_device_platform": platform,
            "multi_device_single_us": round(res["single_s"] * 1e6),
            "multi_device_sharded_us": round(res["sharded_s"] * 1e6),
            "multi_device_gate_armed": armed,
@@ -960,42 +959,29 @@ def planner_scale() -> Dict[str, object]:
     a million-job sweep is many admission windows, not one tensor).
 
     Per rung it records jobs/s, ``peak_cells`` (largest per-chunk
-    admission grid), and two correctness spot-checks on a sampled subset:
-    the numpy oracle (cell choice equal, emissions within 1e-4 relative —
-    a mismatch raises) and the fused Pallas kernel (interpret mode on
-    CPU, compiled elsewhere). Merges the ``planner_scale`` section into
-    BENCH_planner.json. Rungs above 2x10^5 only run with a non-CPU jax
-    backend and are recorded as skipped on CPU hosts; the full-rung
-    backend is "pallas" on accelerators and "jax" (lattice) on CPU,
-    where interpret-mode Pallas is a correctness tool, not a perf path.
-
-    ``BENCH_PLANNER_SCALE_RUNGS`` (comma-separated) and
-    ``BENCH_PLANNER_SCALE_CHUNK`` override the sweep shape."""
+    admission grid) and a correctness spot-check on a sampled subset
+    against the numpy oracle (cell choice equal, emissions within 1e-4
+    relative — a mismatch raises). Merges the ``planner_scale`` section,
+    with the device it ran on, into BENCH_planner.json. Every rung runs
+    on the Pallas tier, whatever the host (interpret mode on the CPU, so
+    pick the rungs to suit it): ``BENCH_PLANNER_SCALE_RUNGS``
+    (comma-separated) and ``BENCH_PLANNER_SCALE_CHUNK`` set the sweep
+    shape."""
     import os as _os
 
     import numpy as np
 
-    from repro.core.carbon.intensity import PAPER_WINDOW_T0 as T0
-    from repro.core.scheduler import grid_pallas
-    from repro.core.scheduler.overlay import FTN
-    from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
+    from repro.core.scheduler.planner import CarbonPlanner
+    from repro.core.workloads.scenarios import (PLANNER_SCALE_FTNS,
+                                                planner_scale_job as _job)
 
     rungs = [int(r) for r in _os.environ.get(
         "BENCH_PLANNER_SCALE_RUNGS", "10000,100000,1000000").split(",")
         if r.strip()]
     chunk = int(_os.environ.get("BENCH_PLANNER_SCALE_CHUNK", "4096"))
-    accel = jax.default_backend() != "cpu"
-    backend = "pallas" if (accel and grid_pallas.PALLAS_AVAILABLE) \
-        else "jax"
-    ftns = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
-            FTN("tacc", "cascade_lake", 10.0)]
-
-    def _job(i: int) -> TransferJob:
-        return TransferJob(
-            f"s{i}", (20 + (13 * i) % 600) * 1e9,
-            ("uc", "m1") if i % 3 else ("uc",), "tacc",
-            SLA(deadline_s=(12 + i % 36) * 3600.0),
-            T0 + (i % 288) * 300.0)
+    backend = "pallas"
+    d0 = jax.devices()[0]
+    ftns = list(PLANNER_SCALE_FTNS)
 
     def _spot(n: int, pl: CarbonPlanner) -> Dict[str, object]:
         """Re-plan a sampled subset on ``pl`` and diff it cell-for-cell
@@ -1020,10 +1006,6 @@ def planner_scale() -> Dict[str, object]:
 
     rows = []
     for n in rungs:
-        if n > 200_000 and not accel:
-            rows.append({"jobs": n,
-                         "skipped": "cpu host: accelerator-only rung"})
-            continue
         pl = CarbonPlanner(ftns, batch_backend=backend)
         peak_cells, done = 0, 0
         t0 = time.perf_counter()
@@ -1038,19 +1020,16 @@ def planner_scale() -> Dict[str, object]:
                "jobs_per_s": round(n / wall, 1),
                "wall_s": round(wall, 2), "peak_cells": peak_cells,
                "oracle_spot": _spot(n, pl)}
-        if grid_pallas.PALLAS_AVAILABLE and pl.batch_backend != "pallas":
-            row["pallas_spot"] = _spot(
-                n, CarbonPlanner(ftns, batch_backend="pallas"))
         rows.append(row)
-        for key in ("oracle_spot", "pallas_spot"):
-            spot = row.get(key)
-            if spot and (spot["mismatches"]
-                         or spot["max_emis_rel_err"] > 1e-4):
-                raise RuntimeError(
-                    f"planner_scale {n}-job rung: {key} diverged from "
-                    f"the numpy oracle: {spot}")
+        spot = row["oracle_spot"]
+        if spot["mismatches"] or spot["max_emis_rel_err"] > 1e-4:
+            raise RuntimeError(
+                f"planner_scale {n}-job rung: the {backend} tier diverged "
+                f"from the numpy oracle: {spot}")
     out = {"planner_scale": {"chunk": chunk,
-                             "accelerator": int(accel),
+                             "device": {"platform": d0.platform,
+                                        "kind": d0.device_kind,
+                                        "count": jax.device_count()},
                              "rungs": rows}}
     _write_planner_bench(out)
     return out

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import pathlib
 import sys
 import time
 
@@ -44,6 +45,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache(pathlib.Path(__file__).resolve().parent.parent)
     rows = []
     print("name,us_per_call,derived")
     failed = 0

@@ -64,7 +64,7 @@ _HEADLINES = {
     "planner_scan": (("plan us", "plan_us"), ("speedup x", "speedup_x"),
                      ("batch jobs/s", "batch_jobs_per_s"),
                      ("oracle", "matches_oracle")),
-    "planner_scale": (("accelerator", "accelerator"), ("chunk", "chunk"),
+    "planner_scale": (("device", "device.kind"), ("chunk", "chunk"),
                       ("rungs", "rungs")),
     "field_lattice": (("rungs", "rungs"),),
 }
@@ -87,6 +87,7 @@ _PLANNER_FLAT = ("plan_us", "reference_us", "speedup_x", "alternatives",
                  "alternatives_per_s", "batch_jobs_per_s", "matches_oracle",
                  "emissions_rel_err", "multi_device_count",
                  "multi_device_gate_armed", "multi_device_note",
+                 "multi_device_platform",
                  "multi_device_sharded_us", "multi_device_single_us",
                  "multi_device_speedup_x")
 

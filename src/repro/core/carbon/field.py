@@ -175,7 +175,7 @@ class CarbonField:
         self._hop_noise = _NoiseTable("{k}:{h}")       # Hop.ci hourly band
         self._hop_base: Dict[str, float] = {}          # Hop.ci per-ip band
         self._hop_grid_cache: Dict[Tuple, np.ndarray] = {}
-        self._weight_fn_cache: Dict[Tuple, Callable] = {}
+        self._weight_fn_cache: Dict[Tuple, Tuple] = {}
 
     # --- zone level --------------------------------------------------------
     def zone_ci(self, zone: str, ts: ArrayLike,
@@ -425,12 +425,15 @@ class CarbonField:
         :meth:`_device_weights` (same clamp and summation order).
         """
         # discover_path memoizes NetworkPath instances, so identity is a
-        # stable key (hashing the hops tuple is the hot-path cost here)
+        # stable key (hashing the hops tuple is the hot-path cost here).
+        # The entry holds its path, so no other path can take that id
+        # while the entry lives (a re-installed topology frees the old
+        # memoized paths).
         key = (id(path), sender.name, receiver.name,
                parallelism, concurrency)
-        fn = self._weight_fn_cache.get(key)
-        if fn is not None:
-            return fn
+        ent = self._weight_fn_cache.get(key)
+        if ent is not None:
+            return ent[1]
         n = path.n_hops
         idle, cw, mw, nw = (np.zeros(n) for _ in range(4))
         den = np.ones(n)
@@ -460,7 +463,7 @@ class CarbonField:
 
         if len(self._weight_fn_cache) >= self._GRID_CACHE_MAX:
             self._weight_fn_cache.pop(next(iter(self._weight_fn_cache)))
-        self._weight_fn_cache[key] = w_of
+        self._weight_fn_cache[key] = (path, w_of)
         return w_of
 
     def __getstate__(self) -> Dict:

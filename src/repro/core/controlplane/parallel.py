@@ -22,12 +22,14 @@ Design contracts:
   worker start. All noise is hashed once in the coordinator; workers never
   re-hash, and every CI query is bit-identical across processes because
   the traces are deterministic functions of the snapshot.
-* **fork workers stay off jax** — XLA's runtime threads do not survive
-  ``os.fork()`` (a forked child calling a jitted kernel deadlocks), so
-  fork-mode workers run their shard planners on the pinned *numpy oracle*
-  backend. The expensive fleet-wide admission sweep already runs in the
-  coordinator, where jax is safe; in-run re-plan sweeps are small.
-  Spawn-mode workers own a fresh interpreter and may use any backend.
+* **workers stay off the accelerator** — a chip belongs to one process,
+  and the coordinator, which runs the fleet-wide admission sweep, holds
+  it. So shard planners in worker processes default to the pinned *numpy
+  oracle* backend (:data:`WORKER_BACKEND`; in-run re-plan sweeps are
+  small), and a spawned worker confines JAX to the CPU before anything
+  can initialise a backend. Fork workers never call into XLA at all: its
+  runtime threads do not survive ``os.fork()`` (a forked child calling a
+  jitted kernel deadlocks).
 * **per-quantum barrier** — :meth:`ParallelShardRunner.pump_all` sends one
   bounded ``pump(until, strict, horizon)`` to every worker, then drains
   replies in shard order: a barrier per time quantum. The
@@ -80,6 +82,7 @@ import multiprocessing as mp
 import os
 import pickle
 import signal
+import sys
 import time
 import traceback
 import warnings
@@ -88,8 +91,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.carbon.field import FrozenField, install_frozen_default
 from repro.core.controlplane.controller import FleetReport
 
-#: shard-planner backend forced on fork workers (see module docstring).
-FORK_SAFE_BACKEND = "numpy"
+#: default shard-planner backend of worker processes (see module
+#: docstring): the accelerator stays with the coordinator.
+WORKER_BACKEND = "numpy"
 
 #: commands the supervisor journals for replay — deterministic state
 #: mutations. Lifecycle ("stop"), introspection ("state" sync barriers are
@@ -391,6 +395,17 @@ def _worker_main(conn, spec: ShardSpec) -> None:
     conn.close()
 
 
+def _spawned_worker_main(conn, spec: ShardSpec) -> None:
+    """Entry point of a spawned worker: a fresh interpreter, so JAX (if
+    anything imports it) can still be confined to the CPU before a
+    backend exists — the coordinator owns the accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+    _worker_main(conn, spec)
+
+
 class _ClockView:
     """Coordinator-side mirror of a worker's ``EventLoop`` clock: ``now``
     and ``peek_t()`` as of the last reply, plus exact optimistic updates
@@ -427,11 +442,13 @@ class _WorkerHandle:
         self.name = name
         self.timeout = timeout
         self.conn, child = ctx.Pipe()
-        self.proc = ctx.Process(target=_worker_main, args=(child, spec),
+        main = _worker_main if ctx.get_start_method() == "fork" \
+            else _spawned_worker_main
+        self.proc = ctx.Process(target=main, args=(child, spec),
                                 name=name, daemon=True)
         with warnings.catch_warnings():
             # jax warns that a multithreaded parent is forking; our fork
-            # workers never call back into XLA (FORK_SAFE_BACKEND), which
+            # workers never call back into XLA (WORKER_BACKEND), which
             # is the precise hazard the warning is about
             warnings.simplefilter("ignore", RuntimeWarning)
             self.proc.start()
@@ -719,18 +736,18 @@ class ShardSupervisor:
             attempts = attempt
             spec = runner._specs[idx]
             if (type(err) is WorkerFailure
-                    and spec.batch_backend != FORK_SAFE_BACKEND):
+                    and spec.batch_backend != WORKER_BACKEND):
                 # the worker *reported* an exception (it did not die): a
                 # jax/XLA batch-backend fault is the expected cause —
                 # retry on the pinned numpy oracle before blaming the
                 # process
                 old = spec.batch_backend
                 spec = dataclasses.replace(
-                    spec, batch_backend=FORK_SAFE_BACKEND)
+                    spec, batch_backend=WORKER_BACKEND)
                 runner._specs[idx] = spec
                 self.degradations.append(
                     f"shard {idx}: batch backend {old} -> "
-                    f"{FORK_SAFE_BACKEND} (worker-reported error)")
+                    f"{WORKER_BACKEND} (worker-reported error)")
             runner._handles[idx].hard_close()
             time.sleep(pol.backoff_s * (2 ** (attempt - 1)))
             try:

@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.core.carbon.field import CarbonField, default_field
 from repro.core.controlplane.controller import FleetController, FleetReport
-from repro.core.controlplane.parallel import (FORK_SAFE_BACKEND, FaultPlan,
+from repro.core.controlplane.parallel import (WORKER_BACKEND, FaultPlan,
                                               ParallelShardRunner, ShardSpec,
                                               SupervisionPolicy, resolve_mode)
 from repro.core.obs import metrics as obs_metrics
@@ -135,14 +135,14 @@ class ShardedFleet:
 
     ``batch_backend`` is forwarded to the fleet-level admission planner
     ("pallas" fuses the admission sweep's scoring chain + per-cell argmin
-    into the tiled ``grid_pallas`` kernel; "jax" stacks the fleet's
-    full-scan planning into one jitted lattice call; None picks jax when
-    available, numpy otherwise — the planner itself degrades pallas ->
-    jax when Pallas cannot run, so admission never silently drops to
-    oracle speed). ``shard_backend`` is the *shard planners'* batch
-    backend — the in-run re-plan sweeps — and defaults to
-    ``batch_backend``, except under ``parallel="fork"`` where it defaults
-    to the numpy oracle (XLA does not survive a fork; see
+    into the ``grid_pallas`` kernels; "jax" stacks the fleet's full-scan
+    planning into one jitted lattice call; None means "jax"). A device
+    tier that cannot run raises — admission never drops to oracle speed
+    in silence. ``shard_backend`` is the *shard planners'* batch backend
+    — the in-run re-plan sweeps — and defaults to ``batch_backend`` when
+    the shards run in this process (``parallel="off"``). With worker
+    processes it defaults to the numpy oracle: the coordinator owns the
+    accelerator, so no worker may initialise a JAX backend on it (see
     ``core.controlplane.parallel``). Remaining keyword arguments are
     forwarded to every ``FleetController``.
 
@@ -174,13 +174,12 @@ class ShardedFleet:
                              f"'auto', got {parallel!r}")
         self.field = field or default_field()
         if batch_backend is None:
-            from repro.core.scheduler.grid_jax import HAVE_JAX
-            batch_backend = "jax" if HAVE_JAX else "numpy"
+            batch_backend = "jax"
         self.parallel = parallel if parallel == "off" \
             else resolve_mode(parallel)
         if shard_backend is None:
-            shard_backend = FORK_SAFE_BACKEND \
-                if self.parallel == "fork" else batch_backend
+            shard_backend = batch_backend if self.parallel == "off" \
+                else WORKER_BACKEND
         self.shard_backend = shard_backend
         self.partition = partition
         self.ftns = list(ftns)

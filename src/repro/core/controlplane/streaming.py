@@ -116,6 +116,10 @@ class GatewayStats:
     stall_wall_s: float = 0.0          # coordinator claim wait, summed
     overlap_fraction: float = 0.0
     admit_stall_ms: float = 0.0
+    # admission sweeps (and their cells) the gateway's planners scored on
+    # a device tier (batch_backend "jax" / "pallas"); 0 on numpy
+    device_sweeps: int = 0
+    device_cells: int = 0
 
 
 class StreamingGateway:
@@ -694,6 +698,7 @@ class StreamingGateway:
     def stats(self) -> GatewayStats:
         lat = np.asarray(self._latency) if self._latency else np.zeros(1)
         sizes = self._batch_sizes or [0]
+        planners = {id(p): p for p in (self._batch_planner, self.planner)}
         return GatewayStats(
             n_jobs=len(self._arrival_t),
             n_batches=len(self._batch_sizes),
@@ -715,4 +720,6 @@ class StreamingGateway:
                     1.0) if self.plan_wall_s > 0 else 0.0),
             admit_stall_ms=(
                 1000.0 * self.stall_wall_s / self.n_pipelined_batches
-                if self.n_pipelined_batches else 0.0))
+                if self.n_pipelined_batches else 0.0),
+            device_sweeps=sum(p.device_sweeps for p in planners.values()),
+            device_cells=sum(p.device_cells for p in planners.values()))

@@ -37,9 +37,10 @@ Design notes for jit stability:
   bounded;
 * both kernels evaluate f32 CI and accumulate the prefix sums in f64
   (~1e-7 relative emission error, memory-bound CPU passes at half the
-  bandwidth); the batched kernel runs under ``jax.experimental.enable_x64``
-  only so its *time and index* math (hour boundaries, day-of-week flips)
-  lands exactly where the numpy oracle puts it.
+  bandwidth); the batched kernel runs under ``jax.enable_x64`` only so
+  its *time and index* math (hour boundaries, day-of-week flips) lands
+  exactly where the numpy oracle puts it. (The Pallas tier in
+  ``grid_pallas`` has no 64-bit values at all.)
 """
 from __future__ import annotations
 
@@ -55,14 +56,13 @@ from repro.core.carbon.field import (CarbonField, CarbonWindow, default_field,
 from repro.core.carbon.intensity import REGIONS, get_calibration
 from repro.core.carbon.path import NetworkPath
 
-try:                                   # gate: jax is optional at runtime
+try:                                   # jax is optional: numpy stays the oracle
     import jax
+except ImportError:                    # pragma: no cover - env without jax
+    jax = None
+HAVE_JAX = jax is not None
+if HAVE_JAX:
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except Exception:                      # pragma: no cover - env without jax
-    jax, jnp, enable_x64 = None, None, None
-    HAVE_JAX = False
 
 _WINDOW_HOURS = 24 * 14                # per-anchor horizon (2 weeks)
 _GRID_BUCKET = 512                     # rate-grid length rounding
@@ -336,17 +336,16 @@ def _kernel(zbase, zamp, zdip, znamp, zpeak, znoise, cal_a, cal_b,
 
     vcell = jax.vmap(cell, in_axes=(0, 0, 0, 0, None, None))
     if n_dev > 1:                      # optional scale-out across devices
-        from repro.models.layers import shard_map_compat
         if mesh is None:               # undeclared: every visible device
             mesh = jax.sharding.Mesh(np.array(jax.devices()[:n_dev]),
                                      ("cells",))
         axis = mesh.axis_names[0]
         spec = jax.sharding.PartitionSpec
-        vcell = shard_map_compat(
+        vcell = jax.shard_map(
             vcell, mesh=mesh,
             in_specs=(spec(axis), spec(axis), spec(axis),
                       spec(axis), spec(), spec()),
-            out_specs=spec(axis))
+            out_specs=spec(axis), check_vma=False)
     return vcell(pair_idx, w_dev, n_steps, rem, prefix, ci)         # (C,2,S)
 
 
@@ -361,13 +360,6 @@ def _batch_kernel():
         _kernel_jit = jax.jit(_kernel, static_argnames=(
             "n_grid", "n_slots", "slot_stride", "dt_s", "n_dev", "mesh"))
     return _kernel_jit
-
-
-def _device_count() -> int:
-    try:
-        return jax.device_count()
-    except Exception:                  # pragma: no cover - backend init race
-        return 1
 
 
 def _iter_chunks(cells: Sequence[CellTask], slot_stride: int,
@@ -431,7 +423,7 @@ def batch_cell_emissions(field: CarbonField, cells: Sequence[CellTask], *,
         else:
             n_dev = 1
     else:
-        n_dev = _device_count() if shard is None or shard else 1
+        n_dev = jax.device_count() if shard is None or shard else 1
         if shard and n_dev < 2:
             n_dev = 1
     out: List[Optional[np.ndarray]] = [None] * len(cells)
@@ -575,21 +567,32 @@ def _chunk_tables(field: CarbonField, cells: Sequence[CellTask], *,
         pair_anchors=[a for a, _ in inv_pair])
 
 
-def _score_chunk(field: CarbonField, cells: Sequence[CellTask], *,
+def chunk_scores(field: CarbonField, cells: Sequence[CellTask], *,
                  dt_s: float, slot_stride: int, n_dev: int,
-                 mesh=None) -> List[np.ndarray]:
+                 mesh=None) -> "jax.Array":
+    """The ``(C_pad, 2, S_pad)`` f64 emission table of one chunk as the
+    device array the kernel returns: with ``n_dev > 1`` its cell axis is
+    sharded over the mesh, which callers can check through
+    ``addressable_shards``."""
     # the cell axis must split evenly across devices for shard_map
     t = _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
                       cell_bucket=math.lcm(_B_CELLS, max(n_dev, 1)))
-    with enable_x64():
-        emis = np.asarray(_batch_kernel()(
+    with jax.enable_x64(True):
+        return _batch_kernel()(
             *t.zcols, t.znoise, t.cal_a, t.cal_b,
             t.h_of_day0, t.day_frac_s, np.int32(t.dow0),
             t.rel0a, t.anchor_idx, t.zone_idx, t.band, t.hnoise,
             t.path_idx, t.pair_idx, t.w_dev, t.n_steps, t.rem,
             n_grid=t.n_grid_pad, n_slots=t.n_slots_pad,
             slot_stride=slot_stride, dt_s=float(dt_s), n_dev=n_dev,
-            mesh=mesh),
-            dtype=np.float64)
+            mesh=mesh)
+
+
+def _score_chunk(field: CarbonField, cells: Sequence[CellTask], *,
+                 dt_s: float, slot_stride: int, n_dev: int,
+                 mesh=None) -> List[np.ndarray]:
+    emis = np.asarray(chunk_scores(field, cells, dt_s=dt_s,
+                                   slot_stride=slot_stride, n_dev=n_dev,
+                                   mesh=mesh), dtype=np.float64)
     return [emis[ci_, :len(c.legs), :c.n_slots]
             for ci_, c in enumerate(cells)]

@@ -1,269 +1,324 @@
 """Fused Pallas planner kernel: the admission-sweep scoring chain in two
-tiled ``pl.pallas_call``s (``CarbonPlanner(batch_backend="pallas")``).
+``pl.pallas_call``s (``CarbonPlanner(batch_backend="pallas")``).
 
 Layer contract: **numpy is the pinned oracle** (see ``grid_jax.py``). The
 jitted lattice path (:func:`grid_jax.batch_cell_emissions`) materializes a
-full ``(C, 2, S)`` emission tensor in HBM and leaves the per-cell
-feasible-argmin to the host; at fleet scale that tensor dominates the
-sweep (a 10^6-job grid is ~4.6 GB of f64 before the host loop even
-starts). This module fuses the whole per-cell chain — CI evaluation,
-f64 prefix-sum accumulation over the rate grid, the per-(anchor, path)
-gather, SLA masking and the per-cell argmin over start slots — so only
-three scalars per cell (best cost / emissions / slot) ever leave the
-kernel.
+full ``(C, 2, S)`` emission tensor and leaves the per-cell feasible-argmin
+to the host. This module fuses the per-cell chain — CI evaluation, the
+prefix sum over the rate grid, the per-(anchor, path) gather, SLA masking
+and the argmin over start slots — so only the winning (cost, emissions,
+slot) of each cell leaves the device.
 
-Two kernels, because the pipeline has two different sequential axes:
+No kernel value is 64-bit (TPU Mosaic has no f64/i64). Time and hour
+math runs on anchor-relative int32 step indices, and the prefix sum is
+carried as a compensated (hi, lo) f32 pair, so a segment
+``E[hi] - E[k]`` keeps the ~1e-7 relative error of an f64 accumulator.
 
-* :func:`_rate_prefix_kernel` — grid ``(A/bA, T/bT)`` with the time axis
-  minor-most; evaluates device CI per (anchor, path) pair directly (no
-  (anchor x zone) lattice detour) and accumulates the *exclusive* f64
-  prefix sum blockwise through a VMEM carry, the ``ssd_scan.py`` scan
-  idiom. Keeps ``grid_jax``'s f32-CI / f64-accumulate split.
-* :func:`_sweep_kernel` — grid ``(C/bC, S/bS)`` with the slot axis
-  minor-most; per block it gathers prefix segments for each cell's legs,
-  applies the drift-scale table, masks infeasible slots (deadline count +
-  carbon budget) and folds a *running first-min* (cost, emissions, slot)
-  in VMEM scratch, the ``flash_attention.py`` online-reduction idiom with
-  ``pl.when`` init/finalize. Padded cells carry ``n_valid = 0`` so every
-  slot masks to +inf and the pads never win.
+The rate grid of every (anchor, path) pair is laid out **slot-major**:
+``(pair, phase, plane, hop, row)`` with planes [E hi, E lo, r], where
+step ``t = row * stride + phase`` and ``stride`` is the slot spacing in
+steps. A start slot ``s`` is then lane ``s`` of the phase-0 slab, and the
+step ``s * stride + n - 1`` a transfer of ``n`` steps ends on is lane
+``s + (n - 1) // stride`` of the phase ``(n - 1) % stride`` slab — a
+gather becomes a slab copy plus one lane rotation.
 
-Execution: ``interpret=True`` on CPU hosts (CI runs the kernel
-end-to-end through the XLA interpreter; correctness, not speed), compiled
-on accelerator backends. The f64 accumulate means TPU compilation needs
-an x64-capable lowering; hosts where the compiled call fails fall back to
-the jitted jax path at the planner level (``CarbonPlanner`` degrades
-``batch_backend="pallas"`` -> ``"jax"`` and warns once). Equivalence with
-the numpy ``plan_batch`` oracle (same cells, emissions <= 1e-4 relative)
-is pinned by ``tests/test_grid_pallas.py``.
+* :func:`_rate_prefix_kernel` — grid ``(A, R/L)`` over pairs and blocks of
+  ``L = 128`` rows. Per block it evaluates device CI phase by phase
+  (hour noise from ``K`` per-row candidate hours, picked by integer
+  compares), accumulates the within-row exclusive prefix, scans the row
+  totals across lanes and carries the block total in VMEM scratch.
+* :func:`_sweep_kernel` — grid ``(C,)``, one cell per step. Scalar
+  prefetch holds each cell's pair rows and phase; the kernel copies just
+  the slabs the cell reads out of HBM, rotates them, weighs hops, applies
+  the drift-scale row, masks infeasible slots (deadline count, carbon
+  budget) and takes the first min over slots.
+
+Execution: ``interpret=True`` where the default backend is the CPU (CI
+runs the kernels through the XLA interpreter: correctness, not speed),
+compiled everywhere else. A kernel that fails to lower raises.
+Equivalence with the numpy ``plan_batch`` oracle (same cells, emissions
+<= 1e-4 relative) is pinned by ``tests/test_grid_pallas.py``; the v5e
+compile of both kernels by ``tests/test_chip_compile.py``.
 """
 from __future__ import annotations
 
 import functools
-import math
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.carbon.field import CarbonField
 from repro.core.carbon.path import NetworkPath
-from repro.core.scheduler.grid_jax import (_B_CELLS, CellTask, HAVE_JAX,
-                                           _chunk_tables, _iter_chunks)
+from repro.core.scheduler.grid_jax import (_B_CELLS, _MAX_ELEMS, CellTask,
+                                           HAVE_JAX, _chunk_tables,
+                                           _iter_chunks, _round_up)
 
-try:                                   # gate: Pallas is optional at runtime
+if HAVE_JAX:
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    PALLAS_AVAILABLE = True
-except Exception:                      # pragma: no cover - env without pallas
-    jax, jnp, enable_x64, pl, pltpu = None, None, None, None, None
-    PALLAS_AVAILABLE = False
 
-_B_PAIR_BLK = 8                        # pairs per rate-kernel block
-_B_GRID_BLK = 512                      # grid steps per rate-kernel block
-_B_SLOT_BLK = 16                       # slots per sweep-kernel block
-# pairs*hops*grid budget per pallas_call: the sweep kernel streams cell
-# blocks past the *whole* chunk window (prefix f64 + rate f32 stay
-# resident), so the chunk budget is what bounds that working set — far
-# below grid_jax's 32M-element HBM budget by design.
-_MAX_ELEMS_PALLAS = 2 * 1024 * 1024
-
-# per-cell f64 row fed to the sweep kernel: [n_steps, rem_s, n_valid,
-# dur_s, w_perf/slack, w_carbon, budget_g, submitted_t]
-_CELL_COLS = 8
+_LANES = 128                           # rows per rate block (one vreg wide)
+_HOP_COLS = 8                          # [base, amp, dip, namp, peak, band,
+                                       #  cal_a, cal_b] per (pair, hop)
+_CELL_COLS = 8                         # [rem, dur, w_perf/slack, w_carbon,
+                                       #  budget_g, 0, 0, 0] per cell
+_WEEKEND = np.float32(0.94)
 
 
-def _rate_prefix_kernel(pp_ref, zn_ref, hn_ref, rel0_ref, tc_ref,
-                        r_ref, e_ref, carry_ref, *, bt: int, dt_s: float,
-                        w_hours: int):
-    """Device-CI rates + blockwise exclusive f64 prefix over the time axis.
+def _add2(ah, al, bh, bl):
+    """(ah + al) + (bh + bl) as a compensated f32 pair: the high parts sum
+    with TwoSum, so the rounding error of ``ah + bh`` lands in the low
+    part exactly."""
+    s = ah + bh
+    bp = s - ah
+    err = (ah - (s - bp)) + (bh - bp)
+    return s, (al + bl) + err
 
-    Block shapes: pp (bA, H, 6) f32 per-(pair, hop) params [base, amp,
-    dip, noise_amp, peak, band]; zn/hn (bA, H, W) f32 hourly noise rows;
-    rel0 (bA, 1) f64 anchor-relative start; tc (5,) f64 time constants
-    [h_of_day0, day_frac_s, dow0, cal_a, cal_b]. Writes r (bA, H, bt) f32
-    and the exclusive prefix E (bA, H, bt) f64; the running row total
-    carries across time blocks in VMEM scratch (ssd_scan idiom).
+
+def _rate_prefix_kernel(hp_ref, zk_ref, hk_ref, ti_ref, tf_ref,
+                        tab_ref, chi_ref, clo_ref, *,
+                        n_phase: int, n_cand: int, sph: int, spd: int,
+                        dt_s: float):
+    """Device-CI rates and the exclusive compensated prefix of one pair
+    over one block of ``L`` rows.
+
+    Blocks: hp (1, H, 8) per-hop params; zk/hk (1, K, H, L) zone/hop
+    noise at the K candidate hours of each row; ti (1, 2, L) int32
+    [phase of the row's first step within its hour, step-of-day of the
+    row's first step]; tf (1, 3, L) f32 [sub-step anchor seconds,
+    weekday factor of the row's first day, of the next day]. Writes
+    tab (1, P, 3, H, L) planes [E hi, E lo, r]; chi/clo (H, L) carry the
+    prefix across row blocks.
     """
-    ti = pl.program_id(1)
+    lb = pl.program_id(1)
 
-    @pl.when(ti == 0)
+    @pl.when(lb == 0)
     def _init():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
+        chi_ref[...] = jnp.zeros_like(chi_ref)
+        clo_ref[...] = jnp.zeros_like(clo_ref)
 
-    tc = tc_ref[...]
-    t_idx = (ti * bt
-             + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bt), 2))
-    t_rel = rel0_ref[...][:, :, None] + dt_s * t_idx            # (bA,1,bt) f64
-    # time/index math in f64 (hour boundaries must land exactly); the CI
-    # value chain in f32 — grid_jax._kernel's documented split
-    hour = jnp.clip((t_rel // 3600.0).astype(jnp.int32), 0, w_hours - 1)
-    hod = ((tc[0] + t_rel / 3600.0) % 24.0).astype(jnp.float32)
-    dow = ((tc[2].astype(jnp.int32)
-            + jnp.floor((t_rel + tc[1]) / 86400.0).astype(jnp.int32)) % 7)
-    pp = pp_ref[...]
-    base, amp, dip = pp[:, :, 0:1], pp[:, :, 1:2], pp[:, :, 2:3]
-    namp, peak, band = pp[:, :, 3:4], pp[:, :, 4:5], pp[:, :, 5:6]
-    v = base + amp * jnp.cos(2 * np.pi * (hod - peak) / 24.0)
-    v = v - dip * jnp.exp(-0.5 * ((hod - 13.0) / 2.5) ** 2)
-    v = jnp.where((dow == 5) | (dow == 6), v * 0.94, v)
-    hb = jnp.broadcast_to(hour, v.shape)
-    v = v + namp * jnp.take_along_axis(zn_ref[...], hb, axis=2)
-    v = jnp.maximum(v, 1.0)
-    v = jnp.maximum(tc[3].astype(jnp.float32) * v
-                    + tc[4].astype(jnp.float32), 0.5)
-    r = v * (1.0 + 0.02 * band
-             + 0.005 * jnp.take_along_axis(hn_ref[...], hb, axis=2))
-    r64 = r.astype(jnp.float64)
-    csum = jnp.cumsum(r64, axis=2)
-    e_ref[...] = carry_ref[...][:, :, None] + (csum - r64)
-    carry_ref[...] += csum[:, :, -1]
-    r_ref[...] = r.astype(jnp.float32)
+    hp = hp_ref[0]
+    base, amp, dip = hp[:, 0:1], hp[:, 1:2], hp[:, 2:3]
+    namp, peak, band = hp[:, 3:4], hp[:, 4:5], hp[:, 5:6]
+    cal_a, cal_b = hp[:, 6:7], hp[:, 7:8]
+    ti, tf = ti_ref[0], tf_ref[0]
+    offh, mo = ti[0:1], ti[1:2]
+    fsec, wk_a, wk_b = tf[0:1], tf[1:2], tf[2:3]
+    shape = chi_ref.shape
+
+    def phase(j, acc):
+        hi, lo = acc
+        m = mo + j
+        wrap = m >= spd                                  # next day
+        m = jnp.where(wrap, m - spd, m)
+        hod = (m.astype(jnp.float32) * dt_s + fsec) / 3600.0
+        z, u = zk_ref[0, 0], hk_ref[0, 0]
+        for k in range(1, n_cand):                       # hour of step j
+            nxt = offh + j >= k * sph
+            z = jnp.where(nxt, zk_ref[0, k], z)
+            u = jnp.where(nxt, hk_ref[0, k], u)
+        v = base + amp * jnp.cos(2 * np.pi * (hod - peak) / 24.0)
+        v = v - dip * jnp.exp(-0.5 * ((hod - 13.0) / 2.5) ** 2)
+        v = v * jnp.where(wrap, wk_b, wk_a)
+        v = jnp.maximum(v + namp * z, 1.0)
+        v = jnp.maximum(cal_a * v + cal_b, 0.5)
+        r = v * (1.0 + 0.02 * band + 0.005 * u)
+        tab_ref[0, j, 0] = hi
+        tab_ref[0, j, 1] = lo
+        tab_ref[0, j, 2] = r
+        return _add2(hi, lo, r, jnp.zeros_like(r))
+
+    zero = jnp.zeros(shape, jnp.float32)
+    th, tl = jax.lax.fori_loop(0, n_phase, phase, (zero, zero))
+    # inclusive lane scan of the row totals (Hillis-Steele, compensated)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    ih, il = th, tl
+    d = 1
+    while d < shape[1]:
+        keep = lane >= d
+        ih, il = _add2(ih, il,
+                       jnp.where(keep, pltpu.roll(ih, d, 1), 0.0),
+                       jnp.where(keep, pltpu.roll(il, d, 1), 0.0))
+        d *= 2
+    first = lane >= 1
+    bh, bl = _add2(chi_ref[...], clo_ref[...],
+                   jnp.where(first, pltpu.roll(ih, 1, 1), 0.0),
+                   jnp.where(first, pltpu.roll(il, 1, 1), 0.0))
+    last = shape[1] - 1
+    chi, clo = _add2(chi_ref[...], clo_ref[...],
+                     jnp.broadcast_to(ih[:, last:], shape),
+                     jnp.broadcast_to(il[:, last:], shape))
+    chi_ref[...] = chi
+    clo_ref[...] = clo
+
+    def rebase(j, carry):
+        eh, el = _add2(bh, bl, tab_ref[0, j, 0], tab_ref[0, j, 1])
+        tab_ref[0, j, 0] = eh
+        tab_ref[0, j, 1] = el
+        return carry
+
+    jax.lax.fori_loop(0, n_phase, rebase, 0)
 
 
-def _sweep_kernel(e_ref, r_ref, scl_ref, pidx_ref, wd_ref, sla_ref,
-                  best_ref, bcost_ref, bemis_ref, bslot_ref, *,
-                  stride: int, dt_s: float, slot_s: float, t_pad: int,
-                  bs: int, ns_blocks: int):
-    """Gather + SLA mask + online first-min argmin over start slots.
+def _sweep_kernel(pidx_ref, ph_ref, sh_ref, nval_ref, tab_hbm, scl_hbm,
+                  w_ref, cf_ref, out_ref, tbuf, sbuf, sem, *,
+                  dt_s: float, slot_s: float):
+    """One cell: both legs' segment emissions over every start slot, the
+    SLA mask and the first-min argmin.
 
-    Per (cell-block, slot-block) iteration: segment emissions for both
-    legs come from two prefix gathers (E[hi] - E[k]) plus the pro-rated
-    last-step rate, are weighted by the per-leg device-power rows,
-    multiplied by the drift-scale table and summed over legs; infeasible
-    slots (index >= n_valid, or emissions over the carbon budget) mask to
-    +inf; a strict-< running min in VMEM scratch preserves numpy's
-    first-min argmin tie-break across blocks (flash_attention idiom).
-    Writes (cost, emissions, slot) per cell at the last slot block.
+    Scalar prefetch: pidx (2C,) pair row per leg, ph (C,) phase of the
+    last step, sh (C,) lane rotation that brings row ``s + o`` to lane
+    ``s``, nval (C,) count of deadline-feasible slots (0 for pad cells,
+    so every slot masks to +inf). ``tab_hbm`` (A, P, 3, H, L) and
+    ``scl_hbm`` (A, 1, L) stay in HBM: per leg the kernel copies the
+    [E hi, E lo, r] slabs at the last-step phase and at phase 0, and the
+    pair's drift-scale row. w (1, 2, H, 1) hop weights; cf (1, 1, 8) the
+    cell's f32 row. Writes lanes [cost, emissions, slot] of out.
     """
-    si = pl.program_id(1)
+    c = pl.program_id(0)
+    ph = ph_ref[c]
+    copies = []
+    for leg in range(2):
+        a = pidx_ref[2 * c + leg]
+        copies += [
+            pltpu.make_async_copy(tab_hbm.at[a, ph], tbuf.at[leg, 0],
+                                  sem.at[leg, 0]),
+            pltpu.make_async_copy(tab_hbm.at[a, 0], tbuf.at[leg, 1],
+                                  sem.at[leg, 1]),
+            pltpu.make_async_copy(scl_hbm.at[a], sbuf.at[leg],
+                                  sem.at[leg, 2])]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+    sh = sh_ref[c]
+    cf = cf_ref[0]
+    rem, dur, wp, wc, budget = (cf[:, 0:1], cf[:, 1:2], cf[:, 2:3],
+                                cf[:, 3:4], cf[:, 4:5])
+    w = w_ref[0]
 
-    @pl.when(si == 0)
-    def _init():
-        bcost_ref[...] = jnp.full_like(bcost_ref, jnp.inf)
-        bemis_ref[...] = jnp.full_like(bemis_ref, jnp.inf)
-        bslot_ref[...] = jnp.zeros_like(bslot_ref)
+    def leg_emis(leg):
+        end, start = tbuf[leg, 0], tbuf[leg, 1]          # (3, H, L) each
+        seg = ((pltpu.roll(end[0], sh, 1) - start[0])
+               + (pltpu.roll(end[1], sh, 1) - start[1]))
+        last = pltpu.roll(end[2], sh, 1)
+        e = (jnp.sum(w[leg] * seg, axis=0, keepdims=True) * dt_s
+             + jnp.sum(w[leg] * last, axis=0, keepdims=True) * rem) / 3.6e6
+        return e * sbuf[leg]
 
-    slots = si * bs + jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
-    row = sla_ref[...]                                  # (bC, 8) f64
-    n = row[:, 0].astype(jnp.int32)                     # n_steps
-    rem, nval, dur = row[:, 1], row[:, 2], row[:, 3]
-    wp, wc, budget, sub = row[:, 4], row[:, 5], row[:, 6], row[:, 7]
-    k = slots * stride                                  # (bs,) i32
-    # valid slots satisfy hi = k + n - 1 <= T - 1 by grid construction;
-    # the clip only tames padded slots/cells, which mask to +inf below
-    hi = jnp.clip(k[None, :] + n[:, None] - 1, 0, t_pad - 1)
-    kc = jnp.minimum(k, t_pad - 1)[None, None, None, :]
-    p = pidx_ref[...]                                   # (bC, 2) i32
-    h_hops = wd_ref.shape[2]
-    hh = jax.lax.broadcasted_iota(jnp.int32, (h_hops,), 0)
-    rowbase = (p[:, :, None] * h_hops + hh[None, None, :]) * t_pad
-    e_flat = e_ref[...].reshape(-1)
-    r_flat = r_ref[...].reshape(-1)
-    idx_hi = rowbase[:, :, :, None] + hi[:, None, None, :]
-    seg = jnp.take(e_flat, idx_hi) - jnp.take(e_flat, rowbase[..., None] + kc)
-    last = jnp.take(r_flat, idx_hi).astype(jnp.float64)
-    wd = wd_ref[...]                                    # (bC, 2, H) f64
-    # per-leg emissions: ((sum_h w*seg)*dt + (sum_h w*last)*rem) / 3.6e6,
-    # the einsum order batch_cell_emissions uses (oracle-equivalent)
-    leg = (jnp.einsum("clh,clhs->cls", wd, seg) * dt_s
-           + jnp.einsum("clh,clhs->cls", wd, last)
-           * rem[:, None, None]) / 3.6e6
-    sl = jnp.take(scl_ref[...], p, axis=0)              # (bC, 2, bs)
-    emis = jnp.sum(leg * sl, axis=1)                    # (bC, bs)
-    # numpy's exact op order for the perf term: (sub + slot_s*k + dur) - sub
-    ts = sub[:, None] + slot_s * slots.astype(jnp.float64)[None, :]
-    cost = wc[:, None] * emis + wp[:, None] * ((ts + dur[:, None])
-                                               - sub[:, None])
-    feas = ((slots.astype(jnp.float64)[None, :] < nval[:, None])
-            & (emis <= budget[:, None]))
+    emis = leg_emis(0) + leg_emis(1)                     # (1, L)
+    slots = jax.lax.broadcasted_iota(jnp.int32, emis.shape, 1)
+    # perf term relative to submission: start slot_s*s + duration
+    cost = wc * emis + wp * (slot_s * slots.astype(jnp.float32) + dur)
+    feas = (slots < nval_ref[c]) & (emis <= budget)
     cost = jnp.where(feas, cost, jnp.inf)
-    j = jnp.argmin(cost, axis=1).astype(jnp.int32)      # first min in block
-    cmin = jnp.take_along_axis(cost, j[:, None], axis=1)[:, 0]
-    emin = jnp.take_along_axis(emis, j[:, None], axis=1)[:, 0]
-    improved = cmin < bcost_ref[...]                    # strict <: first min
-    bslot_ref[...] = jnp.where(improved, si * bs + j, bslot_ref[...])
-    bemis_ref[...] = jnp.where(improved, emin, bemis_ref[...])
-    bcost_ref[...] = jnp.where(improved, cmin, bcost_ref[...])
-
-    @pl.when(si == ns_blocks - 1)
-    def _emit():
-        best_ref[...] = jnp.stack(
-            [bcost_ref[...], bemis_ref[...],
-             bslot_ref[...].astype(jnp.float64)], axis=1)
+    cmin = jnp.min(cost, axis=1, keepdims=True)
+    jmin = jnp.min(jnp.where(cost == cmin, slots, emis.shape[1]),
+                   axis=1, keepdims=True)                # first min
+    emin = jnp.sum(jnp.where(slots == jmin, emis, 0.0), axis=1,
+                   keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
+    out_ref[0] = jnp.where(lane == 0, cmin,
+                           jnp.where(lane == 1, emin,
+                                     jnp.where(lane == 2,
+                                               jmin.astype(jnp.float32),
+                                               0.0)))
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """None = auto: interpret on CPU hosts (correctness under the XLA
-    interpreter), compiled lowering on accelerator backends."""
+    """None = auto: interpret where the default backend is the CPU,
+    compiled lowering everywhere else."""
     if interpret is not None:
         return bool(interpret)
-    try:
-        return jax.default_backend() == "cpu"
-    except Exception:                  # pragma: no cover - backend init race
-        return True
+    return jax.default_backend() == "cpu"
 
 
-def _fused(pp, zn, hn, rel0, tc, pidx, wd, sla, scl, *, t_pad: int,
-           stride: int, dt_s: float, slot_s: float, interpret: bool):
-    """The fused sweep for one chunk: rate+prefix kernel over the padded
-    (pair, hop, grid) window, then the gather/mask/argmin sweep kernel
-    over (cell, slot) blocks. Returns (C_pad, 3) f64 [cost, emis, slot]."""
-    a_pad, h_hops, w_hours = zn.shape
-    ba = min(_B_PAIR_BLK, a_pad)
-    bt = min(_B_GRID_BLK, t_pad)
-    rate = functools.partial(_rate_prefix_kernel, bt=bt, dt_s=dt_s,
-                             w_hours=w_hours)
-    r, e = pl.pallas_call(
+class KernelInputs(NamedTuple):
+    """Host-built arrays of one chunk, in ``_fused`` argument order."""
+    hp: np.ndarray                     # (A, H, 8) f32
+    zk: np.ndarray                     # (A, K, H, L) f32
+    hk: np.ndarray                     # (A, K, H, L) f32
+    ti: np.ndarray                     # (A, 2, L) i32
+    tf: np.ndarray                     # (A, 3, L) f32
+    pidx: np.ndarray                   # (2C,) i32
+    ph: np.ndarray                     # (C,) i32
+    sh: np.ndarray                     # (C,) i32
+    nval: np.ndarray                   # (C,) i32
+    w: np.ndarray                      # (C, 2, H, 1) f32
+    cf: np.ndarray                     # (C, 1, 8) f32
+    scl: np.ndarray                    # (A, 1, L) f32
+
+
+def _rate_table(hp, zk, hk, ti, tf, *, stride: int, dt_s: float,
+                interpret: bool):
+    """The rate/prefix kernel over every (pair, row block): the packed
+    (A, P, 3, H, L) f32 table of [E hi, E lo, r] planes."""
+    a_pad, n_cand, h_hops, width = zk.shape
+    dt = int(dt_s)
+    rate = functools.partial(_rate_prefix_kernel, n_phase=stride,
+                             n_cand=n_cand, sph=3600 // dt, spd=86400 // dt,
+                             dt_s=dt_s)
+    blk = pl.BlockSpec((1, stride, 3, h_hops, _LANES),
+                       lambda a, b: (a, 0, 0, 0, b))
+    return pl.pallas_call(
         rate,
-        grid=(a_pad // ba, t_pad // bt),
+        grid=(a_pad, width // _LANES),
         in_specs=[
-            pl.BlockSpec((ba, h_hops, 6), lambda a, t: (a, 0, 0)),
-            pl.BlockSpec((ba, h_hops, w_hours), lambda a, t: (a, 0, 0)),
-            pl.BlockSpec((ba, h_hops, w_hours), lambda a, t: (a, 0, 0)),
-            pl.BlockSpec((ba, 1), lambda a, t: (a, 0)),
-            pl.BlockSpec((5,), lambda a, t: (0,)),
+            pl.BlockSpec((1, h_hops, _HOP_COLS), lambda a, b: (a, 0, 0)),
+            pl.BlockSpec((1, n_cand, h_hops, _LANES),
+                         lambda a, b: (a, 0, 0, b)),
+            pl.BlockSpec((1, n_cand, h_hops, _LANES),
+                         lambda a, b: (a, 0, 0, b)),
+            pl.BlockSpec((1, 2, _LANES), lambda a, b: (a, 0, b)),
+            pl.BlockSpec((1, 3, _LANES), lambda a, b: (a, 0, b)),
         ],
-        out_specs=[
-            pl.BlockSpec((ba, h_hops, bt), lambda a, t: (a, 0, t)),
-            pl.BlockSpec((ba, h_hops, bt), lambda a, t: (a, 0, t)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((a_pad, h_hops, t_pad), jnp.float32),
-            jax.ShapeDtypeStruct((a_pad, h_hops, t_pad), jnp.float64),
-        ],
-        scratch_shapes=[pltpu.VMEM((ba, h_hops), jnp.float64)],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct((a_pad, stride, 3, h_hops, width),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((h_hops, _LANES), jnp.float32),
+                        pltpu.VMEM((h_hops, _LANES), jnp.float32)],
         interpret=interpret,
-    )(pp, zn, hn, rel0, tc)
-    c_pad = pidx.shape[0]
-    s_pad = scl.shape[1]
-    bc = min(_B_CELLS, c_pad)
-    bs = min(_B_SLOT_BLK, s_pad)
-    ns_blocks = s_pad // bs
-    sweep = functools.partial(_sweep_kernel, stride=stride, dt_s=dt_s,
-                              slot_s=slot_s, t_pad=t_pad, bs=bs,
-                              ns_blocks=ns_blocks)
+    )(hp, zk, hk, ti, tf)
+
+
+def _sweep(tab, pidx, ph, sh, nval, w, cf, scl, *, dt_s: float,
+           slot_s: float, interpret: bool):
+    """The per-cell sweep kernel over a rate table: (C, 1, 128) f32 with
+    lanes [cost, emissions, slot]."""
+    h_hops, width = tab.shape[3:]
+    c_pad = ph.shape[0]
+    sweep = functools.partial(_sweep_kernel, dt_s=dt_s, slot_s=slot_s)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(c_pad,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((1, 2, h_hops, 1),
+                               lambda c, *_: (c, 0, 0, 0)),
+                  pl.BlockSpec((1, 1, _CELL_COLS), lambda c, *_: (c, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, _LANES), lambda c, *_: (c, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, 2, 3, h_hops, width), jnp.float32),
+                        pltpu.VMEM((2, 1, width), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, 3))],
+    )
     return pl.pallas_call(
         sweep,
-        grid=(c_pad // bc, ns_blocks),
-        in_specs=[
-            pl.BlockSpec((a_pad, h_hops, t_pad), lambda c, s: (0, 0, 0)),
-            pl.BlockSpec((a_pad, h_hops, t_pad), lambda c, s: (0, 0, 0)),
-            pl.BlockSpec((a_pad, bs), lambda c, s: (0, s)),
-            pl.BlockSpec((bc, 2), lambda c, s: (c, 0)),
-            pl.BlockSpec((bc, 2, h_hops), lambda c, s: (c, 0, 0)),
-            pl.BlockSpec((bc, _CELL_COLS), lambda c, s: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((bc, 3), lambda c, s: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((c_pad, 3), jnp.float64),
-        scratch_shapes=[
-            pltpu.VMEM((bc,), jnp.float64),
-            pltpu.VMEM((bc,), jnp.float64),
-            pltpu.VMEM((bc,), jnp.int32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((c_pad, 1, _LANES), jnp.float32),
         interpret=interpret,
-    )(e, r, scl, pidx, wd, sla)
+    )(pidx, ph, sh, nval, tab, scl, w, cf)
+
+
+def _fused(hp, zk, hk, ti, tf, pidx, ph, sh, nval, w, cf, scl, *,
+           stride: int, dt_s: float, slot_s: float, interpret: bool):
+    """The fused sweep for one chunk: the rate table, then the per-cell
+    sweep. Returns (C, 1, 128) f32 with lanes [cost, emissions, slot]."""
+    tab = _rate_table(hp, zk, hk, ti, tf, stride=stride, dt_s=dt_s,
+                      interpret=interpret)
+    return _sweep(tab, pidx, ph, sh, nval, w, cf, scl, dt_s=dt_s,
+                  slot_s=slot_s, interpret=interpret)
 
 
 _fused_jit = None                      # one compiled-kernel cache per process
@@ -273,51 +328,84 @@ def _fused_call():
     global _fused_jit
     if _fused_jit is None:
         _fused_jit = jax.jit(_fused, static_argnames=(
-            "t_pad", "stride", "dt_s", "slot_s", "interpret"))
+            "stride", "dt_s", "slot_s", "interpret"))
     return _fused_jit
 
 
-def _best_chunk(field: CarbonField, cells: Sequence[CellTask],
-                sla_rows: np.ndarray, *, dt_s: float, slot_stride: int,
-                slot_s: float,
-                scale_fn: Optional[Callable[[NetworkPath, np.ndarray],
-                                            np.ndarray]],
-                interpret: bool) -> np.ndarray:
+def _kernel_inputs(field: CarbonField, cells: Sequence[CellTask],
+                   sla_rows: np.ndarray, *, dt_s: float, slot_stride: int,
+                   slot_s: float,
+                   scale_fn: Optional[Callable[[NetworkPath, np.ndarray],
+                                               np.ndarray]]
+                   ) -> KernelInputs:
+    """Lay one chunk's cell tables out slot-major for the two kernels.
+
+    All 64-bit time math happens here, on the host: each pair's anchor
+    becomes an int32 step index ``q`` from the hour-aligned window start
+    plus a sub-step remainder, and every row's first step gets its phase
+    within the hour, its step of the day and its weekday factors.
+    """
     t = _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
                       cell_bucket=_B_CELLS)
-    # gather the per-zone params onto (pair, hop) rows: the rate kernel
-    # evaluates device CI directly, no (anchor x zone) lattice detour
-    zbase, zamp, zdip, znamp, zpeak = t.zcols
+    dt = int(dt_s)
+    if dt != dt_s or 3600 % dt:
+        raise ValueError(f"dt_s must be a whole divisor of 3600 s, "
+                         f"got {dt_s}")
+    sph, spd = 3600 // dt, 86400 // dt
+    p = int(slot_stride)
+    if not 1 <= p <= spd:
+        raise ValueError(f"slot_stride must be 1..{spd} steps, got {p}")
+    width = _round_up(-(-t.n_grid_pad // p), _LANES)    # rows, lane-padded
+    a_pad, h_hops = t.path_idx.shape[0], t.n_hops
+    hours = t.znoise.shape[1]
+    # --- per-pair time rows (int math; anchors may be fractional) ---------
+    rel0 = t.rel0a[t.anchor_idx]                        # (A,) f64
+    q = np.floor(rel0 / dt).astype(np.int64)
+    fsec = (rel0 - q * dt).astype(np.float32)
+    u = q[:, None] + p * np.arange(width)[None, :]      # first step of row
+    hour0 = u // sph
+    n_cand = (sph - 1 + p - 1) // sph + 1               # hours a row spans
+    hrs = np.clip(hour0[:, None, :] + np.arange(n_cand)[None, :, None],
+                  0, hours - 1)                         # (A, K, L)
     zid = t.zone_idx[t.path_idx]                        # (A, H)
-    pp = np.stack([zbase[zid], zamp[zid], zdip[zid], znamp[zid],
-                   zpeak[zid], t.band[t.path_idx]],
+    zk = t.znoise[zid[:, None, :, None], hrs[:, :, None, :]]
+    hk = t.hnoise[t.path_idx[:, None, None, None],
+                  np.arange(h_hops)[None, None, :, None],
+                  hrs[:, :, None, :]]
+    hod0 = int(round(t.h_of_day0))
+    day = (u + int(round(t.day_frac_s)) // dt) // spd
+    dow = (t.dow0 + day) % 7
+    ti = np.stack([u - hour0 * sph, (hod0 * sph + u) % spd],
+                  axis=1).astype(np.int32)
+    tf = np.stack([np.broadcast_to(fsec[:, None], u.shape),
+                   np.where(dow >= 5, _WEEKEND, np.float32(1.0)),
+                   np.where((dow + 1) % 7 >= 5, _WEEKEND, np.float32(1.0))],
+                  axis=1).astype(np.float32)
+    zbase, zamp, zdip, znamp, zpeak = t.zcols
+    hp = np.stack([zbase[zid], zamp[zid], zdip[zid], znamp[zid],
+                   zpeak[zid], t.band[t.path_idx],
+                   np.full(zid.shape, t.cal_a), np.full(zid.shape, t.cal_b)],
                   axis=-1).astype(np.float32)
-    zn = t.znoise[zid]                                  # (A, H, W) f32
-    hn = t.hnoise[t.path_idx]                           # (A, H, W) f32
-    rel0 = t.rel0a[t.anchor_idx][:, None]               # (A, 1) f64
-    tc = np.array([t.h_of_day0, t.day_frac_s, float(t.dow0),
-                   float(t.cal_a), float(t.cal_b)])
-    a_pad, s_pad = t.path_idx.shape[0], t.n_slots_pad
-    # the drift-scale hook evaluates host-side into an (A, S) table: a
-    # pair's slot times are anchor + slot_s * k, the same floats the
-    # numpy path hands emission_scale_fn per job
-    scl = np.ones((a_pad, s_pad))
+    # --- drift-scale rows: a pair's slot times are anchor + slot_s * s ----
+    scl = np.ones((a_pad, 1, width), dtype=np.float32)
     if scale_fn is not None:
         for a in range(t.n_pairs):
-            ts = t.pair_anchors[a] + slot_s * np.arange(s_pad)
-            scl[a] = scale_fn(t.pair_paths[a], ts)
+            ts = t.pair_anchors[a] + slot_s * np.arange(width)
+            scl[a, 0] = scale_fn(t.pair_paths[a], ts)
+    # --- per-cell rows (pads: n_steps 1, n_valid 0) -----------------------
     c_pad = t.pair_idx.shape[0]
-    sla = np.zeros((c_pad, _CELL_COLS))
-    sla[:, 0] = t.n_steps                               # pads: 1
-    sla[:, 1] = t.rem                                   # pads: 0
-    sla[:, 6] = np.inf                                  # pads: no budget
-    sla[:len(cells), 2:] = sla_rows                     # pads: n_valid = 0
-    with enable_x64():
-        best = np.asarray(_fused_call()(
-            pp, zn, hn, rel0, tc, t.pair_idx, t.w_dev, sla, scl,
-            t_pad=t.n_grid_pad, stride=slot_stride, dt_s=float(dt_s),
-            slot_s=float(slot_s), interpret=interpret), dtype=np.float64)
-    return best[:len(cells)]
+    last = t.n_steps.astype(np.int64) - 1               # step of the end
+    nval = np.zeros(c_pad, dtype=np.int32)
+    nval[:len(cells)] = sla_rows[:, 0]
+    cf = np.zeros((c_pad, 1, _CELL_COLS), dtype=np.float32)
+    cf[:, 0, 0] = t.rem
+    cf[:len(cells), 0, 1:5] = sla_rows[:, 1:5]
+    return KernelInputs(
+        hp=hp, zk=zk.astype(np.float32), hk=hk.astype(np.float32), ti=ti,
+        tf=tf, pidx=t.pair_idx.reshape(-1).astype(np.int32),
+        ph=(last % p).astype(np.int32),
+        sh=((width - last // p) % width).astype(np.int32), nval=nval,
+        w=t.w_dev.astype(np.float32)[..., None], cf=cf, scl=scl)
 
 
 def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
@@ -333,37 +421,39 @@ def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
     the lattice path materializes never exists.
 
     ``sla_rows`` carries one ``[n_valid, dur_s, w_perf/slack, w_carbon,
-    budget_g, submitted_t]`` row per cell (``n_valid`` = the count of
-    deadline-feasible leading slots, computed host-side because that mask
-    is monotone in the slot index; ``budget_g`` = +inf when the SLA has no
-    carbon budget). ``scale_fn`` is the planner's ``emission_scale_fn``
-    drift hook, evaluated host-side into a per-(anchor, path) slot table.
+    budget_g]`` row per cell (``n_valid`` = the count of deadline-feasible
+    leading slots, computed host-side because that mask is monotone in the
+    slot index; ``budget_g`` = +inf when the SLA has no carbon budget).
+    ``scale_fn`` is the planner's ``emission_scale_fn`` drift hook,
+    evaluated host-side into a per-(anchor, path) slot table.
 
     Returns ``(cost, emis, slot)`` arrays over cells; ``cost = +inf``
-    means no feasible slot (the caller falls back per job). Cost/emission
-    values match the numpy ``plan_batch`` oracle within 1e-4 relative
-    (~1e-7 in practice: f32 CI chain, f64 accumulation — the grid_jax
-    split).
+    means no feasible slot (the caller falls back per job). The kernels
+    compute in f32 and the host widens; cost and emissions match the
+    numpy ``plan_batch`` oracle within 1e-4 relative (~1e-7 in practice).
     """
-    if not PALLAS_AVAILABLE:
+    if not HAVE_JAX:
         raise ImportError(
-            "batch_cell_best needs jax with Pallas support; use "
-            "batch_backend='jax' or the numpy plan_batch oracle")
+            "batch_cell_best needs jax; use the numpy plan_batch oracle")
     sla_rows = np.asarray(sla_rows, dtype=np.float64)
-    if sla_rows.shape != (len(cells), 6):
-        raise ValueError(f"sla_rows must be (n_cells, 6), got "
+    if sla_rows.shape != (len(cells), 5):
+        raise ValueError(f"sla_rows must be (n_cells, 5), got "
                          f"{sla_rows.shape}")
     run_interpret = _resolve_interpret(interpret)
     cost = np.full(len(cells), np.inf)
     emis = np.full(len(cells), np.inf)
     slot = np.zeros(len(cells), dtype=np.int64)
-    for chunk in _iter_chunks(cells, slot_stride, _MAX_ELEMS_PALLAS):
-        best = _best_chunk(field, [cells[j] for j in chunk],
+    for chunk in _iter_chunks(cells, slot_stride, _MAX_ELEMS):
+        x = _kernel_inputs(field, [cells[j] for j in chunk],
                            sla_rows[chunk], dt_s=dt_s,
                            slot_stride=slot_stride, slot_s=slot_s,
-                           scale_fn=scale_fn, interpret=run_interpret)
+                           scale_fn=scale_fn)
+        best = np.asarray(_fused_call()(
+            *x, stride=int(slot_stride), dt_s=float(dt_s),
+            slot_s=float(slot_s), interpret=run_interpret))
         idx = np.asarray(chunk, dtype=np.int64)
-        cost[idx] = best[:, 0]
-        emis[idx] = best[:, 1]
-        slot[idx] = best[:, 2].astype(np.int64)
+        n = len(chunk)
+        cost[idx] = best[:n, 0, 0]
+        emis[idx] = best[:n, 0, 1]
+        slot[idx] = best[:n, 0, 2].astype(np.int64)
     return cost, emis, slot

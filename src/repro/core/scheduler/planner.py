@@ -125,10 +125,9 @@ class CarbonPlanner:
         # additionally fuses the scoring chain + per-cell argmin into the
         # tiled grid_pallas kernel, while single plan()/rescore() calls
         # stay on ``backend`` (small arrays beat jit dispatch there).
-        # None follows ``backend``. The ladder degrades automatically:
-        # "pallas" without Pallas support falls back to "jax" here (and at
-        # runtime if the kernel fails to lower on this backend); "jax"
-        # without jax is an error (no silent oracle-speed planning).
+        # None follows ``backend``. A device tier that cannot run is an
+        # error, here or when its kernel fails to lower: the planner never
+        # swaps one tier for another behind the caller's back.
         if batch_backend is None:
             batch_backend = backend
         if batch_backend in ("jax", "pallas"):
@@ -137,10 +136,6 @@ class CarbonPlanner:
                 raise ImportError(
                     f"batch_backend={batch_backend!r} needs jax; install "
                     f"it or use batch_backend='numpy'")
-        if batch_backend == "pallas":
-            from repro.core.scheduler.grid_pallas import PALLAS_AVAILABLE
-            if not PALLAS_AVAILABLE:
-                batch_backend = "jax"
         self.batch_backend = batch_backend
         # drift hook (the fleet controller's forecast-shock nowcast): a
         # (path, start_times) -> multiplier-array applied to the forecast
@@ -444,8 +439,11 @@ class CarbonPlanner:
     _RESCORE_MIN_CELLS = 512
 
     # observability: cell count of the most recent plan_batch_jax call —
-    # the scale bench reads it to report peak admission-grid size.
+    # the scale bench reads it to report peak admission-grid size — and
+    # the sweeps / cells this planner has scored on a device tier so far.
     last_batch_cells = 0
+    device_sweeps = 0
+    device_cells = 0
 
     def _plan_batch_full(self, jobs: Sequence[TransferJob]) -> List[Plan]:
         if self.batch_backend in ("jax", "pallas") \
@@ -453,41 +451,16 @@ class CarbonPlanner:
             return self.plan_batch_jax(jobs)
         return [self.plan(job) for job in jobs]
 
-    def plan_batch_jax(self, jobs: Sequence[TransferJob], *,
-                       shard=None) -> List[Plan]:
-        """One-jit fleet planning: every job's (FTN x replica x slot) grid
-        is stacked into a single padded/masked cell table and scored by one
-        ``jax.jit`` call per memory chunk (``grid_jax.batch_cell_emissions``
-        — vmap over the job-cell axis, optional shard_map across devices).
-
-        The numpy :meth:`plan_batch` is the pinned oracle: this path must
-        pick the same grid cells with emissions within 1e-4 relative
-        (in practice ~1e-7 — f32 CI chain, f64 time math). Jobs whose
-        layout the batch kernel cannot host (non-dt-aligned slots, a rate
-        grid past the per-cell cap) fall back to the numpy :meth:`plan`.
-        ``shard`` is forwarded to the kernel's device-sharding gate:
-        ``None``/``True``/``False`` as before, or a
-        :class:`~repro.core.scheduler.grid_jax.MeshConfig` declaring the
-        multi-chip mesh (platform, device count, axis name) the cell axis
-        shards over.
-
-        With ``batch_backend="pallas"`` the same cell tables feed
-        ``grid_pallas.batch_cell_best`` instead: the scoring chain *and*
-        each cell's feasible-argmin run fused in a tiled Pallas kernel,
-        so only the per-cell winner (cost, emissions, slot) crosses back
-        to the host — the (cell, leg, slot) emission tensor is never
-        materialized and ``shard`` does not apply. If the kernel cannot
-        run on this backend the planner degrades to ``"jax"`` for the
-        rest of the session (one warning).
-        """
+    def _batch_cells(self, jobs: Sequence[TransferJob], dt_s: float,
+                     stride: int) -> Tuple[list, List[Tuple],
+                                           List[Optional[List[Tuple]]]]:
+        """The stacked cell table of :meth:`plan_batch_jax`: one
+        ``CellTask`` and one SLA row ``[n_valid, dur_s, w_perf/slack,
+        w_carbon, budget_g]`` per (job, FTN, replica) cell, plus per-job
+        metadata (``None`` for a job whose rate grid is past the per-cell
+        cap, which falls back to :meth:`plan`)."""
         from repro.core.scheduler.grid_jax import (CellTask, LegTask,
-                                                   _MAX_GRID,
-                                                   batch_cell_emissions)
-        dt_s = 60.0
-        stride = self.slot_s / dt_s
-        if stride != int(stride) or stride <= 0:
-            return [self.plan(job) for job in jobs]
-        stride = int(stride)
+                                                   _MAX_GRID)
         sender = HOST_PROFILES["storage_frontend"]
         cells: List[CellTask] = []
         sla_rows: List[Tuple] = []     # per cell, aligned with ``cells``
@@ -535,24 +508,54 @@ class CarbonPlanner:
                     job.sla.w_perf / max(job.sla.deadline_s, 1.0),
                     job.sla.w_carbon,
                     job.sla.carbon_budget_g
-                    if job.sla.carbon_budget_g is not None else np.inf,
-                    job.submitted_t))
+                    if job.sla.carbon_budget_g is not None else np.inf))
             meta.append(jcells)
+        return cells, sla_rows, meta
+
+    def plan_batch_jax(self, jobs: Sequence[TransferJob], *,
+                       shard=None) -> List[Plan]:
+        """One-jit fleet planning: every job's (FTN x replica x slot) grid
+        is stacked into a single padded/masked cell table and scored by one
+        ``jax.jit`` call per memory chunk (``grid_jax.batch_cell_emissions``
+        — vmap over the job-cell axis, optional shard_map across devices).
+
+        The numpy :meth:`plan_batch` is the pinned oracle: this path must
+        pick the same grid cells with emissions within 1e-4 relative
+        (in practice ~1e-7 — f32 CI chain, f64 time math). Jobs whose
+        layout the batch kernel cannot host (non-dt-aligned slots, a rate
+        grid past the per-cell cap) fall back to the numpy :meth:`plan`.
+        ``shard`` is forwarded to the kernel's device-sharding gate:
+        ``None``/``True``/``False`` as before, or a
+        :class:`~repro.core.scheduler.grid_jax.MeshConfig` declaring the
+        multi-chip mesh (platform, device count, axis name) the cell axis
+        shards over.
+
+        With ``batch_backend="pallas"`` the same cell tables feed
+        ``grid_pallas.batch_cell_best`` instead: the scoring chain *and*
+        each cell's feasible-argmin run fused in a tiled Pallas kernel,
+        so only the per-cell winner (cost, emissions, slot) crosses back
+        to the host — the (cell, leg, slot) emission tensor is never
+        materialized and ``shard`` does not apply. A kernel that cannot
+        run on this backend raises.
+        """
+        from repro.core.scheduler.grid_jax import batch_cell_emissions
+        dt_s = 60.0
+        stride = self.slot_s / dt_s
+        if stride != int(stride) or stride <= 0:
+            return [self.plan(job) for job in jobs]
+        stride = int(stride)
+        cells, sla_rows, meta = self._batch_cells(jobs, dt_s, stride)
         self.last_batch_cells = len(cells)
+        if cells:
+            self.device_sweeps += 1
+            self.device_cells += len(cells)
         fused = None                   # (cost, emis, slot) per cell
         if cells and self.batch_backend == "pallas":
             from repro.core.scheduler import grid_pallas
-            try:
-                fused = grid_pallas.batch_cell_best(
-                    self.field, cells, sla_rows, dt_s=dt_s,
-                    slot_stride=stride, slot_s=self.slot_s,
-                    scale_fn=self.emission_scale_fn)
-            except Exception as e:     # lowering/backend failure: degrade
-                import warnings
-                warnings.warn(f"pallas planner kernel unavailable "
-                              f"({e!r}); batch_backend degrades to 'jax'",
-                              RuntimeWarning, stacklevel=2)
-                self.batch_backend = "jax"
+            fused = grid_pallas.batch_cell_best(
+                self.field, cells, sla_rows, dt_s=dt_s,
+                slot_stride=stride, slot_s=self.slot_s,
+                scale_fn=self.emission_scale_fn)
         tables = batch_cell_emissions(self.field, cells, dt_s=dt_s,
                                       slot_stride=stride, shard=shard) \
             if cells and fused is None else []

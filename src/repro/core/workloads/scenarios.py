@@ -19,8 +19,9 @@ import dataclasses
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
 from repro.core.carbon import lattice as _lattice
+from repro.core.carbon.intensity import PAPER_WINDOW_T0
 from repro.core.scheduler.overlay import FTN
-from repro.core.scheduler.planner import TransferJob
+from repro.core.scheduler.planner import SLA, TransferJob
 from repro.core.workloads.generators import (DiurnalArrivals, LognormalSizes,
                                              MMPPArrivals, ParetoSizes,
                                              PoissonArrivals, UniformSizes,
@@ -199,6 +200,25 @@ SCENARIOS: Dict[str, Scenario] = {s.name: s for s in [
             replica_sets=(("uc",), ("uc", "site_ne")),
             deadline_h=(6.0, 20.0)),)),
 ]}
+
+
+# --- the admission-sweep scale fleet ----------------------------------------
+# Not an arrival stream: a deterministic job table the planner-scale bench,
+# the chip smoke test and the v5e compile tests cut into fixed-size
+# admission chunks (a 4096-job chunk is ~20.5k cells).
+PLANNER_SCALE_FTNS = (FTN("uc", "skylake", 10.0),
+                      FTN("m1", "apple_m1", 1.2),
+                      FTN("tacc", "cascade_lake", 10.0))
+
+
+def planner_scale_job(i: int, t0: float = PAPER_WINDOW_T0) -> TransferJob:
+    """Job ``i`` of the scale fleet: 20-620 GB, one or two replicas,
+    12-47 h deadlines, anchors cycling over 24 h in 5-minute steps."""
+    return TransferJob(
+        f"s{i}", (20 + (13 * i) % 600) * 1e9,
+        ("uc", "m1") if i % 3 else ("uc",), "tacc",
+        SLA(deadline_s=(12 + i % 36) * 3600.0),
+        t0 + (i % 288) * 300.0)
 
 
 def get_scenario(name: str) -> Scenario:

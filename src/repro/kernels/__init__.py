@@ -3,12 +3,11 @@ reference implementations (``ref.py``) and dispatch wrappers (``ops.py``).
 
 Everything here is exported *lazily*: importing ``repro.kernels`` never
 touches jax, and each attribute resolves its module on first access —
-so a host whose jax build has no Pallas support (or no jax at all) can
-still import the package and probe :data:`PALLAS_AVAILABLE`, and only
-fails, with a clear message, when it actually asks for a kernel. The
-planner's fused admission kernel (``batch_cell_best``) re-exports from
-``repro.core.scheduler.grid_pallas`` so kernel consumers have one
-import surface.
+so a host with no jax at all can still import the package and probe
+:data:`PALLAS_AVAILABLE`, and only fails, with a clear message, when it
+actually asks for a kernel. The planner's fused admission kernel
+(``batch_cell_best``) re-exports from ``repro.core.scheduler.grid_pallas``
+so kernel consumers have one import surface.
 """
 from __future__ import annotations
 
@@ -29,16 +28,19 @@ _probe_cache = None                    # None = not probed yet
 
 
 def pallas_available() -> bool:
-    """True when this jax build can import the Pallas API (probed once;
-    interpret-mode execution still counts — availability is about the
-    API, not about having a TPU)."""
+    """True when jax is installed (probed once; interpret-mode execution
+    still counts — availability is about the API, not about having a
+    TPU). Only a missing jax reads False: any other import error of the
+    Pallas API propagates."""
     global _probe_cache
     if _probe_cache is None:
         try:
+            importlib.import_module("jax")
+        except ImportError:            # pragma: no cover - env without jax
+            _probe_cache = False
+        else:
             importlib.import_module("jax.experimental.pallas")
             _probe_cache = True
-        except Exception:              # pragma: no cover - env without jax
-            _probe_cache = False
     return _probe_cache
 
 
@@ -51,10 +53,8 @@ def __getattr__(name: str):
                              f"{name!r}")
     if not pallas_available():
         raise ImportError(
-            f"repro.kernels.{name} needs jax with Pallas support; this "
-            f"host has none — use the numpy/jax planner backends "
-            f"(CarbonPlanner degrades batch_backend='pallas' to 'jax' "
-            f"automatically)")
+            f"repro.kernels.{name} needs jax; this host has none — plan "
+            f"with CarbonPlanner(batch_backend='numpy'), the oracle")
     return getattr(importlib.import_module(mod), name)
 
 

@@ -21,17 +21,6 @@ from jax import lax
 NEG_INF = -2.0e38  # fp32-safe
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: older releases only ship
-    ``jax.experimental.shard_map`` and spell ``check_vma`` as ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 # ---------------------------------------------------------------- norms ----
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     dtype = x.dtype
@@ -185,9 +174,9 @@ def seq_parallel_attention(q, k, v, *, causal: bool, window: Optional[int],
         return _blockwise_sdpa(ql, kl, vl, q_pos, kv_pos, causal, window,
                                scale, block_kv)
 
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(spec_q, spec_kv, spec_kv),
-                            out_specs=spec_q, check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_q, spec_kv, spec_kv),
+                         out_specs=spec_q, check_vma=False)(q, k, v)
 
 
 def use_seq_parallel(q, k) -> bool:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import MoEConfig
-from repro.models.layers import ffn, shard_map_compat
+from repro.models.layers import ffn
 from repro.runtime.pspec import logical_constraint
 
 
@@ -139,7 +139,7 @@ def _routed_shardmap(params, xt: jax.Array, cfg: MoEConfig, gated: bool):
                              off, e_loc, batch_axes, model_axis)
 
     wg = params.get("wg", params["wu"])
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(spec_x, spec_router, spec_wg, spec_wg, spec_wd),
         out_specs=(spec_x, jax.sharding.PartitionSpec()),

@@ -340,14 +340,9 @@ def _batch_jobs(n=24):
 
 
 def _batch_planner(backend):
-    """Planner on the requested batch backend, skipping when the host
-    can't host it (pallas runs in interpret mode on CPU — slow but
-    exact — and is skipped only when the jax build lacks the API)."""
+    """Planner on the requested batch backend (pallas runs in interpret
+    mode on CPU — slow but exact)."""
     pytest.importorskip("jax")
-    if backend == "pallas":
-        from repro.core.scheduler import grid_pallas
-        if not grid_pallas.PALLAS_AVAILABLE:
-            pytest.skip("jax build without Pallas support")
     return CarbonPlanner(FTNS, batch_backend=backend)
 
 

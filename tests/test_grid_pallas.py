@@ -12,9 +12,6 @@ from repro.core.scheduler import grid_pallas
 from repro.core.scheduler.overlay import FTN
 from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
 
-if not grid_pallas.PALLAS_AVAILABLE:
-    pytest.skip("jax build without Pallas support", allow_module_level=True)
-
 T0 = PAPER_WINDOW_T0
 FTNS = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
         FTN("site_qc", "cascade_lake", 40.0),
@@ -83,31 +80,33 @@ def test_pallas_carbon_budget_mask_in_kernel():
     _three_way(jobs)
 
 
-def test_pallas_construct_time_degrade(monkeypatch):
-    """No Pallas in the jax build: the planner degrades to the jax
-    lattice at construction, never at plan time."""
-    monkeypatch.setattr(grid_pallas, "PALLAS_AVAILABLE", False)
-    pl = CarbonPlanner(FTNS, batch_backend="pallas")
-    assert pl.batch_backend == "jax"
-
-
-def test_pallas_runtime_failure_degrades_to_jax(monkeypatch):
-    """A lowering/backend failure mid-call: warn once, fall through to
-    the lattice path in the same call, stay on "jax" afterwards."""
+def test_pallas_lowering_failure_raises(monkeypatch):
+    """A kernel that fails to lower is an error the caller sees: the
+    planner keeps the tier it was asked for and never re-plans the batch
+    on another one."""
     def boom(*a, **k):
-        raise RuntimeError("no pallas lowering on this backend")
+        raise NotImplementedError("no lowering for this kernel")
 
-    monkeypatch.setattr(grid_pallas, "batch_cell_best", boom)
+    monkeypatch.setattr(grid_pallas, "_fused_call", lambda: boom)
     jobs = [TransferJob(f"rt{i}", 80e9, ("uc",), "tacc",
                         SLA(deadline_s=12 * 3600.0), T0 + i * 600.0)
             for i in range(4)]
     pl = CarbonPlanner(FTNS, batch_backend="pallas")
-    ref = CarbonPlanner(FTNS).plan_batch(jobs)
-    with pytest.warns(RuntimeWarning, match="degrades to 'jax'"):
-        plans = pl.plan_batch_jax(jobs)
-    assert pl.batch_backend == "jax"
-    for a, b in zip(ref, plans):
-        assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn)
+    with pytest.raises(NotImplementedError, match="no lowering"):
+        pl.plan_batch_jax(jobs)
+    assert pl.batch_backend == "pallas"
+
+
+def test_pallas_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode is chosen from the backend alone: on the CPU by
+    default, never on an accelerator, and an explicit choice wins."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert grid_pallas._resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert grid_pallas._resolve_interpret(None) is True
+    assert grid_pallas._resolve_interpret(False) is False
 
 
 def test_pallas_batch_cell_best_validates_sla_rows():
@@ -117,7 +116,7 @@ def test_pallas_batch_cell_best_validates_sla_rows():
     plans = pl.plan_batch_jax(jobs)      # builds a real cell table
     assert plans[0].feasible
     with pytest.raises(ValueError):
-        grid_pallas.batch_cell_best(pl.field, [], np.zeros((1, 6)))
+        grid_pallas.batch_cell_best(pl.field, [], np.zeros((1, 5)))
 
 
 def test_pallas_sharded_fleet_smoke():
