@@ -77,6 +77,7 @@ from repro.core.controlplane.events import (EventLoop, ForecastShock,
                                             JobReady, MigrationCheck,
                                             ReplanTick, StepTick)
 from repro.core.obs import metrics as obs_metrics
+from repro.core.obs.host import span
 from repro.core.obs.observer import as_observer
 from repro.core.obs.trace import Span
 from repro.core.scheduler.overlay import (FTN, MigrationEvent,
@@ -474,17 +475,24 @@ class FleetController:
         self._until = float("inf") if horizon is None else horizon
         n0 = self.n_events
         try:
-            while True:
-                t = self.events.peek_t()
-                if t is None or (until is not None
-                                 and (t >= until if strict else t > until)):
-                    break
-                ev = self.events.pop()
-                self.n_events += 1
-                if self._t_first is None:
-                    self._t_first = ev.t
-                self._t_last = max(self._t_last, ev.t)
-                self._HANDLERS[type(ev)](self, ev)
+            with span("fleet.pump"):
+                while True:
+                    t = self.events.peek_t()
+                    if t is None or (until is not None
+                                     and (t >= until if strict
+                                          else t > until)):
+                        break
+                    ev = self.events.pop()
+                    self.n_events += 1
+                    if self._t_first is None:
+                        self._t_first = ev.t
+                    self._t_last = max(self._t_last, ev.t)
+                    kind = type(ev)
+                    name = self._SPANS.get(kind, "fleet.event")
+                    sp = span(name, queued=len(self.queue)) \
+                        if kind is ReplanTick else span(name)
+                    with sp:
+                        self._HANDLERS[kind](self, ev)
         finally:
             self._wall_s += time.perf_counter() - wall0
         return self.n_events - n0
@@ -898,6 +906,17 @@ class FleetController:
         ReplanTick: _on_replan,
         MigrationCheck: _on_migration_check,
         ForecastShock: _on_shock,
+    }
+    # the host span of each handler (repro.core.obs.host), by event type;
+    # a subclass's own event types record as "fleet.event"
+    _SPANS = {
+        JobArrival: "fleet.arrival",
+        JobReady: "fleet.ready",
+        StepTick: "fleet.step",
+        JobComplete: "fleet.complete",
+        ReplanTick: "fleet.replan",
+        MigrationCheck: "fleet.migrate_check",
+        ForecastShock: "fleet.shock",
     }
 
     # --- reporting ----------------------------------------------------------

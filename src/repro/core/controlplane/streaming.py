@@ -77,6 +77,7 @@ import numpy as np
 
 from repro.core.controlplane.controller import (FleetController, FleetReport)
 from repro.core.controlplane.sharded import PumpQuanta
+from repro.core.obs.host import span
 from repro.core.obs.metrics import MetricsRegistry
 from repro.core.scheduler.planner import CarbonPlanner, Plan, TransferJob
 
@@ -434,21 +435,22 @@ class StreamingGateway:
             t = ctl.events.peek_t()
             return t is not None and (until is None or t <= until)
 
-        while True:
-            self._pump_all(until)
-            if not any(_due(ctl) for ctl in self.controllers):
-                if not self._deferred:
-                    break
-                # capacity can never free again inside the horizon
-                # (nothing due is in flight): over-admit one job rather
-                # than strand the deferred tail, then re-drain
-                now = max(ctl.events.now for ctl in self.controllers)
-                self._promote(now, force=True)
-        run_shards = getattr(self.fleet, "run_shards", None)
-        reports = run_shards(until) if run_shards is not None \
-            else [ctl.run(until) for ctl in self.controllers]
-        rep = FleetReport.merged(reports,
-                                 wall_s=time.perf_counter() - wall0)
+        with span("gw.drain"):
+            while True:
+                self._pump_all(until)
+                if not any(_due(ctl) for ctl in self.controllers):
+                    if not self._deferred:
+                        break
+                    # capacity can never free again inside the horizon
+                    # (nothing due is in flight): over-admit one job rather
+                    # than strand the deferred tail, then re-drain
+                    now = max(ctl.events.now for ctl in self.controllers)
+                    self._promote(now, force=True)
+            run_shards = getattr(self.fleet, "run_shards", None)
+            reports = run_shards(until) if run_shards is not None \
+                else [ctl.run(until) for ctl in self.controllers]
+            rep = FleetReport.merged(reports,
+                                     wall_s=time.perf_counter() - wall0)
         deg = tuple(getattr(self.fleet, "degradations", ()))
         if deg:
             rep = dataclasses.replace(
@@ -494,17 +496,19 @@ class StreamingGateway:
         monotone clock. With ``quanta`` set, the fleet sweep runs as an
         adaptive quantum schedule instead (fine near the batch close
         passed as ``boundary`` and near shock onsets, coarse elsewhere)."""
-        pump_all = getattr(self.fleet, "pump_all", None)
-        if pump_all is not None:
-            if self.quanta is not None:
-                pump_all(t, strict=strict, horizon=horizon,
-                         quanta=self.quanta,
-                         boundaries=() if boundary is None else (boundary,))
+        with span("gw.pump"):
+            pump_all = getattr(self.fleet, "pump_all", None)
+            if pump_all is not None:
+                if self.quanta is not None:
+                    pump_all(t, strict=strict, horizon=horizon,
+                             quanta=self.quanta,
+                             boundaries=() if boundary is None
+                             else (boundary,))
+                else:
+                    pump_all(t, strict=strict, horizon=horizon)
             else:
-                pump_all(t, strict=strict, horizon=horizon)
-        else:
-            for ctl in self.controllers:
-                ctl.pump(t, strict=strict, horizon=horizon)
+                for ctl in self.controllers:
+                    ctl.pump(t, strict=strict, horizon=horizon)
 
     # --- admission planning -------------------------------------------------
     def _plan_timed(self, jobs: List[TransferJob]):
@@ -558,26 +562,27 @@ class StreamingGateway:
         over-capacity jobs join the deferred set (their plan is recomputed
         against the conditions at promotion time, so the admission plan is
         dropped)."""
-        self._batch_sizes.append(len(batch))
-        if self.obs is not None:
-            self.obs.histogram("gw_batch_jobs").observe(float(len(batch)))
-            self.obs.counter("gw_batches_total").inc()
-        if plans is None:
-            plans = self._plan_batch(list(batch))
-        for job, plan in zip(batch, plans):
-            self._arrival_t[job.uuid] = job.submitted_t
-            if (self.max_inflight is not None
-                    and len(self._inflight) >= self.max_inflight):
-                self._deferred.append(_Deferred(job=job, seq=self._seq))
-                self._seq += 1
-                self._n_deferred_total += 1
-                if self.obs is not None:
-                    self.obs.span("defer", t_close, job=job.uuid,
-                                  cause="capacity",
-                                  inflight=len(self._inflight))
-                    self.obs.counter("gw_deferrals_total").inc()
-            else:
-                self._submit(job, plan, at=t_close)
+        with span("gw.admit", jobs=len(batch)):
+            self._batch_sizes.append(len(batch))
+            if self.obs is not None:
+                self.obs.histogram("gw_batch_jobs").observe(float(len(batch)))
+                self.obs.counter("gw_batches_total").inc()
+            if plans is None:
+                plans = self._plan_batch(list(batch))
+            for job, plan in zip(batch, plans):
+                self._arrival_t[job.uuid] = job.submitted_t
+                if (self.max_inflight is not None
+                        and len(self._inflight) >= self.max_inflight):
+                    self._deferred.append(_Deferred(job=job, seq=self._seq))
+                    self._seq += 1
+                    self._n_deferred_total += 1
+                    if self.obs is not None:
+                        self.obs.span("defer", t_close, job=job.uuid,
+                                      cause="capacity",
+                                      inflight=len(self._inflight))
+                        self.obs.counter("gw_deferrals_total").inc()
+                else:
+                    self._submit(job, plan, at=t_close)
 
     def _submit(self, job: TransferJob, plan: Optional[Plan],
                 at: float) -> None:

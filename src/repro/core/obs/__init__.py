@@ -1,8 +1,10 @@
 """Fleet observatory: event-sourced tracing, a dependency-free metrics
-registry with exact cross-shard merge, and carbon/SLA attribution
-rollups.  See ``docs/observability.md`` for the span schema, metric
-names and the overhead gate.
+registry with exact cross-shard merge, carbon/SLA attribution rollups,
+and wall-clock host spans on the profiler's clock.  See
+``docs/observability.md`` for the span schema, metric names and the
+overhead gate.
 """
+from repro.core.obs.host import span
 from repro.core.obs.metrics import (Counter, Gauge, Histogram,
                                     MetricsRegistry, log_bounds, merged,
                                     to_json, to_prometheus)
@@ -19,4 +21,5 @@ __all__ = [
     "observe_pmeter",
     "CarbonLedgerView", "JobRow",
     "JsonlSink", "RingSink", "Span", "TraceSink", "emit_all", "load_jsonl",
+    "span",
 ]

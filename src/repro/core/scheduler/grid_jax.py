@@ -55,6 +55,7 @@ from repro.core.carbon.field import (CarbonField, CarbonWindow, default_field,
                                      make_window, window_ci)
 from repro.core.carbon.intensity import REGIONS, get_calibration
 from repro.core.carbon.path import NetworkPath
+from repro.core.obs.host import span
 
 try:                                   # jax is optional: numpy stays the oracle
     import jax
@@ -427,7 +428,10 @@ def batch_cell_emissions(field: CarbonField, cells: Sequence[CellTask], *,
         if shard and n_dev < 2:
             n_dev = 1
     out: List[Optional[np.ndarray]] = [None] * len(cells)
-    for chunk in _iter_chunks(cells, slot_stride, _MAX_ELEMS):
+    with span("admit.chunks") as sp:
+        chunks = list(_iter_chunks(cells, slot_stride, _MAX_ELEMS))
+        sp.set_metadata(chunks=len(chunks))
+    for chunk in chunks:
         for ci_, emis in zip(chunk, _score_chunk(
                 field, [cells[j] for j in chunk], dt_s=dt_s,
                 slot_stride=slot_stride, n_dev=n_dev, mesh=mesh)):
@@ -574,9 +578,22 @@ def chunk_scores(field: CarbonField, cells: Sequence[CellTask], *,
     device array the kernel returns: with ``n_dev > 1`` its cell axis is
     sharded over the mesh, which callers can check through
     ``addressable_shards``."""
+    t = _device_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
+                       n_dev=n_dev)
+    return _launch(t, dt_s=dt_s, slot_stride=slot_stride, n_dev=n_dev,
+                   mesh=mesh)
+
+
+def _device_tables(field: CarbonField, cells: Sequence[CellTask], *,
+                   dt_s: float, slot_stride: int, n_dev: int
+                   ) -> ChunkTables:
     # the cell axis must split evenly across devices for shard_map
-    t = _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
-                      cell_bucket=math.lcm(_B_CELLS, max(n_dev, 1)))
+    return _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
+                         cell_bucket=math.lcm(_B_CELLS, max(n_dev, 1)))
+
+
+def _launch(t: ChunkTables, *, dt_s: float, slot_stride: int, n_dev: int,
+            mesh=None) -> "jax.Array":
     with jax.enable_x64(True):
         return _batch_kernel()(
             *t.zcols, t.znoise, t.cal_a, t.cal_b,
@@ -588,11 +605,26 @@ def chunk_scores(field: CarbonField, cells: Sequence[CellTask], *,
             mesh=mesh)
 
 
+def _compiled_count() -> int:
+    """Programs the process's lattice-kernel jit holds (see
+    ``grid_pallas._compiled_count``)."""
+    return 0 if _kernel_jit is None else _kernel_jit._cache_size()
+
+
 def _score_chunk(field: CarbonField, cells: Sequence[CellTask], *,
                  dt_s: float, slot_stride: int, n_dev: int,
                  mesh=None) -> List[np.ndarray]:
-    emis = np.asarray(chunk_scores(field, cells, dt_s=dt_s,
-                                   slot_stride=slot_stride, n_dev=n_dev,
-                                   mesh=mesh), dtype=np.float64)
+    with span("admit.inputs", cells=len(cells)) as sp:
+        t = _device_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
+                           n_dev=n_dev)
+        sp.set_metadata(pairs=len(t.path_idx))
+    with span("admit.device"):
+        with span("admit.launch") as sp:
+            n0 = _compiled_count()
+            out = _launch(t, dt_s=dt_s, slot_stride=slot_stride,
+                          n_dev=n_dev, mesh=mesh)
+            sp.set_metadata(compiled=int(_compiled_count() > n0))
+        with span("admit.fetch"):
+            emis = np.asarray(out, dtype=np.float64)
     return [emis[ci_, :len(c.legs), :c.n_slots]
             for ci_, c in enumerate(cells)]

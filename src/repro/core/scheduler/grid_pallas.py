@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.core.carbon.field import CarbonField
 from repro.core.carbon.path import NetworkPath
+from repro.core.obs.host import span
 from repro.core.scheduler.grid_jax import (_B_CELLS, _MAX_ELEMS, CellTask,
                                            HAVE_JAX, _chunk_tables,
                                            _iter_chunks, _round_up)
@@ -280,6 +281,7 @@ def _rate_table(hp, zk, hk, ti, tf, *, stride: int, dt_s: float,
         scratch_shapes=[pltpu.VMEM((h_hops, _LANES), jnp.float32),
                         pltpu.VMEM((h_hops, _LANES), jnp.float32)],
         interpret=interpret,
+        name="rate_prefix",
     )(hp, zk, hk, ti, tf)
 
 
@@ -308,6 +310,7 @@ def _sweep(tab, pidx, ph, sh, nval, w, cf, scl, *, dt_s: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c_pad, 1, _LANES), jnp.float32),
         interpret=interpret,
+        name="sweep",
     )(pidx, ph, sh, nval, tab, scl, w, cf)
 
 
@@ -330,6 +333,12 @@ def _fused_call():
         _fused_jit = jax.jit(_fused, static_argnames=(
             "stride", "dt_s", "slot_s", "interpret"))
     return _fused_jit
+
+
+def _compiled_count() -> int:
+    """Programs the process's ``_fused`` jit holds: it grows by one for
+    each new shape, compiled or loaded from the persistent cache."""
+    return 0 if _fused_jit is None else _fused_jit._cache_size()
 
 
 def _kernel_inputs(field: CarbonField, cells: Sequence[CellTask],
@@ -443,14 +452,25 @@ def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
     cost = np.full(len(cells), np.inf)
     emis = np.full(len(cells), np.inf)
     slot = np.zeros(len(cells), dtype=np.int64)
-    for chunk in _iter_chunks(cells, slot_stride, _MAX_ELEMS):
-        x = _kernel_inputs(field, [cells[j] for j in chunk],
-                           sla_rows[chunk], dt_s=dt_s,
-                           slot_stride=slot_stride, slot_s=slot_s,
-                           scale_fn=scale_fn)
-        best = np.asarray(_fused_call()(
-            *x, stride=int(slot_stride), dt_s=float(dt_s),
-            slot_s=float(slot_s), interpret=run_interpret))
+    with span("admit.chunks") as sp:
+        chunks = list(_iter_chunks(cells, slot_stride, _MAX_ELEMS))
+        sp.set_metadata(chunks=len(chunks))
+    for chunk in chunks:
+        with span("admit.inputs", cells=len(chunk)) as sp:
+            x = _kernel_inputs(field, [cells[j] for j in chunk],
+                               sla_rows[chunk], dt_s=dt_s,
+                               slot_stride=slot_stride, slot_s=slot_s,
+                               scale_fn=scale_fn)
+            sp.set_metadata(pairs=len(x.hp))
+        with span("admit.device"):
+            with span("admit.launch") as sp:
+                n0 = _compiled_count()
+                out = _fused_call()(
+                    *x, stride=int(slot_stride), dt_s=float(dt_s),
+                    slot_s=float(slot_s), interpret=run_interpret)
+                sp.set_metadata(compiled=int(_compiled_count() > n0))
+            with span("admit.fetch"):
+                best = np.asarray(out)
         idx = np.asarray(chunk, dtype=np.int64)
         n = len(chunk)
         cost[idx] = best[:n, 0, 0]

@@ -30,6 +30,7 @@ from repro.core.carbon.field import CarbonField, default_field
 from repro.core.carbon.path import NetworkPath, discover_path
 from repro.core.carbon.score import (carbonscore, transfer_emissions_g,
                                      transfer_emissions_g_reference)
+from repro.core.obs.host import span
 from repro.core.obs.metrics import log_bounds
 from repro.core.scheduler.overlay import FTN
 from repro.core.scheduler.time_shift import expected_transfer_ci
@@ -391,20 +392,21 @@ class CarbonPlanner:
         re-plan of every job whose conditions changed at all — and the
         drifted jobs are themselves re-planned as one batch.
         """
-        if self._metrics is None:
-            return self._plan_batch(jobs, previous, drift_tol)
-        t0 = time.perf_counter()
-        plans = self._plan_batch(jobs, previous, drift_tol)
-        # wall time goes to metrics only, never into spans — traces stay
-        # deterministic under replay, timings do not
-        self._metrics.histogram("planner_plan_batch_wall_s",
-                                bounds=_WALL_BOUNDS) \
-            .observe(time.perf_counter() - t0)
-        self._metrics.counter("planner_plan_batches_total",
-                              backend=self.batch_backend).inc()
-        self._metrics.counter("planner_cells_scored_total").inc(
-            float(sum(p.alternatives for p in plans if p is not None)))
-        return plans
+        with span("admit.sweep", jobs=len(jobs), tier=self.batch_backend):
+            if self._metrics is None:
+                return self._plan_batch(jobs, previous, drift_tol)
+            t0 = time.perf_counter()
+            plans = self._plan_batch(jobs, previous, drift_tol)
+            # wall time goes to metrics only, never into sim-clock spans —
+            # traces stay deterministic under replay, timings do not
+            self._metrics.histogram("planner_plan_batch_wall_s",
+                                    bounds=_WALL_BOUNDS) \
+                .observe(time.perf_counter() - t0)
+            self._metrics.counter("planner_plan_batches_total",
+                                  backend=self.batch_backend).inc()
+            self._metrics.counter("planner_cells_scored_total").inc(
+                float(sum(p.alternatives for p in plans if p is not None)))
+            return plans
 
     def _plan_batch(self, jobs: Sequence[TransferJob],
                     previous: Optional[Sequence[Optional[Plan]]] = None,
@@ -414,9 +416,9 @@ class CarbonPlanner:
         jobs, previous = list(jobs), list(previous)
         out: List[Optional[Plan]] = [None] * len(jobs)
         miss: List[int] = []
-        for i, (prev, re) in enumerate(zip(previous,
-                                           self.rescore_batch(jobs,
-                                                              previous))):
+        with span("admit.rescore", jobs=len(jobs)):
+            rescored = self.rescore_batch(jobs, previous)
+        for i, (prev, re) in enumerate(zip(previous, rescored)):
             if (re is not None and re.feasible
                     and abs(re.predicted_emissions_g
                             - prev.predicted_emissions_g)
@@ -449,7 +451,8 @@ class CarbonPlanner:
         if self.batch_backend in ("jax", "pallas") \
                 and len(jobs) >= self._BATCH_MIN_JOBS:
             return self.plan_batch_jax(jobs)
-        return [self.plan(job) for job in jobs]
+        with span("admit.numpy", jobs=len(jobs)):
+            return [self.plan(job) for job in jobs]
 
     def _batch_cells(self, jobs: Sequence[TransferJob], dt_s: float,
                      stride: int) -> Tuple[list, List[Tuple],
@@ -544,7 +547,9 @@ class CarbonPlanner:
         if stride != int(stride) or stride <= 0:
             return [self.plan(job) for job in jobs]
         stride = int(stride)
-        cells, sla_rows, meta = self._batch_cells(jobs, dt_s, stride)
+        with span("admit.cells") as sp:
+            cells, sla_rows, meta = self._batch_cells(jobs, dt_s, stride)
+            sp.set_metadata(cells=len(cells))
         self.last_batch_cells = len(cells)
         if cells:
             self.device_sweeps += 1
@@ -561,59 +566,61 @@ class CarbonPlanner:
             if cells and fused is None else []
         plans: List[Optional[Plan]] = []
         winners: List[Tuple[int, Tuple[TransferJob, Tuple, int]]] = []
-        for job, jcells in zip(jobs, meta):
-            if jcells is None:
-                plans.append(self.plan(job))
-                continue
-            deadline_t = job.submitted_t + job.sla.deadline_s
-            best: Optional[Tuple] = None
-            n_alt = 0
-            g0: Optional[Tuple] = None   # (dur, emis[0]) greedy capture
-            for idx, ftn, src, paths, gbps, dur, ts in jcells:
-                n_alt += len(ts)
-                if idx is None:
+        with span("admit.select"):
+            for job, jcells in zip(jobs, meta):
+                if jcells is None:
+                    plans.append(self.plan(job))
                     continue
-                if fused is not None:  # in-kernel mask + argmin
-                    c_cost = float(fused[0][idx])
-                    if not math.isfinite(c_cost):
+                deadline_t = job.submitted_t + job.sla.deadline_s
+                best: Optional[Tuple] = None
+                n_alt = 0
+                g0: Optional[Tuple] = None   # (dur, emis[0]) greedy capture
+                for idx, ftn, src, paths, gbps, dur, ts in jcells:
+                    n_alt += len(ts)
+                    if idx is None:
                         continue
-                    if best is None or c_cost < best[0]:
-                        i = int(fused[2][idx])
-                        best = (c_cost, float(fused[1][idx]),
-                                float(ts[i]), ftn, src, paths, gbps, dur)
-                    continue
-                tab = tables[idx]      # (n_legs, n_slots)
-                if self.emission_scale_fn is not None:
-                    tab = tab * np.stack(
-                        [self.emission_scale_fn(p, ts) for p in paths])
-                emis = tab.sum(axis=0)
-                # slot 0 is the submission instant: the scored grid gives
-                # the carbon-blind start-now cell for free (the fused path
-                # never materializes slot values — _resolve_greedy falls
-                # back to one integral there)
-                if self.capture_greedy and gbps > 0 \
-                        and (g0 is None or dur < g0[0]):
-                    g0 = (dur, float(emis[0]))
-                feasible = ts + dur <= deadline_t + 1e-9
-                if job.sla.carbon_budget_g is not None:
-                    feasible &= emis <= job.sla.carbon_budget_g
-                cost = _plan_cost(job.sla, emis, ts + dur - job.submitted_t)
-                if not feasible.any():
-                    continue
-                i = int(np.argmin(np.where(feasible, cost, np.inf)))
-                if best is None or cost[i] < best[0]:
-                    best = (float(cost[i]), float(emis[i]), float(ts[i]),
-                            ftn, src, paths, gbps, dur)
-            if best is None:
-                plans.append(self._fallback(job, n_alt,
-                                            greedy=g0[1] if g0 else None))
-            else:
-                winners.append((len(plans),
-                                (job, best, n_alt, g0[1] if g0 else None)))
-                plans.append(None)     # filled by the batched finisher
-        for (slot, _), plan in zip(winners,
-                                   self._finish_plans([w for _, w
-                                                       in winners])):
+                    if fused is not None:  # in-kernel mask + argmin
+                        c_cost = float(fused[0][idx])
+                        if not math.isfinite(c_cost):
+                            continue
+                        if best is None or c_cost < best[0]:
+                            i = int(fused[2][idx])
+                            best = (c_cost, float(fused[1][idx]),
+                                    float(ts[i]), ftn, src, paths, gbps, dur)
+                        continue
+                    tab = tables[idx]      # (n_legs, n_slots)
+                    if self.emission_scale_fn is not None:
+                        tab = tab * np.stack(
+                            [self.emission_scale_fn(p, ts) for p in paths])
+                    emis = tab.sum(axis=0)
+                    # slot 0 is the submission instant: the scored grid gives
+                    # the carbon-blind start-now cell for free (the fused path
+                    # never materializes slot values — _resolve_greedy falls
+                    # back to one integral there)
+                    if self.capture_greedy and gbps > 0 \
+                            and (g0 is None or dur < g0[0]):
+                        g0 = (dur, float(emis[0]))
+                    feasible = ts + dur <= deadline_t + 1e-9
+                    if job.sla.carbon_budget_g is not None:
+                        feasible &= emis <= job.sla.carbon_budget_g
+                    cost = _plan_cost(job.sla, emis,
+                                      ts + dur - job.submitted_t)
+                    if not feasible.any():
+                        continue
+                    i = int(np.argmin(np.where(feasible, cost, np.inf)))
+                    if best is None or cost[i] < best[0]:
+                        best = (float(cost[i]), float(emis[i]), float(ts[i]),
+                                ftn, src, paths, gbps, dur)
+                if best is None:
+                    plans.append(self._fallback(job, n_alt,
+                                                greedy=g0[1] if g0 else None))
+                else:
+                    winners.append((len(plans),
+                                    (job, best, n_alt, g0[1] if g0 else None)))
+                    plans.append(None)     # filled by the batched finisher
+        with span("admit.finish", plans=len(winners)):
+            done = self._finish_plans([w for _, w in winners])
+        for (slot, _), plan in zip(winners, done):
             plans[slot] = plan
         return plans                   # type: ignore[return-value]
 

@@ -127,3 +127,26 @@ def test_lattice_kernel_compiles_for_v5e(one_chip, chunk):
                 n_slots=t.n_slots_pad, slot_stride=STRIDE, dt_s=DT_S,
                 n_dev=1).compile()
     _assert_fits(compiled)
+
+
+def test_fused_program_names_both_kernels(one_chip):
+    """The two custom calls of the compiled ``_fused`` program carry the
+    kernels' names, so a device trace names them: ``%rate_prefix.<n>``
+    with the (pair, phase, plane=3, hop, lane) result, ``%sweep.<n>``
+    with the (cell, 1, lane) result."""
+    import re
+    pl = CarbonPlanner(list(PLANNER_SCALE_FTNS), batch_backend="pallas")
+    cells, sla, _ = pl._batch_cells([planner_scale_job(i) for i in range(64)],
+                                    DT_S, STRIDE)
+    x = grid_pallas._kernel_inputs(pl.field, cells, np.asarray(sla),
+                                   dt_s=DT_S, slot_stride=STRIDE,
+                                   slot_s=SLOT_S, scale_fn=None)
+    compiled = jax.jit(grid_pallas._fused, static_argnames=(
+        "stride", "dt_s", "slot_s", "interpret")).lower(
+            *[_spec(a, one_chip) for a in x], stride=STRIDE, dt_s=DT_S,
+            slot_s=SLOT_S, interpret=False).compile()
+    calls = [re.match(r"(?:ROOT )?%(\w+)\.\d+ = f32\[([\d,]+)\]", ln.strip())
+             for ln in compiled.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sorted((m.group(1), m.group(2).count(",")) for m in calls) == \
+        [("rate_prefix", 4), ("sweep", 2)]
