@@ -182,7 +182,7 @@ def mesh_phase(n: int, n_dev: int) -> None:
     # where the cell axis landed: score one chunk, read the shards
     cells, _, _ = pl._batch_cells(jobs, 60.0, 60)
     chunk = next(grid_jax._iter_chunks(cells, 60, grid_jax._MAX_ELEMS))
-    out = grid_jax.chunk_scores(pl.field, [cells[j] for j in chunk],
+    out = grid_jax.chunk_scores(pl.field, cells.take(chunk),
                                 dt_s=60.0, slot_stride=60, n_dev=n_dev,
                                 mesh=mesh.build())
     rows = sorted((s.device.id, s.data.shape[0])

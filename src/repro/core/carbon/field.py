@@ -160,6 +160,20 @@ def replay_field_setup(entries: Sequence[Tuple[str, Tuple]]) -> None:
             _FIELD_SETUP.append(entry)
 
 
+def device_weights(coeffs: Tuple, gbps: ArrayLike) -> np.ndarray:
+    """Per-hop power draw (W) at ``gbps`` from the coefficients of
+    :meth:`CarbonField.device_weight_coeffs`; coefficients and ``gbps``
+    broadcast together (one route's rows, or many routes stacked)."""
+    idle, cw, mw, nw, den, c0 = coeffs
+    u_cpu = np.minimum(c0 + (0.4 * gbps) / den, 1.0)
+    u_mem = np.minimum(0.10 + (0.05 * gbps) / den, 1.0)
+    u_nic = np.minimum(gbps / den, 1.0)
+    return (idle
+            + cw * np.minimum(np.maximum(u_cpu, 0.0), 1.0)
+            + mw * np.minimum(np.maximum(u_mem, 0.0), 1.0)
+            + nw * u_nic)
+
+
 class CarbonField:
     """Broadcastable CI queries + prefix-sum emission integrals.
 
@@ -168,6 +182,7 @@ class CarbonField:
     """
 
     _GRID_CACHE_MAX = 128              # ~8×3k f64 per entry ≈ 190 KiB
+    _WEIGHT_CACHE_MAX = 4096           # five (n_hops,) rows per route
 
     def __init__(self, calibrated: bool = True):
         self.calibrated = calibrated
@@ -424,6 +439,22 @@ class CarbonField:
         vectors; the scalar result is float-identical to
         :meth:`_device_weights` (same clamp and summation order).
         """
+        return self._weight_entry(path, sender, receiver, parallelism,
+                                  concurrency)[2]
+
+    def device_weight_coeffs(self, path: NetworkPath, sender: HostPowerModel,
+                             receiver: HostPowerModel, parallelism: int,
+                             concurrency: int) -> Tuple:
+        """The per-hop coefficients ``(idle, cpu_w, mem_w, nic_w, den,
+        c0)`` the :meth:`device_weight_fn` closure evaluates:
+        :func:`device_weights` of them and a gbps is the closure's value,
+        float for float, so many routes can be evaluated in one pass."""
+        return self._weight_entry(path, sender, receiver, parallelism,
+                                  concurrency)[1]
+
+    def _weight_entry(self, path: NetworkPath, sender: HostPowerModel,
+                      receiver: HostPowerModel, parallelism: int,
+                      concurrency: int) -> Tuple:
         # discover_path memoizes NetworkPath instances, so identity is a
         # stable key (hashing the hops tuple is the hot-path cost here).
         # The entry holds its path, so no other path can take that id
@@ -433,7 +464,7 @@ class CarbonField:
                parallelism, concurrency)
         ent = self._weight_fn_cache.get(key)
         if ent is not None:
-            return ent[1]
+            return ent
         n = path.n_hops
         idle, cw, mw, nw = (np.zeros(n) for _ in range(4))
         den = np.ones(n)
@@ -445,26 +476,18 @@ class CarbonField:
         for j, hop in enumerate(path.hops[1:-1], start=1):
             c = HOP_CLASSES[classify_hop(hop.info.org)]
             nw[j], den[j] = c["port_w"], c["line_gbps"]
+        coeffs = (idle, cw, mw, nw, den, c0)
+        col = tuple(x[:, None] for x in coeffs[:5]) + (c0,)
 
-        def w_of(gbps: ArrayLike, _idle=idle, _cw=cw, _mw=mw, _nw=nw,
-                 _den=den, _c0=c0) -> np.ndarray:
+        def w_of(gbps: ArrayLike, _row=coeffs, _col=col) -> np.ndarray:
             g = np.asarray(gbps, dtype=np.float64)
-            if g.ndim:                 # (hops, n_gbps) for step vectors
-                _idle, _cw, _mw, _nw = (x[:, None] for x in
-                                        (_idle, _cw, _mw, _nw))
-                _den = _den[:, None]
-            u_cpu = np.minimum(_c0 + (0.4 * g) / _den, 1.0)
-            u_mem = np.minimum(0.10 + (0.05 * g) / _den, 1.0)
-            u_nic = np.minimum(g / _den, 1.0)
-            return (_idle
-                    + _cw * np.minimum(np.maximum(u_cpu, 0.0), 1.0)
-                    + _mw * np.minimum(np.maximum(u_mem, 0.0), 1.0)
-                    + _nw * u_nic)
+            # (hops, n_gbps) for step vectors
+            return device_weights(_col if g.ndim else _row, g)
 
-        if len(self._weight_fn_cache) >= self._GRID_CACHE_MAX:
+        if len(self._weight_fn_cache) >= self._WEIGHT_CACHE_MAX:
             self._weight_fn_cache.pop(next(iter(self._weight_fn_cache)))
-        self._weight_fn_cache[key] = (path, w_of)
-        return w_of
+        ent = self._weight_fn_cache[key] = (path, coeffs, w_of)
+        return ent
 
     def __getstate__(self) -> Dict:
         """Pickle support for checkpointing (``controlplane.persistence``):

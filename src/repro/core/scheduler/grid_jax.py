@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -261,11 +261,58 @@ class LegTask:
 
 @dataclasses.dataclass(frozen=True)
 class CellTask:
-    """One (job, FTN, replica) cell: 1–2 legs sharing a slot/step layout."""
+    """One (job, FTN, replica) cell: 1–2 legs sharing a slot/step layout
+    (a row of a :class:`CellTable`, built on demand)."""
     legs: Tuple[LegTask, ...]
     n_slots: int                       # candidate starts: anchor + k*slot
     n_steps: int                       # dt_s steps per transfer
     rem_s: float                       # pro-rated final-step seconds
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class CellTable:
+    """An admission sweep's (job, FTN, replica) cells as columns, in the
+    planner's order (job-major, then FTN, then replica).
+
+    Both fleet scorers read the columns directly; ``len``, indexing and
+    iteration give :class:`CellTask` rows, built on demand for callers
+    that want objects (never on the scoring path)."""
+    paths: Tuple[NetworkPath, ...]     # path id -> memoized path
+    legs: np.ndarray                   # (C, 2) i32 path id per leg, -1: none
+    anchor: np.ndarray                 # (C,) f64 grid anchor (first slot)
+    n_slots: np.ndarray                # (C,) i64 candidate starts
+    n_steps: np.ndarray                # (C,) i64 dt_s steps per transfer
+    rem_s: np.ndarray                  # (C,) f64 pro-rated final step
+    w_dev: np.ndarray                  # (C, 2, H) f64 hop power, pads 0
+
+    def __len__(self) -> int:
+        return len(self.anchor)
+
+    def __getitem__(self, i: int) -> CellTask:
+        legs = tuple(
+            LegTask(path=self.paths[p], anchor=float(self.anchor[i]),
+                    w_dev=self.w_dev[i, li, :self.paths[p].n_hops])
+            for li, p in enumerate(self.legs[i]) if p >= 0)
+        return CellTask(legs=legs, n_slots=int(self.n_slots[i]),
+                        n_steps=int(self.n_steps[i]),
+                        rem_s=float(self.rem_s[i]))
+
+    def __iter__(self) -> Iterator[CellTask]:
+        return (self[i] for i in range(len(self)))
+
+    def take(self, idx) -> "CellTable":
+        """The cells at ``idx``, in that order (paths shared)."""
+        return CellTable(self.paths, self.legs[idx], self.anchor[idx],
+                         self.n_slots[idx], self.n_steps[idx],
+                         self.rem_s[idx], self.w_dev[idx])
+
+    def n_legs(self) -> np.ndarray:
+        return 1 + (self.legs[:, 1] >= 0)
+
+    def hops(self) -> np.ndarray:
+        """(C,) hop count of each cell's longest leg."""
+        n = np.array([p.n_hops for p in self.paths] + [0])
+        return np.maximum(n[self.legs[:, 0]], n[self.legs[:, 1]])
 
 
 def _round_up(n: int, b: int) -> int:
@@ -363,38 +410,66 @@ def _batch_kernel():
     return _kernel_jit
 
 
-def _iter_chunks(cells: Sequence[CellTask], slot_stride: int,
-                 max_elems: int) -> Iterator[List[int]]:
+def _first_rank(first: np.ndarray) -> np.ndarray:
+    """Rank of each ``np.unique`` value by its first occurrence."""
+    rank = np.empty(len(first), dtype=np.int64)
+    rank[np.argsort(first, kind="stable")] = np.arange(len(first))
+    return rank
+
+
+def _leg_pairs(cells: CellTable) -> Tuple[np.ndarray, np.ndarray,
+                                          np.ndarray]:
+    """The live (cell, leg) entries in cell-then-leg order: their cell
+    index, anchor code (index into the sorted distinct anchors) and
+    (anchor, path) pair code."""
+    live = cells.legs >= 0
+    cell_of = np.nonzero(live)[0]
+    ua, acode = np.unique(cells.anchor[cell_of], return_inverse=True)
+    pcode = acode * len(cells.paths) + cells.legs[live]
+    return cell_of, acode, pcode
+
+
+def _iter_chunks(cells: CellTable, slot_stride: int,
+                 max_elems: int) -> Iterator[np.ndarray]:
     """Split a fleet of cells into anchor-sorted chunks whose
     pairs*hops*grid element count stays under ``max_elems`` (pathological
     fleets with thousands of distinct anchors would otherwise materialize
-    a multi-GB CI grid in one call). Yields lists of original indices —
+    a multi-GB CI grid in one call). Yields arrays of original indices —
     shared by the jitted lattice path and the fused Pallas path, so both
-    see identical chunk boundaries for a given budget."""
-    order = sorted(range(len(cells)),
-                   key=lambda i: cells[i].legs[0].anchor)
-    i = 0
-    while i < len(order):
-        chunk: List[int] = []
-        pairs: Dict[Tuple, None] = {}
-        grid_max = hops_max = 0
-        while i < len(order):
-            c = cells[order[i]]
-            trial = dict(pairs)
-            for leg in c.legs:
-                # discover_path memoizes paths: identity is a stable key
-                trial.setdefault((leg.anchor, id(leg.path)), None)
-            g = max(grid_max, (c.n_slots - 1) * slot_stride + c.n_steps)
-            h = max(hops_max, max(leg.path.n_hops for leg in c.legs))
-            if chunk and len(trial) * h * g > max_elems:
-                break
-            pairs, grid_max, hops_max = trial, g, h
-            chunk.append(order[i])
-            i += 1
-        yield chunk
+    see identical chunk boundaries for a given budget.
+
+    A chunk grows greedily in anchor order while its distinct (anchor,
+    path) pairs x its longest leg's hops x its longest rate grid fits;
+    all three are running counts over the sorted columns."""
+    n = len(cells)
+    if not n:
+        return
+    order = np.argsort(cells.anchor, kind="stable")
+    s = cells.take(order)
+    grid = (s.n_slots - 1) * slot_stride + s.n_steps
+    hops = s.hops()
+    cell_of, _, pcode = _leg_pairs(s)
+    # previous entry of the same pair (-1: none); a pair counts as new in
+    # a chunk starting at cell c0 when its previous entry lies before c0
+    by = np.argsort(pcode, kind="stable")
+    prev = np.full(len(pcode), -1, dtype=np.int64)
+    same = pcode[by[1:]] == pcode[by[:-1]]
+    prev[by[1:][same]] = cell_of[by[:-1][same]]
+    c0 = 0
+    while c0 < n:
+        tail = cell_of >= c0
+        new = np.bincount(cell_of[tail & (prev < c0)] - c0,
+                          minlength=n - c0)
+        elems = (np.cumsum(new)
+                 * np.maximum.accumulate(hops[c0:])
+                 * np.maximum.accumulate(grid[c0:]))
+        over = np.flatnonzero(elems[1:] > max_elems)
+        c1 = c0 + 1 + (int(over[0]) if len(over) else n - c0 - 1)
+        yield order[c0:c1]
+        c0 = c1
 
 
-def batch_cell_emissions(field: CarbonField, cells: Sequence[CellTask], *,
+def batch_cell_emissions(field: CarbonField, cells: CellTable, *,
                          dt_s: float = 60.0, slot_stride: int = 60,
                          shard=None) -> List[np.ndarray]:
     """Score every cell's (leg, start-slot) emission table in one jitted
@@ -433,7 +508,7 @@ def batch_cell_emissions(field: CarbonField, cells: Sequence[CellTask], *,
         sp.set_metadata(chunks=len(chunks))
     for chunk in chunks:
         for ci_, emis in zip(chunk, _score_chunk(
-                field, [cells[j] for j in chunk], dt_s=dt_s,
+                field, cells.take(chunk), dt_s=dt_s,
                 slot_stride=slot_stride, n_dev=n_dev, mesh=mesh)):
             out[ci_] = emis
     return out                         # type: ignore[return-value]
@@ -474,37 +549,34 @@ class ChunkTables:
     pair_anchors: List[float]          # per live pair, kernel row order
 
 
-def _chunk_tables(field: CarbonField, cells: Sequence[CellTask], *,
+def _chunk_tables(field: CarbonField, cells: CellTable, *,
                   dt_s: float, slot_stride: int,
                   cell_bucket: int) -> ChunkTables:
-    # --- dedupe (anchor, path) pairs and paths ----------------------------
-    paths: Dict[Tuple, int] = {}
-    path_objs: List[NetworkPath] = []
-    anchors: Dict[float, int] = {}
-    pair_ids: Dict[Tuple, int] = {}
-    pair_path: List[int] = []
-    pair_anchor: List[int] = []
-    n_grid = 1
-    for c in cells:
-        n_grid = max(n_grid, (c.n_slots - 1) * slot_stride + c.n_steps)
-        for leg in c.legs:
-            pk = id(leg.path)          # memoized paths: identity is stable
-            if pk not in paths:
-                paths[pk] = len(path_objs)
-                path_objs.append(leg.path)
-            if leg.anchor not in anchors:
-                anchors[leg.anchor] = len(anchors)
-            ak = (leg.anchor, pk)
-            if ak not in pair_ids:
-                pair_ids[ak] = len(pair_path)
-                pair_path.append(paths[pk])
-                pair_anchor.append(anchors[leg.anchor])
+    # --- dedupe paths, anchors and (anchor, path) pairs, each row in the
+    # order of its first (cell, leg) entry -------------------------------
+    cell_of, acode, pcode = _leg_pairs(cells)
+    flat_path = cells.legs[cells.legs >= 0]
+    upath, pfirst = np.unique(flat_path, return_index=True)
+    path_row = np.zeros(len(cells.paths), dtype=np.int64)
+    path_row[upath] = _first_rank(pfirst)
+    path_objs = [cells.paths[p] for p in upath[np.argsort(pfirst)]]
+    _, afirst = np.unique(acode, return_index=True)
+    anchor_row = _first_rank(afirst)
+    anchors = cells.anchor[cell_of[np.sort(afirst)]]
+    _, qfirst, qinv = np.unique(pcode, return_index=True,
+                                return_inverse=True)
+    pair_row = _first_rank(qfirst)[qinv]               # per (cell, leg)
+    qorder = np.sort(qfirst)                           # first entry per row
+    pair_path = path_row[flat_path[qorder]]
+    pair_anchor = anchor_row[acode[qorder]]
+    n_grid = max(1, int(np.max((cells.n_slots - 1) * slot_stride
+                               + cells.n_steps)))
     n_hops = max(p.n_hops for p in path_objs)
-    n_slots = max(c.n_slots for c in cells)
+    n_slots = int(np.max(cells.n_slots))
     zones = sorted({h.zone for p in path_objs for h in p.hops})
     # --- window: one hour-aligned anchor covering every pair's grid -------
-    t0w = 3600.0 * math.floor(min(anchors) / 3600.0)
-    t_end = max(a + n_grid * dt_s for a in anchors)
+    t0w = 3600.0 * math.floor(float(np.min(anchors)) / 3600.0)
+    t_end = float(np.max(anchors + n_grid * dt_s))
     hours = _round_up(int(math.ceil((t_end - t0w) / 3600.0)) + 1, _B_HOURS)
     hour0 = int(t0w // 3600.0)
     hour_idx = np.arange(hour0, hour0 + hours)
@@ -533,27 +605,22 @@ def _chunk_tables(field: CarbonField, cells: Sequence[CellTask], *,
     # --- anchor, pair and cell tables -------------------------------------
     n_anch = _round_up(len(anchors), 32)
     rel0a = np.zeros(n_anch)
-    rel0a[:len(anchors)] = np.fromiter(anchors, dtype=np.float64,
-                                       count=len(anchors)) - t0w
+    rel0a[:len(anchors)] = anchors - t0w
     n_a = _round_up(len(pair_path), _B_PAIRS)
     path_idx = np.zeros(n_a, dtype=np.int32)
     path_idx[:len(pair_path)] = pair_path
     anchor_idx = np.zeros(n_a, dtype=np.int32)
     anchor_idx[:len(pair_anchor)] = pair_anchor
-    n_c = _round_up(len(cells), cell_bucket)
+    n_live = len(cells)
+    n_c = _round_up(n_live, cell_bucket)
     pair_idx = np.zeros((n_c, 2), dtype=np.int32)
+    pair_idx[:n_live][cells.legs >= 0] = pair_row
     w_dev = np.zeros((n_c, 2, n_hops))
+    w_dev[:n_live] = cells.w_dev[:, :, :n_hops]
     n_steps = np.ones(n_c, dtype=np.int32)
+    n_steps[:n_live] = cells.n_steps
     rem = np.zeros(n_c)
-    for ci_, c in enumerate(cells):
-        for li, leg in enumerate(c.legs):
-            pair_idx[ci_, li] = pair_ids[(leg.anchor, id(leg.path))]
-            w_dev[ci_, li, :leg.path.n_hops] = leg.w_dev
-        n_steps[ci_] = c.n_steps
-        rem[ci_] = c.rem_s
-    inv_pair: List[Optional[Tuple[float, int]]] = [None] * len(pair_ids)
-    for (anchor, _pk), row in pair_ids.items():
-        inv_pair[row] = (anchor, pair_path[row])
+    rem[:n_live] = cells.rem_s
     return ChunkTables(
         zcols=tuple(_zcol(a) for a in ("base_ci", "diurnal_amp",
                                        "solar_dip", "noise", "peak_hour")),
@@ -566,12 +633,12 @@ def _chunk_tables(field: CarbonField, cells: Sequence[CellTask], *,
         w_dev=w_dev, n_steps=n_steps, rem=rem,
         n_grid_pad=_round_up(n_grid, _GRID_BUCKET),
         n_slots_pad=_round_up(n_slots, _B_SLOTS),
-        n_hops=n_hops, n_pairs=len(pair_ids),
-        pair_paths=[path_objs[p] for _, p in inv_pair],
-        pair_anchors=[a for a, _ in inv_pair])
+        n_hops=n_hops, n_pairs=len(pair_path),
+        pair_paths=[path_objs[p] for p in pair_path],
+        pair_anchors=anchors[pair_anchor].tolist())
 
 
-def chunk_scores(field: CarbonField, cells: Sequence[CellTask], *,
+def chunk_scores(field: CarbonField, cells: CellTable, *,
                  dt_s: float, slot_stride: int, n_dev: int,
                  mesh=None) -> "jax.Array":
     """The ``(C_pad, 2, S_pad)`` f64 emission table of one chunk as the
@@ -584,7 +651,7 @@ def chunk_scores(field: CarbonField, cells: Sequence[CellTask], *,
                    mesh=mesh)
 
 
-def _device_tables(field: CarbonField, cells: Sequence[CellTask], *,
+def _device_tables(field: CarbonField, cells: CellTable, *,
                    dt_s: float, slot_stride: int, n_dev: int
                    ) -> ChunkTables:
     # the cell axis must split evenly across devices for shard_map
@@ -611,7 +678,7 @@ def _compiled_count() -> int:
     return 0 if _kernel_jit is None else _kernel_jit._cache_size()
 
 
-def _score_chunk(field: CarbonField, cells: Sequence[CellTask], *,
+def _score_chunk(field: CarbonField, cells: CellTable, *,
                  dt_s: float, slot_stride: int, n_dev: int,
                  mesh=None) -> List[np.ndarray]:
     with span("admit.inputs", cells=len(cells)) as sp:
@@ -626,5 +693,5 @@ def _score_chunk(field: CarbonField, cells: Sequence[CellTask], *,
             sp.set_metadata(compiled=int(_compiled_count() > n0))
         with span("admit.fetch"):
             emis = np.asarray(out, dtype=np.float64)
-    return [emis[ci_, :len(c.legs), :c.n_slots]
-            for ci_, c in enumerate(cells)]
+    return [emis[ci_, :n_l, :n_s] for ci_, (n_l, n_s) in enumerate(
+        zip(cells.n_legs().tolist(), cells.n_slots.tolist()))]

@@ -50,8 +50,8 @@ import numpy as np
 from repro.core.carbon.field import CarbonField
 from repro.core.carbon.path import NetworkPath
 from repro.core.obs.host import span
-from repro.core.scheduler.grid_jax import (_B_CELLS, _MAX_ELEMS, CellTask,
-                                           HAVE_JAX, _chunk_tables,
+from repro.core.scheduler.grid_jax import (_B_CELLS, _MAX_ELEMS, HAVE_JAX,
+                                           CellTable, _chunk_tables,
                                            _iter_chunks, _round_up)
 
 if HAVE_JAX:
@@ -341,7 +341,7 @@ def _compiled_count() -> int:
     return 0 if _fused_jit is None else _fused_jit._cache_size()
 
 
-def _kernel_inputs(field: CarbonField, cells: Sequence[CellTask],
+def _kernel_inputs(field: CarbonField, cells: CellTable,
                    sla_rows: np.ndarray, *, dt_s: float, slot_stride: int,
                    slot_s: float,
                    scale_fn: Optional[Callable[[NetworkPath, np.ndarray],
@@ -417,7 +417,7 @@ def _kernel_inputs(field: CarbonField, cells: Sequence[CellTask],
         w=t.w_dev.astype(np.float32)[..., None], cf=cf, scl=scl)
 
 
-def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
+def batch_cell_best(field: CarbonField, cells: CellTable,
                     sla_rows: Sequence[Sequence[float]], *,
                     dt_s: float = 60.0, slot_stride: int = 60,
                     slot_s: float = 3600.0,
@@ -457,7 +457,7 @@ def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
         sp.set_metadata(chunks=len(chunks))
     for chunk in chunks:
         with span("admit.inputs", cells=len(chunk)) as sp:
-            x = _kernel_inputs(field, [cells[j] for j in chunk],
+            x = _kernel_inputs(field, cells.take(chunk),
                                sla_rows[chunk], dt_s=dt_s,
                                slot_stride=slot_stride, slot_s=slot_s,
                                scale_fn=scale_fn)
@@ -471,9 +471,8 @@ def batch_cell_best(field: CarbonField, cells: Sequence[CellTask],
                 sp.set_metadata(compiled=int(_compiled_count() > n0))
             with span("admit.fetch"):
                 best = np.asarray(out)
-        idx = np.asarray(chunk, dtype=np.int64)
         n = len(chunk)
-        cost[idx] = best[:n, 0, 0]
-        emis[idx] = best[:n, 0, 1]
-        slot[idx] = best[:n, 0, 2].astype(np.int64)
+        cost[chunk] = best[:n, 0, 0]
+        emis[chunk] = best[:n, 0, 1]
+        slot[chunk] = best[:n, 0, 2].astype(np.int64)
     return cost, emis, slot

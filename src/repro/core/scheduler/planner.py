@@ -26,7 +26,8 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.carbon.energy import HOST_PROFILES, host_profile_for_endpoint
-from repro.core.carbon.field import CarbonField, default_field
+from repro.core.carbon.field import (CarbonField, default_field,
+                                     device_weights)
 from repro.core.carbon.path import NetworkPath, discover_path
 from repro.core.carbon.score import (carbonscore, transfer_emissions_g,
                                      transfer_emissions_g_reference)
@@ -94,6 +95,157 @@ def _plan_cost(sla: SLA, emissions_g: float, finish_rel_s) -> float:
     """
     slack = max(sla.deadline_s, 1.0)
     return sla.w_carbon * emissions_g + sla.w_perf * finish_rel_s / slack
+
+
+def _steps(dur: np.ndarray, dt_s: float) -> Tuple[np.ndarray, np.ndarray]:
+    """(n_steps, rem_s) of transfers lasting ``dur``: whole ``dt_s`` steps,
+    at least one, and the pro-rated seconds of the last."""
+    n_steps = np.maximum(np.ceil(dur / dt_s - 1e-12), 1).astype(np.int64)
+    return n_steps, dur - (n_steps - 1) * dt_s
+
+
+def _n_valid(sub: np.ndarray, slot_s: float, n_slots: np.ndarray,
+             dur: np.ndarray, deadline_t: np.ndarray) -> np.ndarray:
+    """Per cell, the count of its slots ``sub + slot_s * k`` (k below
+    ``n_slots``) that finish by the deadline — ``np.sum(ts + dur <=
+    deadline_t + 1e-9)`` of :meth:`CarbonPlanner.plan`. ``n_slots`` comes
+    from the same bound, so every slot but the last ends a whole slot
+    (at least one ``dt_s``) before it, far beyond float rounding: the last
+    slot alone decides the count."""
+    last = (sub + slot_s * (n_slots - 1).astype(np.float64)) + dur \
+        <= deadline_t + 1e-9
+    return (n_slots - 1 + last).astype(np.float64)
+
+
+def _first_min(cost: np.ndarray, lo: np.ndarray, hi: np.ndarray
+               ) -> np.ndarray:
+    """Per job, the first of its cells ``lo..hi`` with the least finite
+    cost, or -1 where none is finite."""
+    win = np.full(len(lo), -1, dtype=np.int64)
+    ok = np.isfinite(cost)
+    has = hi > lo
+    if not ok.any() or not has.any():
+        return win
+    c = np.where(ok, cost, np.inf)
+    job_of = np.repeat(np.arange(len(lo)), hi - lo)
+    jmin = np.full(len(lo), np.inf)
+    jmin[has] = np.minimum.reduceat(c, lo[has])
+    at = np.flatnonzero(ok & (c == jmin[job_of]))
+    j, first = np.unique(job_of[at], return_index=True)
+    win[j] = at[first]
+    return win
+
+
+@dataclasses.dataclass
+class _SweepCells:
+    """The planner's columns beside a sweep's ``CellTable``."""
+    lo: np.ndarray                     # (J,) first cell of each job
+    hi: np.ndarray                     # (J,) one past its last cell
+    fallback: np.ndarray               # (J,) grid past the cap: plan()
+    n_alt: np.ndarray                  # (J,) starts over all its candidates
+    dur: np.ndarray                    # (C,) transfer seconds
+    cand: np.ndarray                   # (C,) row of ``cands``
+    cands: List[Tuple]                 # (ftn, src, paths, gbps)
+    groups: int                        # distinct device-weight closures
+
+
+class _CellColumns:
+    """Builds a ``CellTable`` from a sweep's candidates. Each distinct
+    (FTN, replica, dst, parallelism, concurrency) candidate is resolved
+    once — legs, memoized paths, ``throughput.predict`` per (a, b, par,
+    con) — and each distinct device-weight closure (path, receiver,
+    parallelism, concurrency) is looked up once; ``groups`` counts
+    those the table's cells use."""
+
+    def __init__(self, planner: "CarbonPlanner"):
+        self._pl = planner
+        self._cand: dict = {}          # candidate key -> row of ``cands``
+        self._rate: dict = {}          # (a, b, par, con) -> gbps
+        self._pid: dict = {}           # id(path) -> path id
+        self._wkey: dict = {}          # closure key -> closure id
+        self._wfn: List[Tuple] = []    # closure id -> (path, recv, par, con)
+        self.paths: List[NetworkPath] = []
+        self.cands: List[Tuple] = []   # (ftn, src, paths, gbps)
+        self._legs: List[Tuple[int, int]] = []     # path ids per candidate
+        self._w: List[Tuple[int, int]] = []        # closure ids per candidate
+        self.groups = 0                # closures the table's cells use
+
+    def candidate(self, ftn: FTN, src: str, dst: str, par: int, con: int
+                  ) -> int:
+        """The row of one (FTN, replica) cell's candidate in ``cands``:
+        its paths and predicted gbps as :meth:`CarbonPlanner._candidates`
+        computes them."""
+        key = (ftn.name, src, dst, par, con)
+        row = self._cand.get(key)
+        if row is not None:
+            return row
+        legs = [(src, ftn.name)]
+        if ftn.name != dst:
+            legs.append((ftn.name, dst))
+        gbps = min(self._predict(a, b, par, con) for a, b in legs)
+        gbps = min(gbps, ftn.max_gbps)
+        paths = [discover_path(a, b) for a, b in legs]
+        pids, wids = [-1, -1], [-1, -1]
+        for li, p in enumerate(paths):
+            pid = self._pid.get(id(p))
+            if pid is None:
+                pid = self._pid[id(p)] = len(self.paths)
+                self.paths.append(p)
+            wk = (pid, ftn.power_model.name, par, con)
+            wid = self._wkey.get(wk)
+            if wid is None:
+                wid = self._wkey[wk] = len(self._wfn)
+                self._wfn.append((p, ftn.power_model, par, con))
+            pids[li], wids[li] = pid, wid
+        row = self._cand[key] = len(self.cands)
+        self.cands.append((ftn, src, paths, gbps))
+        self._legs.append(tuple(pids))
+        self._w.append(tuple(wids))
+        return row
+
+    def _predict(self, a: str, b: str, par: int, con: int) -> float:
+        key = (a, b, par, con)
+        g = self._rate.get(key)
+        if g is None:
+            g = self._rate[key] = self._pl.throughput.predict(a, b, par, con)
+        return g
+
+    def gbps(self) -> np.ndarray:
+        """(candidates,) predicted gbps."""
+        return np.array([c[3] for c in self.cands], dtype=np.float64)
+
+    def table(self, cand: np.ndarray, *, anchor: np.ndarray,
+              n_slots: np.ndarray, n_steps: np.ndarray,
+              rem_s: np.ndarray) -> "CellTable":
+        """The table of the cells of candidates ``cand``, in that order.
+        A cell's device weights depend on its candidate alone: they
+        evaluate once per (candidate, leg), in one pass over the stacked
+        coefficients of the distinct closures those use."""
+        from repro.core.scheduler.grid_jax import CellTable
+        sender = HOST_PROFILES["storage_frontend"]
+        legs = np.array(self._legs, dtype=np.int32).reshape(-1, 2)
+        wid = np.array(self._w, dtype=np.int64).reshape(-1, 2)
+        h = max((p.n_hops for p in self.paths), default=1)
+        live = np.zeros(wid.shape, dtype=bool)
+        live[np.unique(cand)] = True
+        live &= wid >= 0
+        used, row = np.unique(wid[live], return_inverse=True)
+        self.groups = len(used)
+        coef = [np.zeros((len(used), h)) for _ in range(4)] \
+            + [np.ones((len(used), h)), np.zeros((len(used), 1))]
+        for k, w in enumerate(used):
+            p, recv, par, con = self._wfn[w]
+            cs = self._pl.field.device_weight_coeffs(p, sender, recv, par,
+                                                     con)
+            for x, c in zip(coef[:5], cs[:5]):
+                x[k, :p.n_hops] = c
+            coef[5][k] = cs[5]
+        w_cand = np.zeros(wid.shape + (h,))
+        w_cand[live] = device_weights(
+            tuple(x[row] for x in coef),
+            np.broadcast_to(self.gbps()[:, None], wid.shape)[live][:, None])
+        return CellTable(tuple(self.paths), legs[cand], anchor, n_slots,
+                         n_steps, rem_s, w_cand[cand])
 
 
 class CarbonPlanner:
@@ -455,65 +607,83 @@ class CarbonPlanner:
             return [self.plan(job) for job in jobs]
 
     def _batch_cells(self, jobs: Sequence[TransferJob], dt_s: float,
-                     stride: int) -> Tuple[list, List[Tuple],
-                                           List[Optional[List[Tuple]]]]:
+                     stride: int) -> Tuple["CellTable", np.ndarray,
+                                           "_SweepCells"]:
         """The stacked cell table of :meth:`plan_batch_jax`: one
-        ``CellTask`` and one SLA row ``[n_valid, dur_s, w_perf/slack,
-        w_carbon, budget_g]`` per (job, FTN, replica) cell, plus per-job
-        metadata (``None`` for a job whose rate grid is past the per-cell
-        cap, which falls back to :meth:`plan`)."""
-        from repro.core.scheduler.grid_jax import (CellTask, LegTask,
-                                                   _MAX_GRID)
-        sender = HOST_PROFILES["storage_frontend"]
-        cells: List[CellTask] = []
-        sla_rows: List[Tuple] = []     # per cell, aligned with ``cells``
-        meta: List[Optional[List[Tuple]]] = []
-        wcache: dict = {}              # (path, recv, gbps, par, con) -> w
+        :class:`~repro.core.scheduler.grid_jax.CellTable` row and one SLA
+        row ``[n_valid, dur_s, w_perf/slack, w_carbon, budget_g]`` per
+        live (job, FTN, replica) cell, job-major, plus the planner's own
+        columns (:class:`_SweepCells`). A job whose rate grid is past the
+        per-cell cap has no cells and falls back to :meth:`plan`.
 
-        def leg_w(p, pm, gbps, par, con):
-            k = (id(p), pm.name, gbps, par, con)
-            w = wcache.get(k)
-            if w is None:
-                w = wcache[k] = self.field.device_weight_fn(
-                    p, sender, pm, par, con)(gbps)
-            return w
-
-        for job in jobs:
-            deadline_t = job.submitted_t + job.sla.deadline_s
-            jcells: Optional[List[Tuple]] = []
-            job_cell0 = len(cells)
-            for ftn, src, legs, gbps, dur in self._candidates(job):
-                ts = self._slot_starts(job, dur, deadline_t)
-                paths = [discover_path(a, b) for (a, b) in legs]
-                if gbps <= 0:          # inf emissions: never feasible
-                    jcells.append((None, ftn, src, paths, gbps, dur, ts))
-                    continue
-                n_steps = max(int(math.ceil(dur / dt_s - 1e-12)), 1)
-                if (len(ts) - 1) * stride + n_steps > _MAX_GRID:
-                    jcells = None      # degenerate rate grid: numpy plan()
-                    del cells[job_cell0:]   # drop its half-built cells
-                    del sla_rows[job_cell0:]
-                    break
-                jcells.append((len(cells), ftn, src, paths, gbps, dur, ts))
-                cells.append(CellTask(
-                    legs=tuple(LegTask(
-                        path=p, anchor=float(ts[0]),
-                        w_dev=leg_w(p, ftn.power_model, gbps,
-                                    job.parallelism, job.concurrency))
-                        for p in paths),
-                    n_slots=len(ts), n_steps=n_steps,
-                    rem_s=dur - (n_steps - 1) * dt_s))
-                # the deadline mask is monotone in the slot index, so the
-                # fused kernel takes it as a host-side count; the budget
-                # mask depends on in-kernel emissions and stays in-kernel
-                sla_rows.append((
-                    float(np.sum(ts + dur <= deadline_t + 1e-9)), dur,
-                    job.sla.w_perf / max(job.sla.deadline_s, 1.0),
-                    job.sla.w_carbon,
-                    job.sla.carbon_budget_g
-                    if job.sla.carbon_budget_g is not None else np.inf))
-            meta.append(jcells)
-        return cells, sla_rows, meta
+        Jobs that share a candidate signature (replicas, dst, parallelism,
+        concurrency) share its candidates, which are resolved once; every
+        column is then one vector expression over all (job, candidate)
+        entries, with the per-element arithmetic of :meth:`_candidates`
+        and :meth:`_slot_starts`."""
+        from repro.core.scheduler.grid_jax import _MAX_GRID
+        n_jobs = len(jobs)
+        build = _CellColumns(self)
+        sigs: dict = {}
+        sig_cands: List[np.ndarray] = []
+        sig_of = np.zeros(n_jobs, dtype=np.int64)
+        for i, job in enumerate(jobs):
+            key = (job.replicas, job.dst, job.parallelism, job.concurrency)
+            s = sigs.get(key)
+            if s is None:
+                s = sigs[key] = len(sig_cands)
+                sig_cands.append(np.array(
+                    [build.candidate(ftn, src, *key[1:])
+                     for ftn in self.ftns for src in job.replicas],
+                    dtype=np.int64))
+            sig_of[i] = s
+        # (job, candidate) entries, job-major, then FTN, then replica
+        n_cand = np.array([len(c) for c in sig_cands], dtype=np.int64)
+        job = np.repeat(np.arange(n_jobs), n_cand[sig_of])
+        cand = (np.concatenate([sig_cands[s] for s in sig_of])
+                if len(job) else np.zeros(0, dtype=np.int64))
+        sub = np.array([j.submitted_t for j in jobs], dtype=np.float64)
+        size = np.array([j.size_bytes for j in jobs], dtype=np.float64)
+        deadline = sub + np.array([j.sla.deadline_s for j in jobs],
+                                  dtype=np.float64)
+        gbps = build.gbps()[cand]
+        sub_e, deadline_e = sub[job], deadline[job]
+        with np.errstate(divide="ignore", invalid="ignore"):
+            dur = size[job] * 8.0 / (gbps * 1e9)
+            latest = (deadline_e - dur) + 1e-9
+            n_slots = np.where(latest >= sub_e, np.floor_divide(
+                latest - sub_e, self.slot_s), 0).astype(np.int64) + 1
+            n_steps, rem = _steps(dur, dt_s)
+        live = gbps > 0                # inf emissions: never feasible
+        fallback = np.zeros(n_jobs, dtype=bool)
+        fallback[job[live & ((n_slots - 1) * stride + n_steps
+                             > _MAX_GRID)]] = True
+        n_alt = np.bincount(job, weights=n_slots,
+                            minlength=n_jobs).astype(np.int64)
+        c = np.flatnonzero(live & ~fallback[job])
+        jc = job[c]
+        n_cells = np.bincount(jc, minlength=n_jobs)
+        hi = np.cumsum(n_cells)
+        lo = hi - n_cells
+        # the deadline mask is monotone in the slot index, so the fused
+        # kernel takes it as a host-side count; the budget mask depends on
+        # in-kernel emissions and stays in-kernel
+        sla = np.stack([
+            _n_valid(sub_e[c], self.slot_s, n_slots[c], dur[c],
+                     deadline_e[c]),
+            dur[c],
+            np.array([j.sla.w_perf / max(j.sla.deadline_s, 1.0)
+                      for j in jobs], dtype=np.float64)[jc],
+            np.array([j.sla.w_carbon for j in jobs], dtype=np.float64)[jc],
+            np.array([np.inf if j.sla.carbon_budget_g is None
+                      else j.sla.carbon_budget_g for j in jobs],
+                     dtype=np.float64)[jc]], axis=1).reshape(-1, 5)
+        table = build.table(cand[c], anchor=sub_e[c], n_slots=n_slots[c],
+                            n_steps=n_steps[c], rem_s=rem[c])
+        meta = _SweepCells(lo=lo, hi=hi, fallback=fallback, n_alt=n_alt,
+                           dur=dur[c], cand=cand[c], cands=build.cands,
+                           groups=build.groups)
+        return table, sla, meta
 
     def plan_batch_jax(self, jobs: Sequence[TransferJob], *,
                        shard=None) -> List[Plan]:
@@ -549,13 +719,13 @@ class CarbonPlanner:
         stride = int(stride)
         with span("admit.cells") as sp:
             cells, sla_rows, meta = self._batch_cells(jobs, dt_s, stride)
-            sp.set_metadata(cells=len(cells))
+            sp.set_metadata(cells=len(cells), groups=meta.groups)
         self.last_batch_cells = len(cells)
-        if cells:
+        if len(cells):
             self.device_sweeps += 1
             self.device_cells += len(cells)
         fused = None                   # (cost, emis, slot) per cell
-        if cells and self.batch_backend == "pallas":
+        if len(cells) and self.batch_backend == "pallas":
             from repro.core.scheduler import grid_pallas
             fused = grid_pallas.batch_cell_best(
                 self.field, cells, sla_rows, dt_s=dt_s,
@@ -563,54 +733,31 @@ class CarbonPlanner:
                 scale_fn=self.emission_scale_fn)
         tables = batch_cell_emissions(self.field, cells, dt_s=dt_s,
                                       slot_stride=stride, shard=shard) \
-            if cells and fused is None else []
+            if len(cells) and fused is None else []
         plans: List[Optional[Plan]] = []
         winners: List[Tuple[int, Tuple[TransferJob, Tuple, int]]] = []
         with span("admit.select"):
-            for job, jcells in zip(jobs, meta):
-                if jcells is None:
+            if fused is not None:      # in-kernel mask + argmin
+                win = _first_min(fused[0], meta.lo, meta.hi)
+            for j, job in enumerate(jobs):
+                if meta.fallback[j]:
                     plans.append(self.plan(job))
                     continue
-                deadline_t = job.submitted_t + job.sla.deadline_s
+                n_alt = int(meta.n_alt[j])
                 best: Optional[Tuple] = None
-                n_alt = 0
                 g0: Optional[Tuple] = None   # (dur, emis[0]) greedy capture
-                for idx, ftn, src, paths, gbps, dur, ts in jcells:
-                    n_alt += len(ts)
-                    if idx is None:
-                        continue
-                    if fused is not None:  # in-kernel mask + argmin
-                        c_cost = float(fused[0][idx])
-                        if not math.isfinite(c_cost):
-                            continue
-                        if best is None or c_cost < best[0]:
-                            i = int(fused[2][idx])
-                            best = (c_cost, float(fused[1][idx]),
-                                    float(ts[i]), ftn, src, paths, gbps, dur)
-                        continue
-                    tab = tables[idx]      # (n_legs, n_slots)
-                    if self.emission_scale_fn is not None:
-                        tab = tab * np.stack(
-                            [self.emission_scale_fn(p, ts) for p in paths])
-                    emis = tab.sum(axis=0)
-                    # slot 0 is the submission instant: the scored grid gives
-                    # the carbon-blind start-now cell for free (the fused path
-                    # never materializes slot values — _resolve_greedy falls
-                    # back to one integral there)
-                    if self.capture_greedy and gbps > 0 \
-                            and (g0 is None or dur < g0[0]):
-                        g0 = (dur, float(emis[0]))
-                    feasible = ts + dur <= deadline_t + 1e-9
-                    if job.sla.carbon_budget_g is not None:
-                        feasible &= emis <= job.sla.carbon_budget_g
-                    cost = _plan_cost(job.sla, emis,
-                                      ts + dur - job.submitted_t)
-                    if not feasible.any():
-                        continue
-                    i = int(np.argmin(np.where(feasible, cost, np.inf)))
-                    if best is None or cost[i] < best[0]:
-                        best = (float(cost[i]), float(emis[i]), float(ts[i]),
-                                ftn, src, paths, gbps, dur)
+                if fused is not None:
+                    c = int(win[j])
+                    if c >= 0:
+                        ftn, src, paths, gbps = meta.cands[meta.cand[c]]
+                        best = (float(fused[0][c]), float(fused[1][c]),
+                                job.submitted_t
+                                + self.slot_s * int(fused[2][c]),
+                                ftn, src, paths, gbps, float(meta.dur[c]))
+                else:
+                    best, g0 = self._select_cells(
+                        job, meta, range(meta.lo[j], meta.hi[j]),
+                        cells.n_slots, tables)
                 if best is None:
                     plans.append(self._fallback(job, n_alt,
                                                 greedy=g0[1] if g0 else None))
@@ -623,6 +770,41 @@ class CarbonPlanner:
         for (slot, _), plan in zip(winners, done):
             plans[slot] = plan
         return plans                   # type: ignore[return-value]
+
+    def _select_cells(self, job: TransferJob, meta: "_SweepCells",
+                      cells: range, n_slots: np.ndarray, tables: list
+                      ) -> Tuple[Optional[Tuple], Optional[Tuple]]:
+        """One job's least-cost feasible (cell, slot) from the lattice
+        tier's per-cell emission tables, and its greedy-now capture."""
+        deadline_t = job.submitted_t + job.sla.deadline_s
+        best: Optional[Tuple] = None
+        g0: Optional[Tuple] = None
+        for c in cells:
+            ftn, src, paths, gbps = meta.cands[meta.cand[c]]
+            dur = float(meta.dur[c])
+            ts = job.submitted_t + self.slot_s * np.arange(n_slots[c])
+            tab = tables[c]            # (n_legs, n_slots)
+            if self.emission_scale_fn is not None:
+                tab = tab * np.stack(
+                    [self.emission_scale_fn(p, ts) for p in paths])
+            emis = tab.sum(axis=0)
+            # slot 0 is the submission instant: the scored grid gives the
+            # carbon-blind start-now cell for free (the fused path never
+            # materializes slot values — _resolve_greedy falls back to one
+            # integral there)
+            if self.capture_greedy and (g0 is None or dur < g0[0]):
+                g0 = (dur, float(emis[0]))
+            feasible = ts + dur <= deadline_t + 1e-9
+            if job.sla.carbon_budget_g is not None:
+                feasible &= emis <= job.sla.carbon_budget_g
+            cost = _plan_cost(job.sla, emis, ts + dur - job.submitted_t)
+            if not feasible.any():
+                continue
+            i = int(np.argmin(np.where(feasible, cost, np.inf)))
+            if best is None or cost[i] < best[0]:
+                best = (float(cost[i]), float(emis[i]), float(ts[i]),
+                        ftn, src, paths, gbps, dur)
+        return best, g0
 
     def rescore_batch(self, jobs: Sequence[TransferJob],
                       previous: Sequence[Optional[Plan]]
@@ -640,61 +822,57 @@ class CarbonPlanner:
                 or len(jobs) < self._RESCORE_MIN_CELLS:
             return [self.rescore(j, p) if p is not None else None
                     for j, p in zip(jobs, previous)]
-        from repro.core.scheduler.grid_jax import (CellTask, LegTask,
-                                                   _MAX_GRID,
+        from repro.core.scheduler.grid_jax import (_MAX_GRID,
                                                    batch_cell_emissions)
         dt_s = 60.0
-        sender = HOST_PROFILES["storage_frontend"]
         out: List[Optional[Plan]] = [None] * len(jobs)
-        cells: List[CellTask] = []
-        meta: List[Tuple] = []
+        items: List[Tuple] = []        # candidate key per live cell
+        rows: List[int] = []           # its job
         for i, (job, prev) in enumerate(zip(jobs, previous)):
             if prev is None:
                 continue
             ftn = self._ftn_by_name.get(prev.ftn)
             if ftn is None or prev.start_t < job.submitted_t - 1e-9:
                 continue               # stale cell: caller full-plans
-            legs = [(prev.source, ftn.name)]
-            if ftn.name != job.dst:
-                legs.append((ftn.name, job.dst))
-            gbps = min(self.throughput.predict(a, b, job.parallelism,
-                                               job.concurrency)
-                       for a, b in legs)
-            gbps = min(gbps, ftn.max_gbps)
-            dur = job.size_bytes * 8.0 / (gbps * 1e9)
-            n_steps = max(int(math.ceil(dur / dt_s - 1e-12)), 1)
-            if n_steps > _MAX_GRID:
-                out[i] = self.rescore(job, prev)
-                continue
-            paths = [discover_path(a, b) for (a, b) in legs]
-            meta.append((i, job, prev, ftn, gbps, dur, paths))
-            cells.append(CellTask(
-                legs=tuple(LegTask(
-                    path=p, anchor=float(prev.start_t),
-                    w_dev=self.field.device_weight_fn(
-                        p, sender, ftn.power_model, job.parallelism,
-                        job.concurrency)(gbps)) for p in paths),
-                n_slots=1, n_steps=n_steps,
-                rem_s=dur - (n_steps - 1) * dt_s))
-        if cells:
-            tables = batch_cell_emissions(self.field, cells, dt_s=dt_s,
+            items.append((ftn, prev.source, job.dst, job.parallelism,
+                          job.concurrency))
+            rows.append(i)
+        build = _CellColumns(self)
+        cand = np.array([build.candidate(*key) for key in items],
+                        dtype=np.int64).reshape(-1)
+        i_of = np.array(rows, dtype=np.int64)
+        size = np.array([jobs[i].size_bytes for i in rows], dtype=np.float64)
+        dur = size * 8.0 / (build.gbps()[cand] * 1e9)
+        n_steps, rem = _steps(dur, dt_s)
+        keep = n_steps <= _MAX_GRID
+        for i in i_of[~keep]:
+            out[i] = self.rescore(jobs[i], previous[i])
+        k = np.flatnonzero(keep)
+        if len(k):
+            table = build.table(
+                cand[k], anchor=np.array([previous[i].start_t
+                                          for i in i_of[k]]),
+                n_slots=np.ones(len(k), dtype=np.int64),
+                n_steps=n_steps[k], rem_s=rem[k])
+            tables = batch_cell_emissions(self.field, table, dt_s=dt_s,
                                           slot_stride=1)
-            for (i, job, prev, ftn, gbps, dur, paths), tab in zip(meta,
-                                                                  tables):
+            for i, c, d, tab in zip(i_of[k], cand[k], dur[k], tables):
+                job, prev = jobs[i], previous[i]
+                _, _, paths, gbps = build.cands[c]
+                d = float(d)
                 ts = np.array([prev.start_t])
                 if self.emission_scale_fn is not None:
                     tab = tab * np.stack(
                         [self.emission_scale_fn(p, ts) for p in paths])
                 emis = float(tab.sum())
                 deadline_t = job.submitted_t + job.sla.deadline_s
-                feasible = prev.start_t + dur <= deadline_t + 1e-9
+                feasible = prev.start_t + d <= deadline_t + 1e-9
                 if job.sla.carbon_budget_g is not None:
                     feasible = feasible and emis <= job.sla.carbon_budget_g
                 cost = float(_plan_cost(job.sla, emis,
-                                        prev.start_t + dur
-                                        - job.submitted_t))
+                                        prev.start_t + d - job.submitted_t))
                 out[i] = dataclasses.replace(
-                    prev, predicted_gbps=gbps, predicted_duration_s=dur,
+                    prev, predicted_gbps=gbps, predicted_duration_s=d,
                     predicted_emissions_g=emis, cost=cost,
                     feasible=bool(feasible))
         return out

@@ -55,13 +55,14 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def chunk():
-    """Cells and SLA rows of the first memory chunk of a 4096-job batch."""
+    """Cell table and SLA rows of the first memory chunk of a 4096-job
+    batch."""
     pl = CarbonPlanner(list(PLANNER_SCALE_FTNS), batch_backend="pallas")
     cells, sla, _ = pl._batch_cells(
         [planner_scale_job(i) for i in range(4096)], DT_S, STRIDE)
     idx = next(grid_jax._iter_chunks(cells, STRIDE, grid_jax._MAX_ELEMS))
     assert len(idx) > 16_000, "the chunk should be fleet-sized"
-    return pl.field, [cells[j] for j in idx], np.asarray(sla)[idx]
+    return pl.field, cells.take(idx), sla[idx]
 
 
 @pytest.fixture(scope="module")
@@ -138,7 +139,7 @@ def test_fused_program_names_both_kernels(one_chip):
     pl = CarbonPlanner(list(PLANNER_SCALE_FTNS), batch_backend="pallas")
     cells, sla, _ = pl._batch_cells([planner_scale_job(i) for i in range(64)],
                                     DT_S, STRIDE)
-    x = grid_pallas._kernel_inputs(pl.field, cells, np.asarray(sla),
+    x = grid_pallas._kernel_inputs(pl.field, cells, sla,
                                    dt_s=DT_S, slot_stride=STRIDE,
                                    slot_s=SLOT_S, scale_fn=None)
     compiled = jax.jit(grid_pallas._fused, static_argnames=(

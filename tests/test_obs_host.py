@@ -131,8 +131,10 @@ def test_pallas_sweep_span_tree_and_compiled_arg(tmp_path, monkeypatch):
         got = _named(evs, name)
         assert len(got) == 2, name
         assert all(len(_inside(e, sweeps)) == 1 for e in got), name
+    groups = pl._batch_cells(jobs, 60.0, 60)[2].groups
+    assert 0 < groups <= cells
     assert [e[3] for e in _named(evs, "admit.cells")] == \
-        [{"cells": cells}] * 2
+        [{"cells": cells, "groups": groups}] * 2
     assert all(e[3] == {"chunks": 1} for e in _named(evs, "admit.chunks"))
     for e in _named(evs, "admit.inputs"):
         assert e[3]["cells"] == cells and e[3]["pairs"] > 0
