@@ -545,6 +545,7 @@ class ChunkTables:
     n_slots_pad: int
     n_hops: int
     n_pairs: int                       # live (anchor, path) pairs
+    hop_keys: int                      # distinct hop keys (``Hop.ip``)
     pair_paths: List[NetworkPath]      # per live pair, kernel row order
     pair_anchors: List[float]          # per live pair, kernel row order
 
@@ -573,7 +574,9 @@ def _chunk_tables(field: CarbonField, cells: CellTable, *,
                                + cells.n_steps)))
     n_hops = max(p.n_hops for p in path_objs)
     n_slots = int(np.max(cells.n_slots))
-    zones = sorted({h.zone for p in path_objs for h in p.hops})
+    hops = [h for p in path_objs for h in p.hops]       # path-major
+    hop_zone = [h.zone for h in hops]
+    zones = sorted(set(hop_zone))
     # --- window: one hour-aligned anchor covering every pair's grid -------
     t0w = 3600.0 * math.floor(float(np.min(anchors)) / 3600.0)
     t_end = float(np.max(anchors + n_grid * dt_s))
@@ -592,16 +595,25 @@ def _chunk_tables(field: CarbonField, cells: CellTable, *,
         return col
 
     cal_a, cal_b = get_calibration()
-    # --- per-path hop tables (padded to n_hops; pads weigh 0) -------------
+    # --- per-path hop tables (padded to n_hops; pads weigh 0): one noise
+    # row and band per distinct hop key, gathered per (path, hop) ---------
     n_p = _round_up(len(path_objs), 2)
+    live = np.zeros((n_p, n_hops), dtype=bool)          # row-major = hops
+    live[:len(path_objs)] = (np.arange(n_hops)[None, :] < np.array(
+        [p.n_hops for p in path_objs])[:, None])
+    zone_row = {z: zi_ for zi_, z in enumerate(zones)}
+    key_row: Dict[str, int] = {}
     zone_idx = np.zeros((n_p, n_hops), dtype=np.int32)
-    band = np.zeros((n_p, n_hops), dtype=np.float32)
-    hnoise = np.zeros((n_p, n_hops, hours), dtype=np.float32)
-    for pi, p in enumerate(path_objs):
-        for hi_, h in enumerate(p.hops):
-            zone_idx[pi, hi_] = zones.index(h.zone)
-            band[pi, hi_] = field._hop_band(h.ip)
-            hnoise[pi, hi_] = field._hop_noise.lookup(h.ip, hour_idx) - 0.5
+    zone_idx[live] = [zone_row[z] for z in hop_zone]
+    hop_key = np.full((n_p, n_hops), -1, dtype=np.int64)   # -1: pad row
+    hop_key[live] = [key_row.setdefault(h.ip, len(key_row)) for h in hops]
+    key_noise = np.zeros((len(key_row) + 1, hours), dtype=np.float32)
+    key_band = np.zeros(len(key_row) + 1, dtype=np.float32)
+    for k, ip in enumerate(key_row):
+        key_noise[k] = field._hop_noise.lookup(ip, hour_idx) - 0.5
+        key_band[k] = field._hop_band(ip)
+    hnoise = key_noise[hop_key]
+    band = key_band[hop_key]
     # --- anchor, pair and cell tables -------------------------------------
     n_anch = _round_up(len(anchors), 32)
     rel0a = np.zeros(n_anch)
@@ -633,7 +645,7 @@ def _chunk_tables(field: CarbonField, cells: CellTable, *,
         w_dev=w_dev, n_steps=n_steps, rem=rem,
         n_grid_pad=_round_up(n_grid, _GRID_BUCKET),
         n_slots_pad=_round_up(n_slots, _B_SLOTS),
-        n_hops=n_hops, n_pairs=len(pair_path),
+        n_hops=n_hops, n_pairs=len(pair_path), hop_keys=len(key_row),
         pair_paths=[path_objs[p] for p in pair_path],
         pair_anchors=anchors[pair_anchor].tolist())
 
@@ -684,7 +696,7 @@ def _score_chunk(field: CarbonField, cells: CellTable, *,
     with span("admit.inputs", cells=len(cells)) as sp:
         t = _device_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
                            n_dev=n_dev)
-        sp.set_metadata(pairs=len(t.path_idx))
+        sp.set_metadata(pairs=len(t.path_idx), hop_keys=t.hop_keys)
     with span("admit.device"):
         with span("admit.launch") as sp:
             n0 = _compiled_count()

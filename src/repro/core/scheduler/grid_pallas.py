@@ -46,12 +46,14 @@ import functools
 from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
+from numpy.lib.stride_tricks import sliding_window_view
 
 from repro.core.carbon.field import CarbonField
 from repro.core.carbon.path import NetworkPath
 from repro.core.obs.host import span
 from repro.core.scheduler.grid_jax import (_B_CELLS, _MAX_ELEMS, HAVE_JAX,
-                                           CellTable, _chunk_tables,
+                                           CellTable, ChunkTables,
+                                           _chunk_tables,
                                            _iter_chunks, _round_up)
 
 if HAVE_JAX:
@@ -341,21 +343,79 @@ def _compiled_count() -> int:
     return 0 if _fused_jit is None else _fused_jit._cache_size()
 
 
+def _slot_hours(dt_s: float, slot_stride: int) -> int:
+    """Whole hours per slot, or 0 when a slot is not a whole number of
+    hours: the noise planes are row copies only in the first case."""
+    sph = 3600 // int(dt_s)
+    return slot_stride // sph if slot_stride % sph == 0 else 0
+
+
+def _noise_rows(t: ChunkTables, zid: np.ndarray, h0: np.ndarray, *,
+                m: int, n_cand: int, width: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """Zone and hop noise planes by row copies, for a slot of ``m`` whole
+    hours: row ``r`` of pair ``a`` reads hour ``h0[a] + k + m * r`` at
+    candidate ``k``, so each (pair, candidate, hop) plane is one strided
+    window of a table row. Edge padding of the tables' hour axis gives
+    what the element gather's clip to the window gives."""
+    hours = t.znoise.shape[1]
+    span = m * (width - 1) + 1                          # hours a plane spans
+    lo = max(0, -int(h0.min()))
+    hi = max(0, int(h0.max()) + n_cand - 1 + span - hours)
+    start = (h0 + lo)[:, None, None] + np.arange(n_cand)[None, :, None]
+
+    def windows(tab):
+        if lo or hi:
+            tab = np.pad(tab, [(0, 0)] * (tab.ndim - 1) + [(lo, hi)],
+                         mode="edge")
+        return sliding_window_view(tab, span, axis=-1)[..., ::m]
+
+    zk = windows(t.znoise)[zid[:, None, :], start]
+    hk = windows(t.hnoise)[t.path_idx[:, None, None],
+                           np.arange(zid.shape[1])[None, None, :], start]
+    return zk, hk
+
+
+def _noise_gather(t: ChunkTables, zid: np.ndarray, q: np.ndarray, *,
+                  p: int, sph: int, n_cand: int, width: int
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """Zone and hop noise planes element by element, for any slot stride:
+    each row's candidate hours, clipped to the window."""
+    hour0 = (q[:, None] + p * np.arange(width)[None, :]) // sph
+    hrs = np.clip(hour0[:, None, :] + np.arange(n_cand)[None, :, None],
+                  0, t.znoise.shape[1] - 1)             # (A, K, L)
+    zk = t.znoise[zid[:, None, :, None], hrs[:, :, None, :]]
+    hk = t.hnoise[t.path_idx[:, None, None, None],
+                  np.arange(zid.shape[1])[None, None, :, None],
+                  hrs[:, :, None, :]]
+    return zk, hk
+
+
 def _kernel_inputs(field: CarbonField, cells: CellTable,
                    sla_rows: np.ndarray, *, dt_s: float, slot_stride: int,
                    slot_s: float,
                    scale_fn: Optional[Callable[[NetworkPath, np.ndarray],
                                                np.ndarray]]
                    ) -> KernelInputs:
-    """Lay one chunk's cell tables out slot-major for the two kernels.
+    """Lay one chunk's cell tables out slot-major for the two kernels."""
+    t = _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
+                      cell_bucket=_B_CELLS)
+    return _layout(t, cells, sla_rows, dt_s=dt_s, slot_stride=slot_stride,
+                   slot_s=slot_s, scale_fn=scale_fn)
+
+
+def _layout(t: ChunkTables, cells: CellTable, sla_rows: np.ndarray, *,
+            dt_s: float, slot_stride: int, slot_s: float,
+            scale_fn: Optional[Callable[[NetworkPath, np.ndarray],
+                                        np.ndarray]]) -> KernelInputs:
+    """The kernels' inputs from one chunk's tables.
 
     All 64-bit time math happens here, on the host: each pair's anchor
     becomes an int32 step index ``q`` from the hour-aligned window start
     plus a sub-step remainder, and every row's first step gets its phase
-    within the hour, its step of the day and its weekday factors.
+    within the hour, its step of the day and its weekday factors, each a
+    per-pair offset plus the row's ``p * r`` steps, in int32.
     """
-    t = _chunk_tables(field, cells, dt_s=dt_s, slot_stride=slot_stride,
-                      cell_bucket=_B_CELLS)
     dt = int(dt_s)
     if dt != dt_s or 3600 % dt:
         raise ValueError(f"dt_s must be a whole divisor of 3600 s, "
@@ -365,36 +425,53 @@ def _kernel_inputs(field: CarbonField, cells: CellTable,
     if not 1 <= p <= spd:
         raise ValueError(f"slot_stride must be 1..{spd} steps, got {p}")
     width = _round_up(-(-t.n_grid_pad // p), _LANES)    # rows, lane-padded
-    a_pad, h_hops = t.path_idx.shape[0], t.n_hops
-    hours = t.znoise.shape[1]
-    # --- per-pair time rows (int math; anchors may be fractional) ---------
+    a_pad = t.path_idx.shape[0]
+    # --- per-pair step index (int math; anchors may be fractional) --------
     rel0 = t.rel0a[t.anchor_idx]                        # (A,) f64
     q = np.floor(rel0 / dt).astype(np.int64)
     fsec = (rel0 - q * dt).astype(np.float32)
-    u = q[:, None] + p * np.arange(width)[None, :]      # first step of row
-    hour0 = u // sph
+    # --- zone and hop noise at each row's candidate hours -----------------
     n_cand = (sph - 1 + p - 1) // sph + 1               # hours a row spans
-    hrs = np.clip(hour0[:, None, :] + np.arange(n_cand)[None, :, None],
-                  0, hours - 1)                         # (A, K, L)
     zid = t.zone_idx[t.path_idx]                        # (A, H)
-    zk = t.znoise[zid[:, None, :, None], hrs[:, :, None, :]]
-    hk = t.hnoise[t.path_idx[:, None, None, None],
-                  np.arange(h_hops)[None, None, :, None],
-                  hrs[:, :, None, :]]
-    hod0 = int(round(t.h_of_day0))
-    day = (u + int(round(t.day_frac_s)) // dt) // spd
-    dow = (t.dow0 + day) % 7
-    ti = np.stack([u - hour0 * sph, (hod0 * sph + u) % spd],
-                  axis=1).astype(np.int32)
-    tf = np.stack([np.broadcast_to(fsec[:, None], u.shape),
-                   np.where(dow >= 5, _WEEKEND, np.float32(1.0)),
-                   np.where((dow + 1) % 7 >= 5, _WEEKEND, np.float32(1.0))],
-                  axis=1).astype(np.float32)
+    m = _slot_hours(dt_s, p)
+    if m:
+        zk, hk = _noise_rows(t, zid, q // sph, m=m, n_cand=n_cand,
+                             width=width)
+    else:
+        zk, hk = _noise_gather(t, zid, q, p=p, sph=sph, n_cand=n_cand,
+                               width=width)
+    # --- time rows: a per-pair offset below the modulus plus row r's
+    # p * r steps, wrapped by one compare, in int32 and written in place:
+    # a fresh (A, L) temporary costs first-touch page faults -------------
+    pr = p * np.arange(width)
+
+    def offset_rows(off, n, out=None):
+        """``off[a] + (p * r) % n``, below ``2 n`` for offsets below n."""
+        return np.add(off.astype(np.int32)[:, None],
+                      (pr % n).astype(np.int32), out=out)
+
+    ti = np.empty((a_pad, 2, width), dtype=np.int32)
+    for i, (off, n) in enumerate((
+            (q % sph, sph),                             # step in its hour
+            ((int(round(t.h_of_day0)) * sph + q) % spd, spd))):  # of day
+        row = offset_rows(off, n, out=ti[:, i])
+        np.subtract(row, n, out=row, where=row >= n)
+    c_day = q + int(round(t.day_frac_s)) // dt          # steps past midnight
+    day = np.add(offset_rows(c_day % spd, spd) >= spd,  # past the next one
+                 ((t.dow0 + c_day // spd) % 7).astype(np.int32)[:, None],
+                 dtype=np.int32)
+    day += ((pr // spd) % 7).astype(np.int32)           # weekday, 0..13
+    wk = np.where(np.arange(15) % 7 >= 5, _WEEKEND, np.float32(1.0))
+    tf = np.empty((a_pad, 3, width), dtype=np.float32)
+    tf[:, 0] = fsec[:, None]
+    tf[:, 1] = wk[day]
+    day += 1
+    tf[:, 2] = wk[day]
     zbase, zamp, zdip, znamp, zpeak = t.zcols
     hp = np.stack([zbase[zid], zamp[zid], zdip[zid], znamp[zid],
                    zpeak[zid], t.band[t.path_idx],
                    np.full(zid.shape, t.cal_a), np.full(zid.shape, t.cal_b)],
-                  axis=-1).astype(np.float32)
+                  axis=-1).astype(np.float32, copy=False)
     # --- drift-scale rows: a pair's slot times are anchor + slot_s * s ----
     scl = np.ones((a_pad, 1, width), dtype=np.float32)
     if scale_fn is not None:
@@ -410,7 +487,7 @@ def _kernel_inputs(field: CarbonField, cells: CellTable,
     cf[:, 0, 0] = t.rem
     cf[:len(cells), 0, 1:5] = sla_rows[:, 1:5]
     return KernelInputs(
-        hp=hp, zk=zk.astype(np.float32), hk=hk.astype(np.float32), ti=ti,
+        hp=hp, zk=zk, hk=hk, ti=ti,
         tf=tf, pidx=t.pair_idx.reshape(-1).astype(np.int32),
         ph=(last % p).astype(np.int32),
         sh=((width - last // p) % width).astype(np.int32), nval=nval,
@@ -457,11 +534,14 @@ def batch_cell_best(field: CarbonField, cells: CellTable,
         sp.set_metadata(chunks=len(chunks))
     for chunk in chunks:
         with span("admit.inputs", cells=len(chunk)) as sp:
-            x = _kernel_inputs(field, cells.take(chunk),
-                               sla_rows[chunk], dt_s=dt_s,
-                               slot_stride=slot_stride, slot_s=slot_s,
-                               scale_fn=scale_fn)
-            sp.set_metadata(pairs=len(x.hp))
+            sub = cells.take(chunk)
+            t = _chunk_tables(field, sub, dt_s=dt_s,
+                              slot_stride=slot_stride, cell_bucket=_B_CELLS)
+            x = _layout(t, sub, sla_rows[chunk], dt_s=dt_s,
+                        slot_stride=slot_stride, slot_s=slot_s,
+                        scale_fn=scale_fn)
+            sp.set_metadata(pairs=len(x.hp), hop_keys=t.hop_keys,
+                            rows=int(_slot_hours(dt_s, slot_stride) > 0))
         with span("admit.device"):
             with span("admit.launch") as sp:
                 n0 = _compiled_count()

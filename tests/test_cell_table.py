@@ -218,6 +218,7 @@ def ref_chunk_tables(field, cells, *, dt_s, slot_stride, cell_bucket):
         n_grid_pad=_round_up(n_grid, _GRID_BUCKET),
         n_slots_pad=_round_up(n_slots, _B_SLOTS),
         n_hops=n_hops, n_pairs=len(pair_ids),
+        hop_keys=len({h.ip for p in path_objs for h in p.hops}),
         pair_paths=[path_objs[p] for _, p in inv_pair],
         pair_anchors=[a for a, _ in inv_pair])
 
@@ -311,16 +312,71 @@ def ref_plan_batch_jax(self, jobs):
     return plans
 
 
+def ref_layout(t, cells, sla_rows, *, stride, slot_s, scale_fn, dt_s=DT_S):
+    """The kernels' inputs from one chunk's tables, element by element:
+    every noise element gathered at its row's candidate hour clipped to
+    the window, and the time rows from int64 step indices."""
+    dt = int(dt_s)
+    sph, spd = 3600 // dt, 86400 // dt
+    p = int(stride)
+    width = _round_up(-(-t.n_grid_pad // p), grid_pallas._LANES)
+    a_pad, h_hops = t.path_idx.shape[0], t.n_hops
+    hours = t.znoise.shape[1]
+    rel0 = t.rel0a[t.anchor_idx]
+    q = np.floor(rel0 / dt).astype(np.int64)
+    fsec = (rel0 - q * dt).astype(np.float32)
+    u = q[:, None] + p * np.arange(width)[None, :]
+    hour0 = u // sph
+    n_cand = (sph - 1 + p - 1) // sph + 1
+    hrs = np.clip(hour0[:, None, :] + np.arange(n_cand)[None, :, None],
+                  0, hours - 1)
+    zid = t.zone_idx[t.path_idx]
+    zk = t.znoise[zid[:, None, :, None], hrs[:, :, None, :]]
+    hk = t.hnoise[t.path_idx[:, None, None, None],
+                  np.arange(h_hops)[None, None, :, None],
+                  hrs[:, :, None, :]]
+    hod0 = int(round(t.h_of_day0))
+    day = (u + int(round(t.day_frac_s)) // dt) // spd
+    dow = (t.dow0 + day) % 7
+    ti = np.stack([u - hour0 * sph, (hod0 * sph + u) % spd],
+                  axis=1).astype(np.int32)
+    wk = grid_pallas._WEEKEND
+    tf = np.stack([np.broadcast_to(fsec[:, None], u.shape),
+                   np.where(dow >= 5, wk, np.float32(1.0)),
+                   np.where((dow + 1) % 7 >= 5, wk, np.float32(1.0))],
+                  axis=1).astype(np.float32)
+    zbase, zamp, zdip, znamp, zpeak = t.zcols
+    hp = np.stack([zbase[zid], zamp[zid], zdip[zid], znamp[zid],
+                   zpeak[zid], t.band[t.path_idx],
+                   np.full(zid.shape, t.cal_a), np.full(zid.shape, t.cal_b)],
+                  axis=-1).astype(np.float32)
+    scl = np.ones((a_pad, 1, width), dtype=np.float32)
+    if scale_fn is not None:
+        for a in range(t.n_pairs):
+            ts = t.pair_anchors[a] + slot_s * np.arange(width)
+            scl[a, 0] = scale_fn(t.pair_paths[a], ts)
+    c_pad = t.pair_idx.shape[0]
+    last = t.n_steps.astype(np.int64) - 1
+    nval = np.zeros(c_pad, dtype=np.int32)
+    nval[:len(cells)] = sla_rows[:, 0]
+    cf = np.zeros((c_pad, 1, grid_pallas._CELL_COLS), dtype=np.float32)
+    cf[:, 0, 0] = t.rem
+    cf[:len(cells), 0, 1:5] = sla_rows[:, 1:5]
+    return grid_pallas.KernelInputs(
+        hp=hp, zk=zk.astype(np.float32), hk=hk.astype(np.float32), ti=ti,
+        tf=tf, pidx=t.pair_idx.reshape(-1).astype(np.int32),
+        ph=(last % p).astype(np.int32),
+        sh=((width - last // p) % width).astype(np.int32), nval=nval,
+        w=t.w_dev.astype(np.float32)[..., None], cf=cf, scl=scl)
+
+
 def ref_kernel_inputs(field, cells, sla_rows, stride, slot_s, scale_fn):
-    """``grid_pallas._kernel_inputs`` laid out from the reference tables."""
-    real = grid_pallas._chunk_tables
-    grid_pallas._chunk_tables = ref_chunk_tables
-    try:
-        return grid_pallas._kernel_inputs(
-            field, cells, sla_rows, dt_s=DT_S, slot_stride=stride,
-            slot_s=slot_s, scale_fn=scale_fn)
-    finally:
-        grid_pallas._chunk_tables = real
+    """``grid_pallas._kernel_inputs`` on the reference: the per-cell
+    tables laid out element by element."""
+    t = ref_chunk_tables(field, cells, dt_s=DT_S, slot_stride=stride,
+                         cell_bucket=grid_jax._B_CELLS)
+    return ref_layout(t, cells, sla_rows, stride=stride, slot_s=slot_s,
+                      scale_fn=scale_fn)
 
 
 # --- fleets -----------------------------------------------------------------
