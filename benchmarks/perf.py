@@ -862,97 +862,6 @@ def fleet_matrix() -> Dict[str, float]:
     return out
 
 
-def _multi_device_sweep() -> Dict[str, float]:
-    """Warm walls of the 200-job ``plan_batch_jax`` sweep without and with
-    the cell-axis split over every visible device (the sharded arm
-    through a declared :class:`~repro.core.scheduler.grid_jax.MeshConfig`,
-    the production multi-chip path)."""
-    from repro.core.carbon.intensity import PAPER_WINDOW_T0 as T0
-    from repro.core.scheduler.grid_jax import MeshConfig
-    from repro.core.scheduler.overlay import FTN
-    from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
-
-    ftns = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
-            FTN("tacc", "cascade_lake", 10.0)]
-    pl = CarbonPlanner(ftns, batch_backend="jax")
-    jobs = [TransferJob(f"b{i}", (50 + (7 * i) % 400) * 1e9, ("uc", "m1"),
-                        "tacc", SLA(deadline_s=48 * 3600.0),
-                        T0 + (i % 24) * 600.0) for i in range(200)]
-
-    def timed(shard):
-        pl.plan_batch_jax(jobs, shard=shard)          # compile + warm
-        best = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            pl.plan_batch_jax(jobs, shard=shard)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    return {"devices": jax.device_count(), "single_s": timed(False),
-            "sharded_s": timed(MeshConfig())}
-
-
-def planner_multi_device() -> Dict[str, float]:
-    """Multi-device ``shard_map`` path of the batched planner kernel
-    (:func:`_multi_device_sweep`). Where the default backend is an
-    accelerator with more than one device, the sweep runs in this
-    process, which already holds the chips, and the gate arms: an armed
-    run whose sharded sweep is not faster than the single-device sweep
-    raises after the numbers are written. On the CPU a child process
-    forces ``--xla_force_host_platform_device_count=N`` host devices
-    (the count is fixed at jax import) — they share the same cores, so
-    ~1x is expected and the gate stays unarmed. Merges
-    ``multi_device_*`` fields into BENCH_planner.json."""
-    import os as _os
-    import subprocess as _sp
-    import sys as _sys
-
-    platform = jax.default_backend()
-    devices = jax.device_count() if platform != "cpu" \
-        else min(_os.cpu_count() or 1, 4)
-    if devices < 2:
-        out = {"multi_device_count": devices,
-               "multi_device_speedup_x": None,
-               "multi_device_gate_armed": 0,
-               "multi_device_note": f"one {platform} device: sweep "
-                                    f"skipped"}
-        _write_planner_bench(out)
-        return out
-    if platform != "cpu":
-        res, armed = _multi_device_sweep(), 1
-    else:
-        root = pathlib.Path(__file__).resolve().parent.parent
-        env = dict(_os.environ, JAX_PLATFORMS="cpu")
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count"
-                            f"={devices}")
-        env["PYTHONPATH"] = _os.pathsep.join(
-            [str(root / "src"), str(root), env.get("PYTHONPATH", "")])
-        code = ("import json; from benchmarks.perf import "
-                "_multi_device_sweep; print(json.dumps(_multi_device_sweep()))")
-        proc = _sp.run([_sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=1200)
-        if proc.returncode != 0:
-            raise RuntimeError(f"multi-device sweep failed:\n"
-                               f"{proc.stderr[-2000:]}")
-        res = json.loads(proc.stdout.strip().splitlines()[-1])
-        armed = 0
-    speedup = round(res["single_s"] / res["sharded_s"], 2)
-    out = {"multi_device_count": res["devices"],
-           "multi_device_platform": platform,
-           "multi_device_single_us": round(res["single_s"] * 1e6),
-           "multi_device_sharded_us": round(res["sharded_s"] * 1e6),
-           "multi_device_gate_armed": armed,
-           "multi_device_speedup_x": speedup}
-    _write_planner_bench(out)
-    if armed and speedup <= 1.0:
-        raise RuntimeError(
-            f"multi-device gate: {res['devices']} distinct accelerator "
-            f"devices but sharded sweep is not faster "
-            f"({speedup}x <= 1.0x)")
-    return out
-
-
 def planner_scale() -> Dict[str, object]:
     """Admission-sweep scale rungs: 10^4 -> 10^5 -> 10^6 jobs through the
     batched planner in fixed-size chunks (the streaming gateway's shape —
@@ -1038,14 +947,15 @@ def planner_scale() -> Dict[str, object]:
 def field_lattice() -> Dict[str, float]:
     """Mesoscale zone-lattice plan sweep at 8 / 64 / 200 zones: per rung,
     200 fan-out jobs (replica sets striding the whole lattice toward a
-    core hub) through both the numpy sweep and the jitted jax cell-table
-    path. Records jobs/s per backend and ``peak_cells`` (the admission
-    grid the cell table reaches at 200-zone fan-out), and merges the
+    core hub) through both the numpy sweep and the Pallas cell-table
+    path (interpret mode on the CPU). Records jobs/s per backend and
+    ``peak_cells`` (the admission grid the cell table reaches at 200-zone
+    fan-out), and merges the
     ``field_lattice`` section into BENCH_planner.json.
 
     The correctness spot-checks are gated **unconditionally** — every
     run, every host: a sampled subset must match the scalar
-    ``plan_reference`` oracle (numpy within 1e-6 relative, jax within
+    ``plan_reference`` oracle (numpy within 1e-6 relative, Pallas within
     1e-4) or the bench raises after writing the numbers."""
     import numpy as np
 
@@ -1099,19 +1009,19 @@ def field_lattice() -> Dict[str, float]:
         row["numpy_jobs_per_s"] = round(len(jobs)
                                         / (time.perf_counter() - t0), 1)
         row["numpy_spot"] = _spot(plans_np, jobs, pl_np, 1e-6)
-        pl_jax = CarbonPlanner(ftns, batch_backend="jax")
-        pl_jax.plan_batch(jobs[:32])            # compile the cell table
+        pl_dev = CarbonPlanner(ftns, batch_backend="pallas")
+        pl_dev.plan_batch(jobs[:32])            # compile the kernels
         t0 = time.perf_counter()
-        plans_jax = pl_jax.plan_batch(jobs)
-        row["jax_jobs_per_s"] = round(len(jobs)
-                                      / (time.perf_counter() - t0), 1)
-        row["peak_cells"] = pl_jax.last_batch_cells
-        row["jax_spot"] = _spot(plans_jax, jobs, pl_jax, 1e-4)
+        plans_dev = pl_dev.plan_batch(jobs)
+        row["pallas_jobs_per_s"] = round(len(jobs)
+                                         / (time.perf_counter() - t0), 1)
+        row["peak_cells"] = pl_dev.last_batch_cells
+        row["pallas_spot"] = _spot(plans_dev, jobs, pl_dev, 1e-4)
         rows.append(row)
     out = {"field_lattice": {"rungs": rows}}
     _write_planner_bench(out)
     for row in rows:                            # gate after writing
-        for key in ("numpy_spot", "jax_spot"):
+        for key in ("numpy_spot", "pallas_spot"):
             spot = row[key]
             if spot["mismatches"] or spot["max_emis_rel_err"] > spot["tol"]:
                 raise RuntimeError(

@@ -27,7 +27,6 @@ def _registry():
         ("kernel_ssd_vs_ref", P.kernel_ssd_vs_ref),
         ("carbon_field", P.carbon_field),
         ("planner_scan", P.planner_scan),
-        ("planner_multi_device", P.planner_multi_device),
         ("planner_scale", P.planner_scale),
         ("field_lattice", P.field_lattice),
         ("fleet_loop", P.fleet_loop),

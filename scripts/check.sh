@@ -2,8 +2,8 @@
 # Tier-1 verify entrypoint (see ROADMAP.md): run from the repo root or any
 # subdirectory; mirrors exactly what CI runs. The docs gate (intra-repo
 # markdown links + docs/ snippet execution) always runs; set CHECK_BENCH=1
-# to follow the tests with the bench smoke (planner grid scan + forced
-# multi-device shard_map sweep + the 10^4 planner_scale admission rung,
+# to follow the tests with the bench smoke (planner grid scan + the 10^4
+# planner_scale admission rung,
 # which gates oracle + pallas-interpret spot-checks — raise the rungs
 # with BENCH_PLANNER_SCALE_RUNGS — + the field_lattice 8/64/200-zone
 # plan sweep, whose scalar-oracle spot-checks gate unconditionally on
@@ -36,8 +36,6 @@ PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python scripts/check_docs.py
 if [[ "${CHECK_BENCH:-0}" == "1" ]]; then
   PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run \
     --only planner_scan
-  PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run \
-    --only planner_multi_device
   BENCH_PLANNER_SCALE_RUNGS="${BENCH_PLANNER_SCALE_RUNGS:-10000}" \
     PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run \
     --only planner_scale

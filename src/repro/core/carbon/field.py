@@ -18,10 +18,6 @@ Every method reproduces the scalar reference (``intensity.GridRegion.ci``,
 tolerance — the test suite asserts ≤1e-6 relative error. ``default_field()``
 is the process-wide instance the scheduler stack shares, so planner, queue,
 time-shift, overlay and telemetry all hit one noise/trace cache.
-
-An optional jax view (``make_window`` / ``window_ci``) precomputes the
-hashed noise into a dense (zone × hour) array so CI lookups become pure
-``jnp`` ops that can live inside ``jax.jit``.
 """
 from __future__ import annotations
 
@@ -601,82 +597,3 @@ def default_field() -> CarbonField:
         _DEFAULT_PID = os.getpid()
     return _DEFAULT
 
-
-# --- jax window view -------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class CarbonWindow:
-    """A dense, jit-friendly view of the field over [t0, t0 + hours·1h).
-
-    All hashing happens at construction; ``window_ci`` is then pure array
-    math (works with numpy or jax.numpy, and under ``jax.jit``).
-    """
-    zones: Tuple[str, ...]
-    t0: float
-    hours: int
-    base: np.ndarray          # (Z,)
-    amp: np.ndarray           # (Z,)
-    dip: np.ndarray           # (Z,)
-    noise_amp: np.ndarray     # (Z,)
-    peak: np.ndarray          # (Z,)
-    noise: np.ndarray         # (Z, hours) hashed weather band in [-1, 1)
-    cal_a: float
-    cal_b: float
-
-    def zone_index(self, zone: str) -> int:
-        return self.zones.index(zone)
-
-
-def make_window(zones: Sequence[str], t0: float, hours: int,
-                field: Optional[CarbonField] = None) -> CarbonWindow:
-    f = field or default_field()
-    hour0 = int(t0 // 3600.0)
-    hour_idx = np.arange(hour0, hour0 + hours)
-    noise = np.stack([(f._zone_noise.lookup(z, hour_idx) - 0.5) * 2.0
-                      for z in zones])
-    regs = [REGIONS[z] for z in zones]
-    a, b = get_calibration()
-    return CarbonWindow(
-        zones=tuple(zones), t0=float(t0), hours=int(hours),
-        base=np.array([r.base_ci for r in regs]),
-        amp=np.array([r.diurnal_amp for r in regs]),
-        dip=np.array([r.solar_dip for r in regs]),
-        noise_amp=np.array([r.noise for r in regs]),
-        peak=np.array([r.peak_hour for r in regs]),
-        noise=noise, cal_a=a, cal_b=b)
-
-
-def window_ci(w: CarbonWindow, zone_idx, rel_ts, *, calibrated: bool = True,
-              xp=np):
-    """CI(zone, w.t0 + rel_ts) from a precomputed window as pure array ops.
-
-    ``zone_idx`` and ``rel_ts`` broadcast; ``rel_ts`` is seconds since
-    ``w.t0`` — relative time keeps float32 precision under ``jax.jit``
-    (absolute unix seconds lose ~256 s of resolution in f32). Pass
-    ``xp=jax.numpy`` for the accelerator path. Times outside the window
-    clamp to its edge hours.
-    """
-    rel = xp.asarray(rel_ts)
-    zone_idx = xp.asarray(zone_idx)
-    # fold the absolute anchor into host-side f64 constants
-    hour_frac_s = w.t0 - 3600.0 * math.floor(w.t0 / 3600.0)
-    h_of_day0 = (w.t0 / 3600.0) % 24.0
-    day_frac_s = w.t0 - 86400.0 * math.floor(w.t0 / 86400.0)
-    dow0 = int(w.t0 // 86400.0) % 7
-    hour_rel = xp.clip(
-        xp.floor((rel + hour_frac_s) / 3600.0).astype(xp.int32),
-        0, w.hours - 1)
-    h_of_day = (h_of_day0 + rel / 3600.0) % 24.0
-    dow = (dow0 + xp.floor((rel + day_frac_s) / 86400.0).astype(xp.int32)) % 7
-    base = xp.asarray(w.base)[zone_idx]
-    amp = xp.asarray(w.amp)[zone_idx]
-    dip = xp.asarray(w.dip)[zone_idx]
-    namp = xp.asarray(w.noise_amp)[zone_idx]
-    peak = xp.asarray(w.peak)[zone_idx]
-    v = base + amp * xp.cos(2 * np.pi * (h_of_day - peak) / 24.0)
-    v = v - dip * xp.exp(-0.5 * ((h_of_day - 13.0) / 2.5) ** 2)
-    v = xp.where((dow == 5) | (dow == 6), v * 0.94, v)
-    v = v + namp * xp.asarray(w.noise)[zone_idx, hour_rel]
-    v = xp.maximum(v, 1.0)
-    if calibrated:
-        v = xp.maximum(w.cal_a * v + w.cal_b, 0.5)
-    return v

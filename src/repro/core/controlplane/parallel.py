@@ -8,7 +8,7 @@ controllers, which makes them exactly the unit a worker process should
 own: :class:`ParallelShardRunner` starts one persistent worker per shard,
 rebuilds that shard's :class:`FleetController` inside it, and drives it
 over a pipelined pipe protocol. The coordinator keeps the fleet-level
-batched admission (the one-jit ``plan_batch`` sweep) and ships each shard
+batched admission (one ``plan_batch`` sweep) and ships each shard
 its (job, plan) stream; workers run the event loops concurrently and ship
 :class:`FleetReport`\\ s back, merged by the exact-sum
 ``FleetReport.merged`` contract — totals bit-identical to the sequential

@@ -13,10 +13,10 @@ partition — which is what makes :meth:`FleetReport.merged` an *exact*
 merge: totals, counters and the ledger re-integration audit are plain sums.
 
 Admission is batched: ``submit_many`` groups jobs by shard and plans each
-group through the shard planner's ``plan_batch`` — with the default jax
-batch backend that is one jitted ``plan_batch_jax`` sweep per shard
-(``scheduler/grid_jax.py``), not a per-job grid scan — and hands the
-precomputed plans to the controllers via ``JobArrival.plan``. In-run
+group through the shard planner's ``plan_batch`` — with the default
+Pallas batch backend that is one ``plan_batch_jax`` device sweep per
+shard (``scheduler/grid_pallas.py``), not a per-job grid scan — and
+hands the precomputed plans to the controllers via ``JobArrival.plan``. In-run
 re-plan sweeps batch the same way through the shard's own planner, so
 drifted queues re-score as one call too.
 
@@ -54,7 +54,8 @@ from repro.core.obs import metrics as obs_metrics
 from repro.core.obs.metrics import log_bounds
 from repro.core.obs.observer import ObsConfig, as_observer
 from repro.core.scheduler.overlay import FTN
-from repro.core.scheduler.planner import CarbonPlanner, TransferJob
+from repro.core.scheduler.planner import (CarbonPlanner, TransferJob,
+                                          check_batch_backend)
 
 # supervisor recovery-latency histogram bounds: 1 ms .. 1000 s
 _RECOVERY_BOUNDS = log_bounds(1e-3, 1e3, per_decade=2)
@@ -134,17 +135,18 @@ class ShardedFleet:
     """N partitioned :class:`FleetController` shards, one merged report.
 
     ``batch_backend`` is forwarded to the fleet-level admission planner
-    ("pallas" fuses the admission sweep's scoring chain + per-cell argmin
-    into the ``grid_pallas`` kernels; "jax" stacks the fleet's full-scan
-    planning into one jitted lattice call; None means "jax"). A device
-    tier that cannot run raises — admission never drops to oracle speed
-    in silence. ``shard_backend`` is the *shard planners'* batch backend
-    — the in-run re-plan sweeps — and defaults to ``batch_backend`` when
-    the shards run in this process (``parallel="off"``). With worker
-    processes it defaults to the numpy oracle: the coordinator owns the
-    accelerator, so no worker may initialise a JAX backend on it (see
-    ``core.controlplane.parallel``). Remaining keyword arguments are
-    forwarded to every ``FleetController``.
+    ("pallas", the default, fuses the admission sweep's scoring chain +
+    per-cell argmin into the ``grid_pallas`` kernels; "numpy" is the
+    pinned oracle). A device tier that cannot run raises — admission
+    never drops to oracle speed in silence, and a tier name the planner
+    does not know raises its ``ValueError``. ``shard_backend`` is the
+    *shard planners'* batch backend — the in-run re-plan sweeps — and
+    defaults to ``batch_backend`` when the shards run in this process
+    (``parallel="off"``). With worker processes it defaults to the numpy
+    oracle: the coordinator owns the accelerator, so no worker may
+    initialise a JAX backend on it (see ``core.controlplane.parallel``).
+    Remaining keyword arguments are forwarded to every
+    ``FleetController``.
 
     ``parallel`` selects the shard execution engine: ``"off"`` (default)
     drains shards sequentially in-process — the pinned oracle — while
@@ -158,7 +160,7 @@ class ShardedFleet:
     def __init__(self, ftns: Sequence[FTN], *, n_shards: int = 4,
                  field: Optional[CarbonField] = None,
                  partition: Union[str, Callable[[TransferJob], int]] = "hash",
-                 batch_backend: Optional[str] = None,
+                 batch_backend: str = "pallas",
                  parallel: str = "off",
                  shard_backend: Optional[str] = None,
                  supervision: Optional["SupervisionPolicy"] = None,
@@ -173,13 +175,16 @@ class ShardedFleet:
             raise ValueError(f"parallel must be 'off', 'fork', 'spawn' or "
                              f"'auto', got {parallel!r}")
         self.field = field or default_field()
-        if batch_backend is None:
-            batch_backend = "jax"
         self.parallel = parallel if parallel == "off" \
             else resolve_mode(parallel)
         if shard_backend is None:
             shard_backend = batch_backend if self.parallel == "off" \
                 else WORKER_BACKEND
+        # both tiers up front: a worker planner's error would only degrade
+        # its shard to numpy, and a restored checkpoint may name a tier
+        # that no longer exists
+        check_batch_backend(batch_backend)
+        check_batch_backend(shard_backend)
         self.shard_backend = shard_backend
         self.partition = partition
         self.ftns = list(ftns)
@@ -278,7 +283,7 @@ class ShardedFleet:
     def submit_many(self, jobs: Sequence[TransferJob]) -> None:
         """Batched admission: the *whole* fleet's (job x FTN x replica x
         slot) grid stack is scored in one fleet-level ``plan_batch`` call
-        (one jitted sweep on the jax batch backend), then each shard's
+        (one device sweep on the Pallas batch backend), then each shard's
         arrivals are enqueued as one plan-carrying group — shards never
         replan at arrival, only at their drift sweeps, and a parallel
         fleet ships each shard one wire message instead of one per job.

@@ -9,7 +9,7 @@ field. The :class:`StreamingGateway` sits in front of a
   (up to ``window_s`` of arrival time or ``max_batch`` jobs); a batch
   *closes* on its window timer (or at its last member's arrival when
   ``max_batch`` filled it early), is planned by ONE ``plan_batch`` call
-  on the gateway's admission planner (the jax one-jit sweep once the
+  on the gateway's admission planner (one Pallas device sweep once the
   batch is big enough — never per-job grid scoring on the hot path) and
   handed to the controllers as plan-carrying ``JobArrival`` events AT the
   close instant — the member's micro-batch admission latency, which the
@@ -118,7 +118,7 @@ class GatewayStats:
     overlap_fraction: float = 0.0
     admit_stall_ms: float = 0.0
     # admission sweeps (and their cells) the gateway's planners scored on
-    # a device tier (batch_backend "jax" / "pallas"); 0 on numpy
+    # the device tier (batch_backend "pallas"); 0 on numpy
     device_sweeps: int = 0
     device_cells: int = 0
 
@@ -311,7 +311,6 @@ class StreamingGateway:
         clone = CarbonPlanner(src.ftns, throughput=src.throughput,
                               slot_s=src.slot_s, ci_fn=src.ci_fn,
                               field=src.field.freeze().thaw(),
-                              backend=src.backend,
                               batch_backend=src.batch_backend)
         clone.emission_scale_fn = src.emission_scale_fn
         clone.capture_greedy = src.capture_greedy

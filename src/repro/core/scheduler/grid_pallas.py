@@ -1,13 +1,14 @@
 """Fused Pallas planner kernel: the admission-sweep scoring chain in two
-``pl.pallas_call``s (``CarbonPlanner(batch_backend="pallas")``).
+``pl.pallas_call``s (``CarbonPlanner(batch_backend="pallas")``), the
+planner's one device tier.
 
-Layer contract: **numpy is the pinned oracle** (see ``grid_jax.py``). The
-jitted lattice path (:func:`grid_jax.batch_cell_emissions`) materializes a
-full ``(C, 2, S)`` emission tensor and leaves the per-cell feasible-argmin
-to the host. This module fuses the per-cell chain — CI evaluation, the
-prefix sum over the rate grid, the per-(anchor, path) gather, SLA masking
-and the argmin over start slots — so only the winning (cost, emissions,
-slot) of each cell leaves the device.
+Layer contract: **numpy is the pinned oracle** (see ``grid_jax.py``, which
+builds the cell table and the per-chunk tables this module lays out).
+The kernels fuse the per-cell chain — CI evaluation, the prefix sum over
+the rate grid, the per-(anchor, path) gather, SLA masking and the argmin
+over start slots — so only the winning (cost, emissions, slot) of each
+cell leaves the device; the ``(cell, leg, slot)`` emission tensor never
+exists.
 
 No kernel value is 64-bit (TPU Mosaic has no f64/i64). Time and hour
 math runs on anchor-relative int32 step indices, and the prefix sum is
@@ -503,8 +504,7 @@ def batch_cell_best(field: CarbonField, cells: CellTable,
                     interpret: Optional[bool] = None
                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Fused admission sweep: the winning (cost, emissions, slot) of every
-    cell, computed entirely in-kernel — the ``(C, 2, S)`` emission tensor
-    the lattice path materializes never exists.
+    cell, computed entirely in-kernel.
 
     ``sla_rows`` carries one ``[n_valid, dur_s, w_perf/slack, w_carbon,
     budget_g]`` row per cell (``n_valid`` = the count of deadline-feasible

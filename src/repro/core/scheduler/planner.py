@@ -42,6 +42,15 @@ from repro.core.transfer.throughput import ThroughputModel
 _WALL_BOUNDS = log_bounds(1e-5, 1e2, per_decade=2)
 
 
+def check_batch_backend(name) -> None:
+    """Raise ``ValueError`` unless ``name`` is a tier of ``plan_batch``:
+    "numpy" (the pinned oracle) or "pallas" (the fused kernels of
+    ``grid_pallas``, the one device tier)."""
+    if name not in ("numpy", "pallas"):
+        raise ValueError(f"batch_backend must be 'numpy' or 'pallas', got "
+                         f"{name!r}")
+
+
 @dataclasses.dataclass(frozen=True)
 class SLA:
     deadline_s: float                  # relative to submission
@@ -254,41 +263,27 @@ class CarbonPlanner:
                  slot_s: float = 3600.0,
                  ci_fn: Optional[Callable[[NetworkPath, float], float]] = None,
                  field: Optional[CarbonField] = None,
-                 backend: str = "numpy",
-                 batch_backend: Optional[str] = None):
-        if backend not in ("numpy", "jax"):
-            raise ValueError(f"backend must be 'numpy' or 'jax', got "
-                             f"{backend!r}")
-        if batch_backend not in (None, "numpy", "jax", "pallas"):
-            raise ValueError(f"batch_backend must be None, 'numpy', 'jax' "
-                             f"or 'pallas', got {batch_backend!r}")
+                 batch_backend: str = "numpy"):
+        check_batch_backend(batch_backend)
         self.ftns = list(ftns)
         self._ftn_by_name = {f.name: f for f in self.ftns}
         self.throughput = throughput or ThroughputModel()
         self.slot_s = slot_s
         self.ci_fn = ci_fn             # forecast hook; None = oracle trace
         self.field = field or default_field()
-        self.backend = backend
-        self._jax_scorer = None
-        if backend == "jax":
-            from repro.core.scheduler.grid_jax import JaxGridScorer
-            self._jax_scorer = JaxGridScorer(self.field)
-        # batch_backend governs plan_batch's *full-scan* path only: "jax"
-        # routes whole fleets through the one-jit plan_batch_jax, "pallas"
-        # additionally fuses the scoring chain + per-cell argmin into the
-        # tiled grid_pallas kernel, while single plan()/rescore() calls
-        # stay on ``backend`` (small arrays beat jit dispatch there).
-        # None follows ``backend``. A device tier that cannot run is an
-        # error, here or when its kernel fails to lower: the planner never
-        # swaps one tier for another behind the caller's back.
-        if batch_backend is None:
-            batch_backend = backend
-        if batch_backend in ("jax", "pallas"):
+        # batch_backend governs plan_batch's sweeps only: "pallas" scores
+        # whole fleets (and large re-score sweeps) in the fused
+        # grid_pallas kernels, while single plan()/rescore() calls stay on
+        # numpy (small arrays beat kernel dispatch there). A device tier
+        # that cannot run is an error, here or when its kernel fails to
+        # lower: the planner never swaps one tier for another behind the
+        # caller's back.
+        if batch_backend == "pallas":
             from repro.core.scheduler.grid_jax import HAVE_JAX
             if not HAVE_JAX:
                 raise ImportError(
-                    f"batch_backend={batch_backend!r} needs jax; install "
-                    f"it or use batch_backend='numpy'")
+                    "batch_backend='pallas' needs jax; install it or use "
+                    "batch_backend='numpy'")
         self.batch_backend = batch_backend
         # drift hook (the fleet controller's forecast-shock nowcast): a
         # (path, start_times) -> multiplier-array applied to the forecast
@@ -312,37 +307,28 @@ class CarbonPlanner:
         self._metrics = obs.registry
 
     def __getstate__(self) -> dict:
-        """Pickle support for checkpointing: the jitted jax scorer does
-        not pickle (rebuilt on restore), and ``emission_scale_fn`` is the
+        """Pickle support for checkpointing: ``emission_scale_fn`` is the
         owning controller's bound hook — the controller re-wires it in its
         own ``__setstate__``, so a planner never drags a stale owner
         through a checkpoint."""
         d = self.__dict__.copy()
-        d["_jax_scorer"] = None
         d["emission_scale_fn"] = None
         return d
 
     def __setstate__(self, d: dict) -> None:
+        # a planner pickled on a tier that no longer exists raises rather
+        # than plan on another one
+        check_batch_backend(d.get("batch_backend"))
         self.__dict__.update(d)
-        if self.backend == "jax" and self._jax_scorer is None:
-            from repro.core.scheduler.grid_jax import JaxGridScorer
-            self._jax_scorer = JaxGridScorer(self.field)
 
     def _leg_emissions(self, path: NetworkPath, receiver, job: TransferJob,
                        ts: np.ndarray, gbps: float) -> np.ndarray:
         """Emission integral for one leg over all candidate starts — the
-        grid-scoring hot path, dispatched by backend (numpy is the pinned
-        oracle; jax runs the same integral jit-compiled on jnp)."""
-        if self._jax_scorer is not None:
-            emis = self._jax_scorer.leg_emissions_g(
-                path, HOST_PROFILES["storage_frontend"], receiver,
-                job.size_bytes, ts, gbps,
-                parallelism=job.parallelism, concurrency=job.concurrency)
-        else:
-            emis = self.field.transfer_emissions_g(
-                path, HOST_PROFILES["storage_frontend"], receiver,
-                job.size_bytes, ts, gbps,
-                parallelism=job.parallelism, concurrency=job.concurrency)
+        grid-scoring hot path, on the numpy field."""
+        emis = self.field.transfer_emissions_g(
+            path, HOST_PROFILES["storage_frontend"], receiver,
+            job.size_bytes, ts, gbps,
+            parallelism=job.parallelism, concurrency=job.concurrency)
         if self.emission_scale_fn is not None:
             emis = emis * self.emission_scale_fn(path, np.atleast_1d(ts))
         return emis
@@ -529,8 +515,9 @@ class CarbonPlanner:
                    drift_tol: Optional[float] = None) -> List[Plan]:
         """Fleet-scale planning: one call, shared caches. On the numpy
         batch backend the first plan warms the path/noise/trace caches and
-        the rest reuse them; with ``batch_backend="jax"`` the whole fleet's
-        grids are stacked into one jitted :meth:`plan_batch_jax` call.
+        the rest reuse them; with ``batch_backend="pallas"`` a sweep of at
+        least ``_BATCH_MIN_JOBS`` jobs is stacked into one
+        :meth:`plan_batch_jax` call on the device.
 
         Incremental mode (the control plane's forecast-drift path): with
         ``previous`` plans and a ``drift_tol``, each job's old grid cell is
@@ -585,10 +572,10 @@ class CarbonPlanner:
                 out[i] = plan
         return out                     # type: ignore[return-value]
 
-    # below these sizes the jitted batch path's fixed dispatch cost loses
-    # to the numpy per-job scan, so small sweeps stay on the oracle.
-    # Re-scores are single-cell (one slot, one anchor each): the kernel's
-    # per-anchor lattice only amortizes on very large sweeps.
+    # below these sizes the device path's fixed dispatch cost loses to
+    # the numpy per-job scan, so small sweeps stay on the oracle.
+    # Re-scores are single-cell (one slot, one anchor each): the kernels'
+    # per-(anchor, path) rate table only amortizes on very large sweeps.
     _BATCH_MIN_JOBS = 8
     _RESCORE_MIN_CELLS = 512
 
@@ -600,7 +587,7 @@ class CarbonPlanner:
     device_cells = 0
 
     def _plan_batch_full(self, jobs: Sequence[TransferJob]) -> List[Plan]:
-        if self.batch_backend in ("jax", "pallas") \
+        if self.batch_backend == "pallas" \
                 and len(jobs) >= self._BATCH_MIN_JOBS:
             return self.plan_batch_jax(jobs)
         with span("admit.numpy", jobs=len(jobs)):
@@ -685,33 +672,22 @@ class CarbonPlanner:
                            groups=build.groups)
         return table, sla, meta
 
-    def plan_batch_jax(self, jobs: Sequence[TransferJob], *,
-                       shard=None) -> List[Plan]:
-        """One-jit fleet planning: every job's (FTN x replica x slot) grid
-        is stacked into a single padded/masked cell table and scored by one
-        ``jax.jit`` call per memory chunk (``grid_jax.batch_cell_emissions``
-        — vmap over the job-cell axis, optional shard_map across devices).
+    def plan_batch_jax(self, jobs: Sequence[TransferJob]) -> List[Plan]:
+        """Fleet planning on the device: every job's (FTN x replica x
+        slot) grid is stacked into one columnar cell table, and
+        ``grid_pallas.batch_cell_best`` scores it per memory chunk in two
+        fused Pallas kernels — the scoring chain *and* each cell's
+        feasible-argmin — so only the per-cell winner (cost, emissions,
+        slot) crosses back to the host.
 
         The numpy :meth:`plan_batch` is the pinned oracle: this path must
         pick the same grid cells with emissions within 1e-4 relative
-        (in practice ~1e-7 — f32 CI chain, f64 time math). Jobs whose
-        layout the batch kernel cannot host (non-dt-aligned slots, a rate
-        grid past the per-cell cap) fall back to the numpy :meth:`plan`.
-        ``shard`` is forwarded to the kernel's device-sharding gate:
-        ``None``/``True``/``False`` as before, or a
-        :class:`~repro.core.scheduler.grid_jax.MeshConfig` declaring the
-        multi-chip mesh (platform, device count, axis name) the cell axis
-        shards over.
-
-        With ``batch_backend="pallas"`` the same cell tables feed
-        ``grid_pallas.batch_cell_best`` instead: the scoring chain *and*
-        each cell's feasible-argmin run fused in a tiled Pallas kernel,
-        so only the per-cell winner (cost, emissions, slot) crosses back
-        to the host — the (cell, leg, slot) emission tensor is never
-        materialized and ``shard`` does not apply. A kernel that cannot
-        run on this backend raises.
+        (in practice ~1e-7). Jobs whose layout the kernels cannot host
+        (non-dt-aligned slots, a rate grid past the per-cell cap) fall
+        back to the numpy :meth:`plan`. A kernel that cannot run on this
+        backend raises.
         """
-        from repro.core.scheduler.grid_jax import batch_cell_emissions
+        from repro.core.scheduler import grid_pallas
         dt_s = 60.0
         stride = self.slot_s / dt_s
         if stride != int(stride) or stride <= 0:
@@ -721,110 +697,64 @@ class CarbonPlanner:
             cells, sla_rows, meta = self._batch_cells(jobs, dt_s, stride)
             sp.set_metadata(cells=len(cells), groups=meta.groups)
         self.last_batch_cells = len(cells)
+        cost, emis, slot = np.zeros(0), np.zeros(0), np.zeros(0, np.int64)
         if len(cells):
             self.device_sweeps += 1
             self.device_cells += len(cells)
-        fused = None                   # (cost, emis, slot) per cell
-        if len(cells) and self.batch_backend == "pallas":
-            from repro.core.scheduler import grid_pallas
-            fused = grid_pallas.batch_cell_best(
+            cost, emis, slot = grid_pallas.batch_cell_best(
                 self.field, cells, sla_rows, dt_s=dt_s,
                 slot_stride=stride, slot_s=self.slot_s,
                 scale_fn=self.emission_scale_fn)
-        tables = batch_cell_emissions(self.field, cells, dt_s=dt_s,
-                                      slot_stride=stride, shard=shard) \
-            if len(cells) and fused is None else []
         plans: List[Optional[Plan]] = []
         winners: List[Tuple[int, Tuple[TransferJob, Tuple, int]]] = []
         with span("admit.select"):
-            if fused is not None:      # in-kernel mask + argmin
-                win = _first_min(fused[0], meta.lo, meta.hi)
+            win = _first_min(cost, meta.lo, meta.hi)   # in-kernel mask
             for j, job in enumerate(jobs):
                 if meta.fallback[j]:
                     plans.append(self.plan(job))
                     continue
                 n_alt = int(meta.n_alt[j])
-                best: Optional[Tuple] = None
-                g0: Optional[Tuple] = None   # (dur, emis[0]) greedy capture
-                if fused is not None:
-                    c = int(win[j])
-                    if c >= 0:
-                        ftn, src, paths, gbps = meta.cands[meta.cand[c]]
-                        best = (float(fused[0][c]), float(fused[1][c]),
-                                job.submitted_t
-                                + self.slot_s * int(fused[2][c]),
-                                ftn, src, paths, gbps, float(meta.dur[c]))
-                else:
-                    best, g0 = self._select_cells(
-                        job, meta, range(meta.lo[j], meta.hi[j]),
-                        cells.n_slots, tables)
-                if best is None:
-                    plans.append(self._fallback(job, n_alt,
-                                                greedy=g0[1] if g0 else None))
-                else:
-                    winners.append((len(plans),
-                                    (job, best, n_alt, g0[1] if g0 else None)))
-                    plans.append(None)     # filled by the batched finisher
+                c = int(win[j])
+                if c < 0:
+                    plans.append(self._fallback(job, n_alt))
+                    continue
+                ftn, src, paths, gbps = meta.cands[meta.cand[c]]
+                best = (float(cost[c]), float(emis[c]),
+                        job.submitted_t + self.slot_s * int(slot[c]),
+                        ftn, src, paths, gbps, float(meta.dur[c]))
+                # the kernels return no slot values: the greedy-now
+                # capture falls back to one integral (_resolve_greedy)
+                winners.append((len(plans), (job, best, n_alt, None)))
+                plans.append(None)     # filled by the batched finisher
         with span("admit.finish", plans=len(winners)):
             done = self._finish_plans([w for _, w in winners])
-        for (slot, _), plan in zip(winners, done):
-            plans[slot] = plan
+        for (at, _), plan in zip(winners, done):
+            plans[at] = plan
         return plans                   # type: ignore[return-value]
-
-    def _select_cells(self, job: TransferJob, meta: "_SweepCells",
-                      cells: range, n_slots: np.ndarray, tables: list
-                      ) -> Tuple[Optional[Tuple], Optional[Tuple]]:
-        """One job's least-cost feasible (cell, slot) from the lattice
-        tier's per-cell emission tables, and its greedy-now capture."""
-        deadline_t = job.submitted_t + job.sla.deadline_s
-        best: Optional[Tuple] = None
-        g0: Optional[Tuple] = None
-        for c in cells:
-            ftn, src, paths, gbps = meta.cands[meta.cand[c]]
-            dur = float(meta.dur[c])
-            ts = job.submitted_t + self.slot_s * np.arange(n_slots[c])
-            tab = tables[c]            # (n_legs, n_slots)
-            if self.emission_scale_fn is not None:
-                tab = tab * np.stack(
-                    [self.emission_scale_fn(p, ts) for p in paths])
-            emis = tab.sum(axis=0)
-            # slot 0 is the submission instant: the scored grid gives the
-            # carbon-blind start-now cell for free (the fused path never
-            # materializes slot values — _resolve_greedy falls back to one
-            # integral there)
-            if self.capture_greedy and (g0 is None or dur < g0[0]):
-                g0 = (dur, float(emis[0]))
-            feasible = ts + dur <= deadline_t + 1e-9
-            if job.sla.carbon_budget_g is not None:
-                feasible &= emis <= job.sla.carbon_budget_g
-            cost = _plan_cost(job.sla, emis, ts + dur - job.submitted_t)
-            if not feasible.any():
-                continue
-            i = int(np.argmin(np.where(feasible, cost, np.inf)))
-            if best is None or cost[i] < best[0]:
-                best = (float(cost[i]), float(emis[i]), float(ts[i]),
-                        ftn, src, paths, gbps, dur)
-        return best, g0
 
     def rescore_batch(self, jobs: Sequence[TransferJob],
                       previous: Sequence[Optional[Plan]]
                       ) -> List[Optional[Plan]]:
-        """:meth:`rescore` for a whole sweep. On the jax batch backend all
-        surviving cells (one slot each) score in one ``batch_cell_emissions``
-        call (within float noise, ~1e-7, of per-job rescore — a sweep with
+        """:meth:`rescore` for a whole sweep. On the pallas batch backend
+        a sweep of at least ``_RESCORE_MIN_CELLS`` jobs scores all
+        surviving cells (one slot each) in one
+        ``grid_pallas.batch_cell_best`` call: a one-slot cell's winner is
+        its slot 0, so the kernels' emissions are the cell's value, within
+        f32 noise (~1e-7) of per-job :meth:`rescore` — a sweep with
         ``drift_tol=0.0`` should therefore use the numpy backend, where
-        re-scores are bit-stable); otherwise falls back to per-job
-        :meth:`rescore`. The pallas batch backend re-scores on the same
-        lattice path — a re-score needs the cell's *value*, not a fused
-        argmin over slots. ``None`` entries mean the cell no longer
-        exists and the caller must full-plan."""
-        if self.batch_backend not in ("jax", "pallas") \
-                or len(jobs) < self._RESCORE_MIN_CELLS:
+        re-scores are bit-stable. Feasibility and cost stay on the host in
+        float64. Otherwise falls back to per-job :meth:`rescore`. ``None``
+        entries mean the cell no longer exists and the caller must
+        full-plan."""
+        dt_s = 60.0
+        stride = self.slot_s / dt_s
+        if self.batch_backend != "pallas" \
+                or len(jobs) < self._RESCORE_MIN_CELLS \
+                or stride != int(stride) or stride <= 0:
             return [self.rescore(j, p) if p is not None else None
                     for j, p in zip(jobs, previous)]
-        from repro.core.scheduler.grid_jax import (_MAX_GRID,
-                                                   batch_cell_emissions)
-        dt_s = 60.0
+        from repro.core.scheduler import grid_pallas
+        from repro.core.scheduler.grid_jax import _MAX_GRID
         out: List[Optional[Plan]] = [None] * len(jobs)
         items: List[Tuple] = []        # candidate key per live cell
         rows: List[int] = []           # its job
@@ -854,17 +784,21 @@ class CarbonPlanner:
                                           for i in i_of[k]]),
                 n_slots=np.ones(len(k), dtype=np.int64),
                 n_steps=n_steps[k], rem_s=rem[k])
-            tables = batch_cell_emissions(self.field, table, dt_s=dt_s,
-                                          slot_stride=1)
-            for i, c, d, tab in zip(i_of[k], cand[k], dur[k], tables):
+            sla = [jobs[i].sla for i in i_of[k]]
+            # slot 0 alone is live, and no budget masks it
+            sla_rows = np.stack([
+                np.ones(len(k)), dur[k],
+                np.array([s.w_perf / max(s.deadline_s, 1.0) for s in sla]),
+                np.array([s.w_carbon for s in sla]),
+                np.full(len(k), np.inf)], axis=1)
+            _, emis_k, _ = grid_pallas.batch_cell_best(
+                self.field, table, sla_rows, dt_s=dt_s,
+                slot_stride=int(stride), slot_s=self.slot_s,
+                scale_fn=self.emission_scale_fn)
+            for i, c, d, e in zip(i_of[k], cand[k], dur[k], emis_k):
                 job, prev = jobs[i], previous[i]
-                _, _, paths, gbps = build.cands[c]
-                d = float(d)
-                ts = np.array([prev.start_t])
-                if self.emission_scale_fn is not None:
-                    tab = tab * np.stack(
-                        [self.emission_scale_fn(p, ts) for p in paths])
-                emis = float(tab.sum())
+                gbps = build.cands[c][3]
+                d, emis = float(d), float(e)
                 deadline_t = job.submitted_t + job.sla.deadline_s
                 feasible = prev.start_t + d <= deadline_t + 1e-9
                 if job.sla.carbon_budget_g is not None:

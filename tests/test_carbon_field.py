@@ -8,8 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core.carbon.energy import HOST_PROFILES
-from repro.core.carbon.field import (CarbonField, default_field, make_window,
-                                     window_ci)
+from repro.core.carbon.field import CarbonField, default_field
 from repro.core.carbon.intensity import (PAPER_WINDOW_T0, REGIONS,
                                          calibrated_ci, region_ci)
 from repro.core.carbon.path import discover_path
@@ -310,18 +309,3 @@ def test_pmeter_field_ci_and_emissions():
     assert pm.emissions_g() == pytest.approx(expect, rel=RTOL)
     # zone-less meters price at zero rather than guessing a grid
     assert Pmeter("n0").ci(T0) == 0.0
-
-
-def test_jax_window_ci_matches_scalar():
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-    zones = list(REGIONS)
-    w = make_window(zones, T0, 60)
-    zi = np.arange(len(zones))[:, None]
-    rel = np.linspace(0.1, 59.6, 41)[None, :] * 3600.0
-    ref = np.array([[calibrated_ci(z, T0 + t) for t in rel[0]]
-                    for z in zones])
-    np.testing.assert_allclose(window_ci(w, zi, rel), ref, rtol=RTOL)
-    jitted = jax.jit(lambda zi, rel: window_ci(w, zi, rel, xp=jnp))
-    # f32 under jit: relative-time anchoring keeps error at f32 epsilon
-    np.testing.assert_allclose(np.asarray(jitted(zi, rel)), ref, rtol=5e-5)

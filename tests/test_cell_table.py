@@ -6,8 +6,7 @@ array operations. The reference below builds the same sweep one
 ``CellTask`` per cell and walks it cell by cell. Every column, SLA row,
 chunk list, ``ChunkTables`` and ``KernelInputs`` array must come out
 bit-identical, and ``plan_batch_jax`` must return equal plans (same cell,
-same floats) on the Pallas tier (interpret mode on the CPU) and on the
-jax lattice tier.
+same floats) on the Pallas tier (interpret mode on the CPU).
 """
 import dataclasses
 import itertools
@@ -30,7 +29,7 @@ from repro.core.scheduler.grid_jax import (_B_HOURS, _B_PAIRS,  # noqa: E402
                                            ChunkTables, LegTask, _round_up)
 from repro.core.scheduler.overlay import FTN  # noqa: E402
 from repro.core.scheduler.planner import (SLA, CarbonPlanner,  # noqa: E402
-                                          Plan, TransferJob, _plan_cost)
+                                          Plan, TransferJob)
 from repro.core.workloads.scenarios import (PLANNER_SCALE_FTNS,  # noqa: E402
                                             get_scenario, planner_scale_job)
 
@@ -101,9 +100,8 @@ def ref_iter_chunks(cells, slot_stride, max_elems):
     """Split a fleet of cells into anchor-sorted chunks whose
     pairs*hops*grid element count stays under ``max_elems`` (pathological
     fleets with thousands of distinct anchors would otherwise materialize
-    a multi-GB CI grid in one call). Yields lists of original indices —
-    shared by the jitted lattice path and the fused Pallas path, so both
-    see identical chunk boundaries for a given budget."""
+    a multi-GB rate table in one call). Yields lists of original
+    indices."""
     order = sorted(range(len(cells)),
                    key=lambda i: cells[i].legs[0].anchor)
     i = 0
@@ -231,80 +229,42 @@ def ref_plan_batch_jax(self, jobs):
     cells, sla_rows, meta = ref_batch_cells(self, jobs, dt_s, stride)
     sla_rows = np.asarray(sla_rows, dtype=np.float64).reshape(-1, 5)
     chunks = list(ref_iter_chunks(cells, stride, grid_jax._MAX_ELEMS))
-    fused = tables = None
-    if self.batch_backend == "pallas":
-        fused = (np.full(len(cells), np.inf), np.full(len(cells), np.inf),
-                 np.zeros(len(cells), dtype=np.int64))
-        for chunk in chunks:
-            x = ref_kernel_inputs(self.field, [cells[j] for j in chunk],
-                                  sla_rows[chunk], stride, self.slot_s,
-                                  self.emission_scale_fn)
-            best = np.asarray(grid_pallas._fused_call()(
-                *x, stride=stride, dt_s=dt_s, slot_s=self.slot_s,
-                interpret=True))
-            n = len(chunk)
-            fused[0][chunk] = best[:n, 0, 0]
-            fused[1][chunk] = best[:n, 0, 1]
-            fused[2][chunk] = best[:n, 0, 2].astype(np.int64)
-    else:
-        tables = [None] * len(cells)
-        for chunk in chunks:
-            sub = [cells[j] for j in chunk]
-            t = ref_chunk_tables(self.field, sub, dt_s=dt_s,
-                                 slot_stride=stride,
-                                 cell_bucket=grid_jax._B_CELLS)
-            emis = np.asarray(grid_jax._launch(t, dt_s=dt_s,
-                                               slot_stride=stride, n_dev=1),
-                              dtype=np.float64)
-            for k, (j, c) in enumerate(zip(chunk, sub)):
-                tables[j] = emis[k, :len(c.legs), :c.n_slots]
+    fused = (np.full(len(cells), np.inf), np.full(len(cells), np.inf),
+             np.zeros(len(cells), dtype=np.int64))
+    for chunk in chunks:
+        x = ref_kernel_inputs(self.field, [cells[j] for j in chunk],
+                              sla_rows[chunk], stride, self.slot_s,
+                              self.emission_scale_fn)
+        best = np.asarray(grid_pallas._fused_call()(
+            *x, stride=stride, dt_s=dt_s, slot_s=self.slot_s,
+            interpret=True))
+        n = len(chunk)
+        fused[0][chunk] = best[:n, 0, 0]
+        fused[1][chunk] = best[:n, 0, 1]
+        fused[2][chunk] = best[:n, 0, 2].astype(np.int64)
     plans: List[Optional[Plan]] = []
     winners = []
     for job, jcells in zip(jobs, meta):
         if jcells is None:
             plans.append(self.plan(job))
             continue
-        deadline_t = job.submitted_t + job.sla.deadline_s
         best: Optional[Tuple] = None
         n_alt = 0
-        g0: Optional[Tuple] = None
         for idx, ftn, src, paths, gbps, dur, ts in jcells:
             n_alt += len(ts)
             if idx is None:
                 continue
-            if fused is not None:
-                c_cost = float(fused[0][idx])
-                if not math.isfinite(c_cost):
-                    continue
-                if best is None or c_cost < best[0]:
-                    i = int(fused[2][idx])
-                    best = (c_cost, float(fused[1][idx]),
-                            float(ts[i]), ftn, src, paths, gbps, dur)
+            c_cost = float(fused[0][idx])
+            if not math.isfinite(c_cost):
                 continue
-            tab = tables[idx]
-            if self.emission_scale_fn is not None:
-                tab = tab * np.stack(
-                    [self.emission_scale_fn(p, ts) for p in paths])
-            emis = tab.sum(axis=0)
-            if self.capture_greedy and gbps > 0 \
-                    and (g0 is None or dur < g0[0]):
-                g0 = (dur, float(emis[0]))
-            feasible = ts + dur <= deadline_t + 1e-9
-            if job.sla.carbon_budget_g is not None:
-                feasible &= emis <= job.sla.carbon_budget_g
-            cost = _plan_cost(job.sla, emis, ts + dur - job.submitted_t)
-            if not feasible.any():
-                continue
-            i = int(np.argmin(np.where(feasible, cost, np.inf)))
-            if best is None or cost[i] < best[0]:
-                best = (float(cost[i]), float(emis[i]), float(ts[i]),
-                        ftn, src, paths, gbps, dur)
+            if best is None or c_cost < best[0]:
+                i = int(fused[2][idx])
+                best = (c_cost, float(fused[1][idx]),
+                        float(ts[i]), ftn, src, paths, gbps, dur)
         if best is None:
-            plans.append(self._fallback(job, n_alt,
-                                        greedy=g0[1] if g0 else None))
+            plans.append(self._fallback(job, n_alt))
         else:
-            winners.append((len(plans),
-                            (job, best, n_alt, g0[1] if g0 else None)))
+            winners.append((len(plans), (job, best, n_alt, None)))
             plans.append(None)
     for (slot, _), plan in zip(winners,
                                self._finish_plans([w for _, w in winners])):
@@ -568,15 +528,17 @@ def test_chunks_tables_and_inputs_match_per_cell_build(fleet, max_elems):
                               pl.emission_scale_fn))
 
 
-@pytest.mark.parametrize("tier", ["pallas", "jax"])
+@pytest.mark.parametrize("greedy", [False, True])
 @pytest.mark.parametrize("fleet", ["lattice", "edge"])
-def test_plans_match_per_cell_build(fleet, tier):
+def test_plans_match_per_cell_build(fleet, greedy):
     """``plan_batch_jax`` returns the reference's plans: same cell, same
-    floats, on both device tiers."""
+    floats, with and without the greedy-now capture."""
     pl, jobs = FLEETS[fleet]()
-    pl.batch_backend = tier
-    pl.capture_greedy = fleet == "edge"
-    assert pl.plan_batch_jax(jobs) == ref_plan_batch_jax(pl, jobs)
+    pl.batch_backend = "pallas"
+    pl.capture_greedy = greedy
+    plans = pl.plan_batch_jax(jobs)
+    assert plans == ref_plan_batch_jax(pl, jobs)
+    assert all((p.greedy_g is not None) == greedy for p in plans)
 
 
 def test_plans_match_per_cell_build_on_scale_fleet():
@@ -634,7 +596,7 @@ def test_admit_cells_span_counts_weight_groups():
 
 
 def test_rescore_batch_builds_one_slot_table():
-    """``rescore_batch`` on a device tier builds its one-slot cells with
+    """``rescore_batch`` on the Pallas tier builds its one-slot cells with
     the same ``_CellColumns`` and agrees with per-job ``rescore``: the
     same cell, gbps and duration, emissions within the oracle's 1e-4; a
     missing or stale plan stays ``None``; a cell past the grid cap
@@ -648,7 +610,7 @@ def test_rescore_batch_builds_one_slot_table():
     jobs.append(huge)
     prev.append(dataclasses.replace(prev[0], job_uuid="big", ftn="uc",
                                     source="uc", start_t=T0))
-    pl.batch_backend = "jax"
+    pl.batch_backend = "pallas"
     pl._RESCORE_MIN_CELLS = 1
     got = pl.rescore_batch(jobs, prev)
     ref = [pl.rescore(j, p) if p is not None else None
@@ -665,3 +627,41 @@ def test_rescore_batch_builds_one_slot_table():
              b.predicted_gbps, b.predicted_duration_s)
         assert a.predicted_emissions_g == pytest.approx(
             b.predicted_emissions_g, rel=1e-4)
+
+
+@pytest.mark.parametrize("hook", [False, True])
+def test_rescore_batch_scores_on_pallas(monkeypatch, hook):
+    """A Pallas planner's re-score sweep is one ``batch_cell_best`` call
+    at the planner's own stride, with SLA rows that leave slot 0 alone
+    live and the drift hook passed to the layout; its plans agree with
+    the numpy planner's per-job ``rescore`` (emissions and cost within
+    1e-4, the rest equal)."""
+    pl, jobs = _scale_fleet(40)
+    ref_pl = CarbonPlanner(list(PLANNER_SCALE_FTNS))
+    if hook:
+        pl.emission_scale_fn = ref_pl.emission_scale_fn = _scale_fn
+    prev = ref_pl.plan_batch(jobs)
+    calls = []
+    real = grid_pallas.batch_cell_best
+
+    def spy(field, cells, sla_rows, **kw):
+        calls.append((cells, np.asarray(sla_rows), kw))
+        return real(field, cells, sla_rows, **kw)
+
+    monkeypatch.setattr(grid_pallas, "batch_cell_best", spy)
+    pl.batch_backend = "pallas"
+    pl._RESCORE_MIN_CELLS = 1
+    got = pl.rescore_batch(jobs, prev)
+    ((cells, sla, kw),) = calls
+    assert len(cells) == len(jobs) and (cells.n_slots == 1).all()
+    assert (sla[:, 0] == 1).all() and np.isinf(sla[:, 4]).all()
+    assert kw["slot_stride"] == STRIDE and kw["slot_s"] == SLOT_S
+    assert kw["scale_fn"] is pl.emission_scale_fn
+    for a, b in zip(got, ref_pl.rescore_batch(jobs, prev)):
+        assert (a.start_t, a.source, a.ftn, a.feasible, a.predicted_gbps,
+                a.predicted_duration_s) == \
+            (b.start_t, b.source, b.ftn, b.feasible, b.predicted_gbps,
+             b.predicted_duration_s)
+        assert a.predicted_emissions_g == pytest.approx(
+            b.predicted_emissions_g, rel=1e-4)
+        assert a.cost == pytest.approx(b.cost, rel=1e-4)

@@ -3,13 +3,12 @@ chip — no chip attached, nothing runs.
 
 One realistic admission chunk (the first memory chunk of a 4096-job
 planner-scale batch, ~19.8k cells) is laid out by the same host code the
-planner uses, and three programs are compiled for one v5e chip at its
-shapes with ``interpret=False``: the Pallas rate/prefix kernel, the
-Pallas per-cell sweep kernel, and the jitted lattice kernel
-(``grid_jax._kernel``, under x64). A compile that the chip's compiler
-refuses — a 64-bit type in a kernel, a block that does not tile, more
-VMEM than a kernel may use, a program larger than device memory — fails
-here at no chip time.
+planner uses, and two programs are compiled for one v5e chip at its
+shapes with ``interpret=False``: the Pallas rate/prefix kernel and the
+Pallas per-cell sweep kernel. A compile that the chip's compiler refuses
+— a 64-bit type in a kernel, a block that does not tile, more VMEM than
+a kernel may use, a program larger than device memory — fails here at no
+chip time.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every pytest worker imports
@@ -109,24 +108,6 @@ def test_pallas_sweep_kernel_compiles_for_v5e(one_chip, kernel_inputs):
         static_argnames=("dt_s", "slot_s", "interpret")).lower(
             tab, *args, dt_s=DT_S, slot_s=SLOT_S, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    _assert_fits(compiled)
-
-
-def test_lattice_kernel_compiles_for_v5e(one_chip, chunk):
-    field, cells, _ = chunk
-    t = grid_jax._chunk_tables(field, cells, dt_s=DT_S, slot_stride=STRIDE,
-                               cell_bucket=grid_jax._B_CELLS)
-    args = [*t.zcols, t.znoise, t.cal_a, t.cal_b, t.h_of_day0,
-            t.day_frac_s, np.int32(t.dow0), t.rel0a, t.anchor_idx,
-            t.zone_idx, t.band, t.hnoise, t.path_idx, t.pair_idx, t.w_dev,
-            t.n_steps, t.rem]
-    with jax.enable_x64(True):
-        compiled = jax.jit(grid_jax._kernel, static_argnames=(
-            "n_grid", "n_slots", "slot_stride", "dt_s", "n_dev",
-            "mesh")).lower(
-                *[_spec(a, one_chip) for a in args], n_grid=t.n_grid_pad,
-                n_slots=t.n_slots_pad, slot_stride=STRIDE, dt_s=DT_S,
-                n_dev=1).compile()
     _assert_fits(compiled)
 
 

@@ -1,5 +1,5 @@
 """Fleet control plane: event loop, closed-loop controller, incremental
-re-planning, checkpointed migration, and the jax grid-scoring backend."""
+re-planning, checkpointed migration, and the Pallas batch tier."""
 import dataclasses
 
 import pytest
@@ -293,41 +293,18 @@ def test_device_weight_fn_matches_device_weights():
         assert W[:, j].tolist() == fn(float(g)).tolist()
 
 
-# --- jax grid-scoring backend ----------------------------------------------
-def test_jax_backend_matches_numpy_oracle():
-    jax = pytest.importorskip("jax")  # noqa: F841
-    job = TransferJob("jx", 300e9, ("uc", "m1"), "tacc",
-                      SLA(deadline_s=48 * 3600.0), T0)
-    ref = CarbonPlanner(FTNS).plan(job)
-    fast = CarbonPlanner(FTNS, backend="jax").plan(job)
-    assert (fast.start_t, fast.source, fast.ftn) == \
-        (ref.start_t, ref.source, ref.ftn)
-    assert fast.predicted_emissions_g == pytest.approx(
-        ref.predicted_emissions_g, rel=1e-4)
-    assert fast.cost == pytest.approx(ref.cost, rel=1e-4)
-
-
-def test_jax_backend_batch_matches_numpy_oracle():
-    jax = pytest.importorskip("jax")  # noqa: F841
-    jobs = [TransferJob(f"jb{i}", (50 + 70 * i) * 1e9, ("uc",), "tacc",
-                        SLA(deadline_s=24 * 3600.0), T0 + i * 1800.0)
-            for i in range(4)]
-    ref = CarbonPlanner(FTNS).plan_batch(jobs)
-    fast = CarbonPlanner(FTNS, backend="jax").plan_batch(jobs)
-    for a, b in zip(ref, fast):
-        assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn)
-        assert b.predicted_emissions_g == pytest.approx(
-            a.predicted_emissions_g, rel=1e-4)
-
-
+# --- batch tiers -----------------------------------------------------------
 def test_planner_rejects_unknown_backend():
-    with pytest.raises(ValueError):
-        CarbonPlanner(FTNS, backend="tpu")
-    with pytest.raises(ValueError):
-        CarbonPlanner(FTNS, batch_backend="tpu")
+    """``batch_backend`` is exactly "numpy" or "pallas": the deleted
+    "jax" tier is as unknown as any other name."""
+    for name in ("jax", "tpu", None):
+        with pytest.raises(ValueError, match="batch_backend"):
+            CarbonPlanner(FTNS, batch_backend=name)
+    with pytest.raises(TypeError):
+        CarbonPlanner(FTNS, backend="numpy")   # no per-leg tier knob
 
 
-# --- one-jit fleet batch (plan_batch_jax) ------------------------------------
+# --- fleet batch on the Pallas tier (plan_batch_jax) -------------------------
 def _batch_jobs(n=24):
     """Mixed fleet: spread anchors, two replica sets, varied sizes and
     deadlines — enough shape diversity to exercise padding/masking."""
@@ -339,23 +316,36 @@ def _batch_jobs(n=24):
             for i in range(n)]
 
 
-def _batch_planner(backend):
-    """Planner on the requested batch backend (pallas runs in interpret
-    mode on CPU — slow but exact)."""
+def _batch_planner():
+    """Planner on the Pallas batch tier (interpret mode on CPU — slow but
+    exact)."""
     pytest.importorskip("jax")
-    return CarbonPlanner(FTNS, batch_backend=backend)
+    return CarbonPlanner(FTNS, batch_backend="pallas")
 
 
-BATCH_BACKENDS = ["jax", "pallas"]
+BATCH_MIXES = {
+    "fleet": _batch_jobs,
+    # one two-replica job with a 48 h deadline: a long slot grid
+    "two_replica_48h": lambda: [
+        TransferJob("jx", 300e9, ("uc", "m1"), "tacc",
+                    SLA(deadline_s=48 * 3600.0), PAPER_WINDOW_T0)],
+    # four single-replica 24 h jobs, staggered by half an hour
+    "four_24h": lambda: [
+        TransferJob(f"jb{i}", (50 + 70 * i) * 1e9, ("uc",), "tacc",
+                    SLA(deadline_s=24 * 3600.0),
+                    PAPER_WINDOW_T0 + i * 1800.0) for i in range(4)],
+}
 
 
-@pytest.mark.parametrize("backend", BATCH_BACKENDS)
-def test_plan_batch_jax_matches_numpy_oracle(backend):
-    """Acceptance: the batched fleet paths (jax lattice and fused pallas
-    kernel alike) pick the same grid cells as the numpy plan_batch
-    oracle with emissions within 1e-4 relative (in practice ~1e-7)."""
-    ref = CarbonPlanner(FTNS).plan_batch(_batch_jobs())
-    fast = _batch_planner(backend).plan_batch_jax(_batch_jobs())
+@pytest.mark.parametrize("mix", sorted(BATCH_MIXES))
+def test_plan_batch_jax_matches_numpy_oracle(mix):
+    """Acceptance: the fused Pallas fleet path picks the same grid cells
+    as the numpy plan_batch oracle with emissions within 1e-4 relative
+    (in practice ~1e-7)."""
+    jobs = BATCH_MIXES[mix]()
+    ref = CarbonPlanner(FTNS).plan_batch(jobs)
+    fast = _batch_planner().plan_batch_jax(jobs)
+    assert len(fast) == len(ref)
     for a, b in zip(ref, fast):
         assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn)
         assert b.predicted_emissions_g == pytest.approx(
@@ -366,23 +356,21 @@ def test_plan_batch_jax_matches_numpy_oracle(backend):
         assert a.alternatives == b.alternatives
 
 
-@pytest.mark.parametrize("backend", BATCH_BACKENDS)
-def test_plan_batch_routes_through_jax_when_configured(backend):
+def test_plan_batch_routes_through_jax_when_configured():
     jobs = _batch_jobs(12)
-    pl = _batch_planner(backend)
+    pl = _batch_planner()
     ref = CarbonPlanner(FTNS).plan_batch(jobs)
     for a, b in zip(ref, pl.plan_batch(jobs)):
         assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn)
 
 
-@pytest.mark.parametrize("backend", BATCH_BACKENDS)
-def test_plan_batch_jax_infeasible_falls_back_like_numpy(backend):
+def test_plan_batch_jax_infeasible_falls_back_like_numpy():
     """A job no slot can satisfy must yield the same SLA-first fallback
     plan (start now, direct path, feasible=False) as the numpy oracle."""
     job = TransferJob("late", 2000e9, ("uc",), "tacc",
                       SLA(deadline_s=120.0), T0)
     ref = CarbonPlanner(FTNS).plan(job)
-    fast = _batch_planner(backend).plan_batch_jax([job])[0]
+    fast = _batch_planner().plan_batch_jax([job])[0]
     assert not ref.feasible and not fast.feasible
     assert (ref.start_t, ref.source, ref.ftn) == \
         (fast.start_t, fast.source, fast.ftn)
@@ -390,8 +378,7 @@ def test_plan_batch_jax_infeasible_falls_back_like_numpy(backend):
         ref.predicted_emissions_g, rel=1e-9)
 
 
-@pytest.mark.parametrize("backend", BATCH_BACKENDS)
-def test_plan_batch_jax_applies_emission_scale_hook(backend):
+def test_plan_batch_jax_applies_emission_scale_hook():
     """The controller's forecast-shock nowcast multiplies the forecast
     integral per leg; the batched paths must honor it like plan() does."""
     import numpy as np
@@ -403,9 +390,51 @@ def test_plan_batch_jax_applies_emission_scale_hook(backend):
     jobs = _batch_jobs(10)
     ref_pl = CarbonPlanner(FTNS)
     ref_pl.emission_scale_fn = scale
-    jax_pl = _batch_planner(backend)
+    jax_pl = _batch_planner()
     jax_pl.emission_scale_fn = scale
     for a, b in zip(ref_pl.plan_batch(jobs), jax_pl.plan_batch_jax(jobs)):
         assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn)
         assert b.predicted_emissions_g == pytest.approx(
             a.predicted_emissions_g, rel=1e-4)
+
+
+def test_incremental_plan_batch_on_pallas_matches_numpy(monkeypatch):
+    """``plan_batch(previous, drift_tol=0.05)`` on the Pallas tier keeps
+    and re-plans the same jobs as the numpy planner: the re-scores run
+    as one sweep of one-slot cells through ``grid_pallas.batch_cell_best``
+    and the drifted jobs re-plan on the same cells."""
+    import numpy as np
+
+    from repro.core.scheduler import grid_pallas
+
+    def scale(path, ts):
+        f = 1.5 if any(h.zone == "CA-QC" for h in path.hops) else 1.0
+        return np.full(np.shape(ts), f)
+
+    jobs = _batch_jobs(16)
+    previous = CarbonPlanner(FTNS).plan_batch(jobs)
+    ref_pl, fast_pl = CarbonPlanner(FTNS), _batch_planner()
+    fast_pl._RESCORE_MIN_CELLS = 1
+    for pl in (ref_pl, fast_pl):
+        pl.emission_scale_fn = scale
+    sweeps = []
+    real = grid_pallas.batch_cell_best
+
+    def spy(field, cells, sla_rows, **kw):
+        sweeps.append(int(cells.n_slots.max()))
+        return real(field, cells, sla_rows, **kw)
+
+    monkeypatch.setattr(grid_pallas, "batch_cell_best", spy)
+    ref = ref_pl.plan_batch(jobs, previous, drift_tol=0.05)
+    fast = fast_pl.plan_batch(jobs, previous, drift_tol=0.05)
+    assert sweeps[0] == 1                  # the one-slot re-score sweep
+    moved = [abs(a.predicted_emissions_g - p.predicted_emissions_g)
+             > 1e-9 * p.predicted_emissions_g
+             for a, p in zip(ref, previous)]
+    assert any(moved) and not all(moved)
+    for a, b in zip(ref, fast):
+        assert (a.start_t, a.source, a.ftn, a.feasible) == \
+            (b.start_t, b.source, b.ftn, b.feasible)
+        assert b.predicted_emissions_g == pytest.approx(
+            a.predicted_emissions_g, rel=1e-4)
+        assert b.cost == pytest.approx(a.cost, rel=1e-4)

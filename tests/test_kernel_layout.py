@@ -61,28 +61,28 @@ def _past_window():
 
 
 def _rescore(stride):
-    """The one-slot cells ``rescore_batch`` builds, as it scores them."""
+    """The one-slot cells and SLA rows ``rescore_batch`` builds, as it
+    scores them, laid out at ``stride``."""
     def run():
-        pl = CarbonPlanner(list(PLANNER_SCALE_FTNS), batch_backend="jax")
+        pl = CarbonPlanner(list(PLANNER_SCALE_FTNS), batch_backend="pallas")
         pl.emission_scale_fn = _scale_fn
         pl._RESCORE_MIN_CELLS = 1
         jobs = [planner_scale_job(i) for i in range(60)]
         prev = [pl.plan(j) for j in jobs]
         seen = []
-        real = grid_jax.batch_cell_emissions
+        real = grid_pallas.batch_cell_best
 
-        def capture(field, cells, **kw):
-            seen.append(cells)
-            return real(field, cells, **kw)
+        def capture(field, cells, sla_rows, **kw):
+            seen.append((cells, sla_rows))
+            return real(field, cells, sla_rows, **kw)
 
-        grid_jax.batch_cell_emissions = capture
+        grid_pallas.batch_cell_best = capture
         try:
             pl.rescore_batch(jobs, prev)
         finally:
-            grid_jax.batch_cell_emissions = real
-        (table,) = seen
+            grid_pallas.batch_cell_best = real
+        ((table, sla),) = seen
         assert (table.n_slots == 1).all()
-        sla = np.tile([1.0, 0.0, 0.0, 1.0, np.inf], (len(table), 1))
         return pl.field, table, sla, stride, 3600.0, pl.emission_scale_fn
     return run
 
@@ -149,15 +149,13 @@ def test_hop_tables_match_per_hop_loop():
     assert t.hop_keys < sum(p.n_hops for p in paths)
 
 
-@pytest.mark.parametrize("tier,slot_s,rows", [("pallas", 3600.0, 1),
-                                              ("pallas", 900.0, 0),
-                                              ("jax", 3600.0, None)])
-def test_admit_inputs_counts_rows_and_hop_keys(tmp_path, tier, slot_s,
-                                                rows):
+@pytest.mark.parametrize("slot_s,rows", [(3600.0, 1), (900.0, 0),
+                                         (7200.0, 1)])
+def test_admit_inputs_counts_rows_and_hop_keys(tmp_path, slot_s, rows):
     """A traced ``plan_batch_jax`` sweep records ``rows`` (1: the row
-    copy laid the chunk out, 0: the element gather; the Pallas tier
-    alone) and ``hop_keys``, the distinct ``Hop.ip`` of the chunk."""
-    pl = CarbonPlanner(FTNS, batch_backend=tier, slot_s=slot_s)
+    copy laid the chunk out, 0: the element gather) and ``hop_keys``,
+    the distinct ``Hop.ip`` of the chunk."""
+    pl = CarbonPlanner(FTNS, batch_backend="pallas", slot_s=slot_s)
     jobs = _sweep_jobs()
     with jax.profiler.trace(str(tmp_path)):
         pl.plan_batch_jax(jobs)
@@ -166,4 +164,4 @@ def test_admit_inputs_counts_rows_and_hop_keys(tmp_path, tier, slot_s,
     keys = len({h.ip for p in used for h in table.paths[p].hops})
     (ev,) = _named(_events(tmp_path), "admit.inputs")
     assert ev[3]["hop_keys"] == keys
-    assert ev[3].get("rows") == rows
+    assert ev[3]["rows"] == rows

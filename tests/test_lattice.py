@@ -5,8 +5,8 @@ The lattice tentpole's contract, pinned three ways:
 * **scalar oracle** — every lattice zone's vectorized ``zone_ci`` matches
   the scalar ``GridRegion.ci`` + calibration within 1e-6 relative, and a
   200-zone plan sweep picks the same cells as ``plan_reference``;
-* **differential sweep** — numpy == jax == pallas-interpret within 1e-4 on
-  the same lattice-sized cell tables;
+* **differential sweep** — numpy == pallas-interpret within 1e-4 on the
+  same lattice-sized cell tables;
 * **properties** — zone-relabeling (replica-permutation) invariance of
   chosen plans, monotonicity under uniform CI scaling, and CSV → field →
   CSV bit-stability of the ingestion path. Each property has a hypothesis
@@ -101,24 +101,21 @@ def test_plan_sweep_matches_scalar_oracle():
         assert _rel(fast.cost, ref.cost) <= 1e-6
 
 
-# --- three-way differential sweep -------------------------------------------
-def test_differential_sweep_numpy_jax_pallas():
+# --- differential sweep: numpy oracle against the Pallas tier ---------------
+def test_differential_sweep_numpy_pallas():
     pytest.importorskip("jax")
-    from repro.kernels import PALLAS_AVAILABLE
     sc, jobs = _fanout_jobs(16)
-    base = CarbonPlanner(sc.ftns, batch_backend="numpy")
-    plans_np = base.plan_batch(jobs)
-    backends = ["jax"] + (["pallas"] if PALLAS_AVAILABLE else [])
-    for backend in backends:
-        p = CarbonPlanner(sc.ftns, batch_backend=backend)
-        plans = p.plan_batch(jobs)
-        assert p.last_batch_cells >= 100, \
-            "lattice fan-out should produce a 100+-cell table"
-        for got, ref in zip(plans, plans_np):
-            assert (got.source, got.ftn, got.start_t) == \
-                (ref.source, ref.ftn, ref.start_t), backend
-            assert _rel(got.predicted_emissions_g,
-                        ref.predicted_emissions_g) <= 1e-4, backend
+    plans_np = CarbonPlanner(sc.ftns, batch_backend="numpy").plan_batch(jobs)
+    p = CarbonPlanner(sc.ftns, batch_backend="pallas")
+    plans = p.plan_batch(jobs)
+    assert p.last_batch_cells >= 100, \
+        "lattice fan-out should produce a 100+-cell table"
+    assert len(plans) == len(plans_np)
+    for got, ref in zip(plans, plans_np):
+        assert (got.source, got.ftn, got.start_t) == \
+            (ref.source, ref.ftn, ref.start_t)
+        assert _rel(got.predicted_emissions_g,
+                    ref.predicted_emissions_g) <= 1e-4
 
 
 # --- space-shift fan-out ----------------------------------------------------

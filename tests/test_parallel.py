@@ -337,7 +337,7 @@ def test_fault_plan_full_ladder_recovers_bit_identical():
 def test_backend_fault_downgrades_shard_to_numpy():
     """Degradation ladder rung 1: a worker that *reports* a failure (is
     alive, spoke a traceback) on a non-numpy shard backend respawns with
-    batch_backend='numpy' first — and, pre-fault jax having planned
+    batch_backend='numpy' first — and, pre-fault Pallas having planned
     nothing yet, the run still matches the numpy oracle exactly."""
     from repro.core.scheduler.grid_jax import HAVE_JAX
     if not HAVE_JAX:
@@ -349,13 +349,13 @@ def test_backend_fault_downgrades_shard_to_numpy():
 
     plan = FaultPlan(actions=(
         FaultAction(quantum=0, shard=1, kind="backend"),))
-    fleet = _fleet(MODE, shard_backend="jax", supervision=SupervisionPolicy(),
-                   fault_plan=plan)
+    fleet = _fleet(MODE, shard_backend="pallas",
+                   supervision=SupervisionPolicy(), fault_plan=plan)
     fleet.submit_many(jobs)
     rep = _drive(fleet, quanta=2, quantum_h=2.0)
     fleet.close()
     _assert_identical(rep, oracle)
-    assert any("jax -> numpy" in d for d in rep.degradations), \
+    assert any("pallas -> numpy" in d for d in rep.degradations), \
         rep.degradations
 
 

@@ -248,6 +248,27 @@ def test_restore_refuses_version_mismatch():
         persistence.restore(stale)
 
 
+@pytest.mark.parametrize("key", ["batch_backend", "shard_backend"])
+def test_restore_refuses_a_checkpoint_naming_the_jax_tier(key):
+    """A sharded checkpoint whose config names the deleted "jax" tier
+    raises the planner's ValueError on restore, under worker processes
+    too (where a worker's planner error would only degrade to numpy)."""
+    ckpt = persistence.capture(_mk_sharded())
+    stale = dataclasses.replace(ckpt, config=dict(ckpt.config, **{key: "jax"}))
+    for mode in ("off", MODE):
+        with pytest.raises(ValueError, match="batch_backend.*'jax'"):
+            persistence.restore(stale, parallel=mode)
+
+
+def test_restore_refuses_a_controller_planned_on_the_jax_tier():
+    """A controller blob whose planner was pickled on the "jax" tier does
+    not unpickle onto another tier."""
+    ctl = _mk_controller()
+    ctl.planner.batch_backend = "jax"
+    with pytest.raises(ValueError, match="batch_backend.*'jax'"):
+        persistence.restore(persistence.capture(ctl))
+
+
 def test_capture_rejects_unknown_fleet_type():
     with pytest.raises(TypeError, match="cannot checkpoint"):
         persistence.capture(object())

@@ -4,8 +4,7 @@ ledger audit — sequentially and over worker pools; the adaptive pump
 quantum schedule must be a pinned pure function (coarse idle, fine near
 boundaries) that never changes outcomes; a crash between plan dispatch and
 batch close must replay the in-flight batch exactly from the last
-checkpoint; and ``plan_batch_jax`` must plan identically through a
-declared :class:`MeshConfig` mesh."""
+checkpoint."""
 import dataclasses
 import multiprocessing as mp
 
@@ -17,7 +16,7 @@ from repro.core.controlplane import (FleetController, PumpQuanta,
                                      quantum_schedule)
 from repro.core.controlplane import persistence
 from repro.core.scheduler.overlay import FTN
-from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
+from repro.core.scheduler.planner import CarbonPlanner
 from repro.core.workloads import PoissonArrivals, UniformSizes, Workload
 
 T0 = PAPER_WINDOW_T0
@@ -336,31 +335,3 @@ def test_pipeline_config_checkpoints_and_restores():
     assert _totals(rep) == _totals(rep2)
     # wall occupancy resumes from the cut, it never goes backwards
     assert gw2.stats().n_pipelined_batches >= 1
-
-
-# --- MeshConfig: the declared mesh plans identically -------------------------
-def test_mesh_config_validation():
-    from repro.core.scheduler.grid_jax import MeshConfig
-    with pytest.raises(ValueError):
-        MeshConfig(axis="")
-    with pytest.raises(ValueError):
-        MeshConfig(n_devices=0)
-
-
-def test_plan_batch_jax_through_mesh_config_matches_default():
-    from repro.core.scheduler.grid_jax import HAVE_JAX, MeshConfig
-    if not HAVE_JAX:
-        pytest.skip("needs jax")
-    pl = CarbonPlanner(FTNS, batch_backend="jax")
-    jobs = [TransferJob(f"m{i}", (100.0 + i) * 1e9, ("uc",), "tacc",
-                        SLA(deadline_s=8 * 3600.0), T0 + 60.0 * i)
-            for i in range(12)]
-    base = pl.plan_batch_jax(jobs, shard=False)
-    for cfg in (MeshConfig(), MeshConfig(n_devices=1),
-                MeshConfig(axis="lattice")):
-        via = pl.plan_batch_jax(jobs, shard=cfg)
-        for a, b in zip(base, via):
-            assert (a.ftn, a.source, a.start_t) == (b.ftn, b.source,
-                                                    b.start_t)
-            assert a.predicted_emissions_g == \
-                pytest.approx(b.predicted_emissions_g, abs=1e-9)

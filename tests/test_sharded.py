@@ -1,10 +1,7 @@
 """Sharded fleet scale-out: partitioning, merged reports (property-tested
-conservation + the exact ledger re-integration audit), and the multi-device
-shard_map path of the batched planner kernel."""
+conservation + the exact ledger re-integration audit), and the admission
+tier a fleet picks by default."""
 import math
-import os
-import subprocess
-import sys
 
 import pytest
 
@@ -214,43 +211,33 @@ def test_single_submit_routes_to_owning_shard():
     assert fleet.shard_reports[fleet.shard_of(job)].n_jobs == 1
 
 
-# --- multi-device shard_map path of the batch kernel ------------------------
-def test_batch_kernel_shard_map_across_forced_devices():
-    """The optional shard_map split of the cell axis must reproduce the
-    numpy oracle when XLA is forced to expose multiple host devices (a
-    subprocess: device count is fixed at jax import). Three devices on
-    purpose: the cell axis must pad to a device-divisible size even when
-    the device count does not divide the padding bucket."""
+# --- the default admission tier --------------------------------------------
+def test_sharded_fleet_admits_on_pallas_by_default():
+    """With no ``batch_backend`` the fleet planner and, in this process,
+    every shard planner run the Pallas tier."""
     pytest.importorskip("jax")
-    code = """
-import jax
-assert jax.device_count() == 3, jax.device_count()
-from repro.core.carbon.intensity import PAPER_WINDOW_T0 as T0
-from repro.core.scheduler.overlay import FTN
-from repro.core.scheduler.planner import SLA, CarbonPlanner, TransferJob
-FTNS = [FTN("uc", "skylake", 10.0), FTN("m1", "apple_m1", 1.2),
-        FTN("tacc", "cascade_lake", 10.0)]
-jobs = [TransferJob(f"d{i}", (80 + 60 * i) * 1e9, ("uc",), "tacc",
-                    SLA(deadline_s=(6 + i % 5) * 3600.0), T0 + i * 900.0)
-        for i in range(10)]
-ref = CarbonPlanner(FTNS).plan_batch(jobs)
-fast = CarbonPlanner(FTNS, batch_backend="jax").plan_batch_jax(
-    jobs, shard=True)
-for a, b in zip(ref, fast):
-    assert (a.start_t, a.source, a.ftn) == (b.start_t, b.source, b.ftn), \\
-        (a.job_uuid, a.ftn, b.ftn)
-    rel = abs(a.predicted_emissions_g - b.predicted_emissions_g) \\
-        / max(a.predicted_emissions_g, 1e-12)
-    assert rel < 1e-4, (a.job_uuid, rel)
-print("OK")
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=3")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "OK" in proc.stdout
+    fleet = ShardedFleet(FTNS, n_shards=3)
+    assert fleet.planner.batch_backend == "pallas"
+    assert fleet.shard_backend == "pallas"
+    assert [c.planner.batch_backend for c in fleet.controllers] == \
+        ["pallas"] * 3
+
+
+def test_worker_shards_default_to_numpy_beside_a_pallas_planner():
+    """Worker processes plan on numpy by default: the coordinator keeps
+    the accelerator for its Pallas admission planner (no worker starts
+    before the first shard command)."""
+    from repro.core.controlplane.parallel import WORKER_BACKEND
+    pytest.importorskip("jax")
+    fleet = ShardedFleet(FTNS, n_shards=3, parallel="spawn")
+    try:
+        assert fleet.planner.batch_backend == "pallas"
+        assert fleet.shard_backend == WORKER_BACKEND == "numpy"
+    finally:
+        fleet.close()
+
+
+@pytest.mark.parametrize("key", ["batch_backend", "shard_backend"])
+def test_sharded_fleet_rejects_the_deleted_jax_tier(key):
+    with pytest.raises(ValueError, match="'jax'"):
+        ShardedFleet(FTNS, n_shards=2, parallel="spawn", **{key: "jax"})
