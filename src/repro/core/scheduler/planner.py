@@ -152,10 +152,27 @@ class _SweepCells:
     hi: np.ndarray                     # (J,) one past its last cell
     fallback: np.ndarray               # (J,) grid past the cap: plan()
     n_alt: np.ndarray                  # (J,) starts over all its candidates
+    sub: np.ndarray                    # (J,) submission times
+    size: np.ndarray                   # (J,) bytes
     dur: np.ndarray                    # (C,) transfer seconds
     cand: np.ndarray                   # (C,) row of ``cands``
     cands: List[Tuple]                 # (ftn, src, paths, gbps)
     groups: int                        # distinct device-weight closures
+
+
+@dataclasses.dataclass
+class _Winners:
+    """A sweep's winning cells, one row per job that has one, in job
+    order."""
+    job: np.ndarray                    # (W,) index into the sweep's jobs
+    cost: np.ndarray                   # (W,) the cell's least cost
+    emis: np.ndarray                   # (W,) its emissions at that slot
+    start_t: np.ndarray                # (W,) that slot's start
+    dur: np.ndarray                    # (W,) transfer seconds
+    size: np.ndarray                   # (W,) bytes
+    n_alt: np.ndarray                  # (W,) the job's alternatives
+    cand: np.ndarray                   # (W,) row of the sweep's ``cands``
+    legs: np.ndarray                   # (W, 2) path ids, -1 past the last
 
 
 class _CellColumns:
@@ -452,8 +469,9 @@ class CarbonPlanner:
         """Materialize the winning cell into a Plan. The avg-CI/carbonscore
         annotations never enter the cost, so they are sampled once for the
         winner here instead of for every candidate slot of the scan (~30%
-        of the old grid-scan cost); plan() and plan_batch_jax() share this
-        tail so both report bit-identical annotations."""
+        of the old grid-scan cost). plan() finishes here, and
+        plan_batch_jax()'s :meth:`_finish_plans` gives the same floats
+        column by column."""
         cost_i, emis_i, t_i, ftn, src, paths, gbps, dur = best
         t_arr = np.array([t_i])
         avg_ci = sum(float(self._ci_vec(p, t_arr, dur)[0])
@@ -467,48 +485,76 @@ class CarbonPlanner:
             cost=cost_i, feasible=True, alternatives=n_alt,
             greedy_g=self._resolve_greedy(job, greedy))
 
-    def _finish_plans(self, items: Sequence[Tuple]) -> List[Plan]:
-        """:meth:`_finish_plan` for many winners at once: the midpoint
-        CI samples of every winner sharing a path evaluate in one
-        ``path_ci`` call (identical floats — same per-element math and
-        summation order as ``expected_transfer_ci``)."""
-        if self.ci_fn is not None or len(items) < 4:
-            return [self._finish_plan(job, best, n_alt, greedy)
-                    for job, best, n_alt, greedy in items]
-        by_path: dict = {}
-        legs_n: List[List[Tuple]] = []
-        for j, (job, best, n_alt, _greedy) in enumerate(items):
-            _, _, t_i, _, _, paths, _, dur = best
-            row = []
-            for p in paths:
-                n = max(int(dur // 900.0), 1)
-                mids = t_i + (np.arange(n) + 0.5) * dur / n
-                key = (p.src, p.dst, p.hops)
-                ent = by_path.setdefault(key, (p, []))
-                ent[1].append(mids)
-                row.append((key, len(ent[1]) - 1, n))
-            legs_n.append(row)
-        vals: dict = {}
-        for key, (p, chunks) in by_path.items():
-            v = self.field.path_ci(p, np.concatenate(chunks))
-            bounds = np.cumsum([0] + [len(c) for c in chunks])
-            vals[key] = [v[bounds[i]:bounds[i + 1]]
-                         for i in range(len(chunks))]
-        out = []
-        for (job, best, n_alt, greedy), row in zip(items, legs_n):
-            cost_i, emis_i, t_i, ftn, src, paths, gbps, dur = best
-            avg_ci = sum(float(vals[key][slot].sum() / n)
-                         for key, slot, n in row) / len(row)
-            out.append(Plan(
-                job_uuid=job.uuid, start_t=t_i, source=src, ftn=ftn.name,
-                path=discover_path(src, ftn.name), predicted_gbps=gbps,
-                predicted_duration_s=dur, predicted_emissions_g=emis_i,
-                predicted_avg_ci=avg_ci,
-                predicted_carbonscore=carbonscore(job.size_bytes, avg_ci,
-                                                  dur),
-                cost=cost_i, feasible=True, alternatives=n_alt,
-                greedy_g=self._resolve_greedy(job, greedy)))
-        return out
+    def _finish_plans(self, jobs: Sequence[TransferJob], won: _Winners,
+                      cands: Sequence[Tuple], paths: Sequence[NetworkPath]
+                      ) -> Tuple[List[Plan], int, int]:
+        """:meth:`_finish_plan` for a sweep's winners, column by column,
+        with bit-identical fields: the avg CI of every leg from
+        :meth:`_winner_ci`, carbonscore with its own guard over the
+        columns, and each candidate's source, FTN and path resolved once.
+        Returns the plans, the distinct paths of the winners' legs and
+        the ``path_ci`` calls made. A user ``ci_fn`` is a scalar callback,
+        so its winners finish one by one."""
+        n_paths = len(np.unique(won.legs[won.legs >= 0]))
+        if self.ci_fn is not None:
+            return [self._finish_plan(jobs[j], (c, e, t, *cands[k], d), n)
+                    for j, c, e, t, k, d, n in zip(
+                        won.job.tolist(), won.cost.tolist(),
+                        won.emis.tolist(), won.start_t.tolist(),
+                        won.cand.tolist(), won.dur.tolist(),
+                        won.n_alt.tolist())], n_paths, 0
+        avg, n_calls = self._winner_ci(won, paths)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            score = np.where((avg <= 0) | (won.dur <= 0), 0.0,
+                             won.size / (avg * won.dur))
+        row = {}                       # candidate -> (src, ftn, path, gbps)
+        for k in np.unique(won.cand).tolist():
+            ftn, src, _, gbps = cands[k]
+            row[k] = (src, ftn.name, discover_path(src, ftn.name), gbps)
+        # the kernels return no slot values: the greedy-now capture falls
+        # back to one integral a job (_resolve_greedy)
+        greedy = ([self._resolve_greedy(jobs[j], None)
+                   for j in won.job.tolist()] if self.capture_greedy
+                  else [None] * len(won.job))
+        plans = []
+        for j, k, t, d, e, a, sc, c, n, g in zip(
+                won.job.tolist(), won.cand.tolist(), won.start_t.tolist(),
+                won.dur.tolist(), won.emis.tolist(), avg.tolist(),
+                score.tolist(), won.cost.tolist(), won.n_alt.tolist(),
+                greedy):
+            src, ftn, path, gbps = row[k]
+            plans.append(Plan(jobs[j].uuid, t, src, ftn, path, gbps, d, e,
+                              a, sc, c, True, n, g))
+        return plans, n_paths, n_calls
+
+    def _winner_ci(self, won: _Winners, paths: Sequence[NetworkPath]
+                   ) -> Tuple[np.ndarray, int]:
+        """Each winner's avg CI as :meth:`_finish_plan` samples it, and
+        the ``path_ci`` calls made. The legs that share a path and a
+        midpoint count n (``expected_transfer_ci``'s rule) form one
+        (legs, n) block of the same midpoints, evaluated in one call and
+        summed row by row, which sums each row in the order of a
+        one-row block."""
+        n = np.maximum((won.dur // 900.0).astype(np.int64), 1)
+        w, leg = np.nonzero(won.legs >= 0)         # by winner, leg order
+        m = int(n.max(initial=1)) + 1
+        grp, inv = np.unique(won.legs[w, leg].astype(np.int64) * m + n[w],
+                             return_inverse=True)
+        order = np.argsort(inv, kind="stable")
+        ends = np.cumsum(np.bincount(inv, minlength=len(grp)))
+        span_s = np.maximum(won.dur, 0.0)   # no duration: the start alone
+        ci = np.zeros(won.legs.shape)
+        for g, lo, hi in zip(grp.tolist(), [0, *ends[:-1].tolist()],
+                             ends.tolist()):
+            p, k = divmod(g, m)
+            r = order[lo:hi]
+            tt = won.start_t[w[r], None] \
+                + (np.arange(k) + 0.5) * span_s[w[r], None] / k
+            v = self.field.path_ci(paths[p], tt.ravel()).reshape(tt.shape)
+            ci[w[r], leg[r]] = v.sum(axis=1) / k
+        # sum(...) / len(paths): 0 + leg 0 + leg 1, a missing leg adds 0
+        return (0.0 + ci[:, 0] + ci[:, 1]) / (won.legs >= 0).sum(axis=1), \
+            len(grp)
 
     def plan_batch(self, jobs: Sequence[TransferJob],
                    previous: Optional[Sequence[Optional[Plan]]] = None,
@@ -668,8 +714,8 @@ class CarbonPlanner:
         table = build.table(cand[c], anchor=sub_e[c], n_slots=n_slots[c],
                             n_steps=n_steps[c], rem_s=rem[c])
         meta = _SweepCells(lo=lo, hi=hi, fallback=fallback, n_alt=n_alt,
-                           dur=dur[c], cand=cand[c], cands=build.cands,
-                           groups=build.groups)
+                           sub=sub, size=size, dur=dur[c], cand=cand[c],
+                           cands=build.cands, groups=build.groups)
         return table, sla, meta
 
     def plan_batch_jax(self, jobs: Sequence[TransferJob]) -> List[Plan]:
@@ -705,31 +751,27 @@ class CarbonPlanner:
                 self.field, cells, sla_rows, dt_s=dt_s,
                 slot_stride=stride, slot_s=self.slot_s,
                 scale_fn=self.emission_scale_fn)
-        plans: List[Optional[Plan]] = []
-        winners: List[Tuple[int, Tuple[TransferJob, Tuple, int]]] = []
         with span("admit.select"):
             win = _first_min(cost, meta.lo, meta.hi)   # in-kernel mask
-            for j, job in enumerate(jobs):
-                if meta.fallback[j]:
-                    plans.append(self.plan(job))
-                    continue
-                n_alt = int(meta.n_alt[j])
-                c = int(win[j])
-                if c < 0:
-                    plans.append(self._fallback(job, n_alt))
-                    continue
-                ftn, src, paths, gbps = meta.cands[meta.cand[c]]
-                best = (float(cost[c]), float(emis[c]),
-                        job.submitted_t + self.slot_s * int(slot[c]),
-                        ftn, src, paths, gbps, float(meta.dur[c]))
-                # the kernels return no slot values: the greedy-now
-                # capture falls back to one integral (_resolve_greedy)
-                winners.append((len(plans), (job, best, n_alt, None)))
-                plans.append(None)     # filled by the batched finisher
-        with span("admit.finish", plans=len(winners)):
-            done = self._finish_plans([w for _, w in winners])
-        for (at, _), plan in zip(winners, done):
-            plans[at] = plan
+            plans: List[Optional[Plan]] = [None] * len(jobs)
+            # a fallback job has no cells, so no winner either
+            for j in np.flatnonzero(win < 0).tolist():
+                plans[j] = self.plan(jobs[j]) if meta.fallback[j] \
+                    else self._fallback(jobs[j], int(meta.n_alt[j]))
+            jw = np.flatnonzero(win >= 0)
+            c = win[jw]
+            won = _Winners(job=jw, cost=cost[c], emis=emis[c],
+                           start_t=meta.sub[jw] + self.slot_s * slot[c],
+                           dur=meta.dur[c], size=meta.size[jw],
+                           n_alt=meta.n_alt[jw], cand=meta.cand[c],
+                           legs=cells.legs[c])
+        with span("admit.finish", plans=len(jw)) as sp:
+            done, n_paths, n_calls = self._finish_plans(jobs, won,
+                                                        meta.cands,
+                                                        cells.paths)
+            sp.set_metadata(paths=n_paths, ci_calls=n_calls)
+        for j, plan in zip(jw.tolist(), done):
+            plans[j] = plan
         return plans                   # type: ignore[return-value]
 
     def rescore_batch(self, jobs: Sequence[TransferJob],

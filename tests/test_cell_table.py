@@ -224,7 +224,8 @@ def ref_chunk_tables(field, cells, *, dt_s, slot_stride, cell_bucket):
 
 def ref_plan_batch_jax(self, jobs):
     """``plan_batch_jax`` on the reference build: per-cell tables, chunks
-    and layout, the same kernels, and a per-cell selection walk."""
+    and layout, the same kernels, a per-cell selection walk, and each
+    winner finished by the per-winner ``_finish_plan``."""
     dt_s, stride = DT_S, int(self.slot_s / DT_S)
     cells, sla_rows, meta = ref_batch_cells(self, jobs, dt_s, stride)
     sla_rows = np.asarray(sla_rows, dtype=np.float64).reshape(-1, 5)
@@ -266,9 +267,8 @@ def ref_plan_batch_jax(self, jobs):
         else:
             winners.append((len(plans), (job, best, n_alt, None)))
             plans.append(None)
-    for (slot, _), plan in zip(winners,
-                               self._finish_plans([w for _, w in winners])):
-        plans[slot] = plan
+    for slot, (job, best, n_alt, greedy) in winners:
+        plans[slot] = self._finish_plan(job, best, n_alt, greedy)
     return plans
 
 
